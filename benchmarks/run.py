@@ -288,6 +288,10 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma list, e.g. fig07")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         fig04_design_iterations,
         fig07_tree_reduction,

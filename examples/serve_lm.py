@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get_config, reduced
 from repro.core import EngineConfig, FaultConfig, GraphBuilder, WukongEngine
 from repro.models import model as M
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.serve import build_serve_step
 
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen-len", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced(get_config(args.arch))
     params, _ = M.init_model(jax.random.PRNGKey(0), cfg)

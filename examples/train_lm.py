@@ -24,6 +24,7 @@ from repro.core import EngineConfig, FaultConfig
 from repro.models import model as M
 from repro.optim import AdamWConfig, adamw_init
 from repro.runtime import checkpoint as ckpt
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.orchestrator import (
     build_training_workflow,
     run_training_workflow,
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--fail-prob", type=float, default=0.02,
                     help="injected Lambda failure probability")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_width:

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -81,7 +79,7 @@ def decode_attention(
     kv_len: jax.Array,           # (B,) int32
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, H, hd = q.shape
     S, K = k_cache.shape[1], k_cache.shape[2]
@@ -120,7 +118,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), qg, kt, vt)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -103,7 +101,7 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,       # CPU container: interpret; False on TPU
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, hd = q.shape
     K = k.shape[2]
@@ -143,7 +141,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),    # running sum l
             pltpu.VMEM((block_q, hd), jnp.float32),   # fp32 accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 
 def _mlstm_kernel(
     q_ref, k_ref, v_ref,        # (c, hd)
@@ -42,12 +40,24 @@ def _mlstm_kernel(
     q = q_ref[...].astype(jnp.float32)
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    f = f_ref[...].astype(jnp.float32)[:, 0]       # (c,)
-    ig = i_ref[...].astype(jnp.float32)[:, 0]
+    f = f_ref[...].astype(jnp.float32)             # (c, 1)
+    ig = i_ref[...].astype(jnp.float32)            # (c, 1)
 
-    fcum = jnp.cumsum(f)                           # (c,)
-    ftot = fcum[-1]
-    decay_q = jnp.exp(fcum)[:, None]               # (c, 1)
+    # Mosaic lowers no cumsum: the prefix sums of the log-forget gates
+    # are a lower-triangular matmul, taken once as a column and once as
+    # a row. HIGHEST keeps them f32, since they are exponentiated.
+    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = mask.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    fcum = jax.lax.dot_general(
+        tril, f, (((1,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)        # (c, 1)
+    fcum_row = jax.lax.dot_general(
+        f, tril, (((0,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)        # (1, c)
+    ftot = jnp.sum(f, axis=0, keepdims=True)       # (1, 1)
+    decay_q = jnp.exp(fcum)                        # (c, 1)
 
     C = C_scratch[...]
     nvec = n_scratch[...]                          # (1, hd)
@@ -55,29 +65,28 @@ def _mlstm_kernel(
         q * decay_q, C, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (c, hd)
     n_inter = jax.lax.dot_general(
-        q * decay_q, nvec.T, (((1,), (0,)), ((), ())),
+        q * decay_q, nvec, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (c, 1)
 
-    rel = fcum[:, None] - fcum[None, :]            # (c, c)
-    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    D = jnp.where(mask, jnp.exp(rel), 0.0) * ig[None, :]
+    # The input gate of key j scales column j of the decay matrix; it is
+    # applied to the keys' rows instead, which is the same product.
+    D = jnp.where(mask, jnp.exp(fcum - fcum_row), 0.0)
     scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k * ig, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * D    # (c, c)
     y = y_inter + jax.lax.dot_general(
         scores, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    nrm = n_inter[:, 0] + jnp.sum(scores, axis=1)
-    y = y / jnp.maximum(jnp.abs(nrm), 1.0)[:, None]
+    nrm = n_inter + jnp.sum(scores, axis=1, keepdims=True)
+    y = y / jnp.maximum(jnp.abs(nrm), 1.0)
     o_ref[...] = y.astype(o_ref.dtype)
 
-    decay_k = (ig * jnp.exp(ftot - fcum))[:, None]  # (c, 1)
-    kd = k * decay_k
+    kd = k * (ig * jnp.exp(ftot - fcum))           # (c, hd)
     C_scratch[...] = jnp.exp(ftot) * C + jax.lax.dot_general(
         kd, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)         # (hd, hd)
-    n_scratch[...] = jnp.exp(ftot) * nvec + jnp.sum(kd, axis=0)[None, :]
+    n_scratch[...] = jnp.exp(ftot) * nvec + jnp.sum(kd, axis=0,
+                                                    keepdims=True)
 
 
 def mlstm_chunk(
@@ -88,7 +97,7 @@ def mlstm_chunk(
     i_gate: jax.Array,          # (B, S, H)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, hd = q.shape
     assert S % chunk == 0
@@ -113,7 +122,7 @@ def mlstm_chunk(
             pltpu.VMEM((hd, hd), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt, ft, it)

@@ -1,8 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On the TPU target these run compiled (``interpret=False``); in this CPU
-container they run in interpret mode, validated against ``ref.py``. The
-wrappers pad ragged shapes up to block multiples and handle layout.
+On the TPU they run compiled. On the CPU backend, where the test suite
+runs, they run in Pallas interpret mode, validated against ``ref.py``;
+any other backend is an error. The wrappers pad ragged shapes up to
+block multiples and handle layout.
 """
 from __future__ import annotations
 
@@ -15,7 +16,21 @@ from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
 from repro.kernels import linear_attention as _la
 
-_ON_TPU = jax.default_backend() == "tpu"
+
+def interpret_mode() -> bool:
+    """Whether the kernels must run in interpret mode on this backend.
+
+    Decided when a wrapper is traced, not at import: ``tpu`` compiles
+    the kernels, ``cpu`` interprets them, and any other backend raises
+    rather than dropping into the interpreter unannounced."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels run compiled on 'tpu' or interpreted on "
+        f"'cpu'; the default backend is {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window",
@@ -30,7 +45,7 @@ def flash_attention(q, k, v, *, causal=True, window=None,
         q, k, v = (jnp.pad(t, cfg) for t in (q, k, v))
     out = _fa.flash_attention(q, k, v, causal=causal, window=window,
                               block_q=bq, block_k=bk,
-                              interpret=not _ON_TPU)
+                              interpret=interpret_mode())
     return out[:, :S] if pad else out
 
 
@@ -38,11 +53,11 @@ def flash_attention(q, k, v, *, causal=True, window=None,
 def decode_attention(q, k_cache, v_cache, kv_len, *, block_k=512):
     return _dec.decode_attention(q, k_cache, v_cache, kv_len,
                                  block_k=min(block_k, k_cache.shape[1]),
-                                 interpret=not _ON_TPU)
+                                 interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def mlstm_chunk(q, k, v, log_f, i_gate, *, chunk=64):
     return _la.mlstm_chunk(q, k, v, log_f, i_gate,
                            chunk=min(chunk, q.shape[1]),
-                           interpret=not _ON_TPU)
+                           interpret=interpret_mode())

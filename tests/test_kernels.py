@@ -1,4 +1,7 @@
-"""Pallas kernels vs. pure-jnp oracles: shape/dtype sweeps + properties."""
+"""Pallas kernels vs. pure-jnp oracles: shape/dtype sweeps + properties.
+
+The suite runs on the CPU, so every raw kernel call asks for Pallas
+interpret mode explicitly."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +15,7 @@ except ImportError:  # property tests skip without the dev extra
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.linear_attention import mlstm_chunk
+from repro.kernels.ops import interpret_mode
 from repro.kernels.ref import (
     decode_attention_ref,
     flash_attention_ref,
@@ -42,7 +46,7 @@ def test_flash_attention_sweep(dtype, B, S, H, K, hd, bq, bk, causal,
     k = rand(ks[1], (B, S, K, hd), dtype)
     v = rand(ks[2], (B, S, K, hd), dtype)
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=bq, block_k=bk)
+                          block_q=bq, block_k=bk, interpret=True)
     ref = flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(
         np.asarray(out, dtype=np.float32), np.asarray(ref, np.float32),
@@ -61,7 +65,7 @@ def test_decode_attention_sweep(dtype, B, S, H, K, hd, bk):
     kc = rand(ks[1], (B, S, K, hd), dtype)
     vc = rand(ks[2], (B, S, K, hd), dtype)
     kv_len = jnp.asarray([S, max(1, S // 2), 7][:B], dtype=jnp.int32)
-    out = decode_attention(q, kc, vc, kv_len, block_k=bk)
+    out = decode_attention(q, kc, vc, kv_len, block_k=bk, interpret=True)
     ref = decode_attention_ref(q, kc, vc, kv_len)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
@@ -80,7 +84,7 @@ def test_mlstm_chunk_sweep(B, S, H, hd, chunk):
     v = rand(ks[2], (B, S, H, hd), jnp.float32)
     log_f = jax.nn.log_sigmoid(rand(ks[3], (B, S, H), jnp.float32))
     i_g = jax.nn.sigmoid(rand(ks[4], (B, S, H), jnp.float32))
-    out = mlstm_chunk(q, k, v, log_f, i_g, chunk=chunk)
+    out = mlstm_chunk(q, k, v, log_f, i_g, chunk=chunk, interpret=True)
     ref = mlstm_chunk_ref(q, k, v, log_f, i_g, chunk=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-5, rtol=5e-4)
@@ -102,7 +106,8 @@ def test_flash_attention_property(s_blocks, h, g, causal):
     q = rand(ks[0], (1, S, H, hd), jnp.float32)
     k = rand(ks[1], (1, S, K, hd), jnp.float32)
     v = rand(ks[2], (1, S, K, hd), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     ref = flash_attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -115,7 +120,8 @@ def test_flash_attention_matches_model_sdpa():
     q = rand(ks[0], (2, 128, 4, 64), jnp.float32)
     k = rand(ks[1], (2, 128, 2, 64), jnp.float32)
     v = rand(ks[2], (2, 128, 2, 64), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                          interpret=True)
     ref = sdpa(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
@@ -141,7 +147,21 @@ def test_mlstm_kernel_matches_model_layer():
     xf = x.astype(jnp.float32)
     log_f = jax.nn.log_sigmoid(xf @ p["wf"])
     i_g = jnp.exp(jax.nn.log_sigmoid(xf @ p["wi"]))
-    y_kernel = mlstm_chunk(q, k, v, log_f, i_g, chunk=64)
+    y_kernel = mlstm_chunk(q, k, v, log_f, i_g, chunk=64, interpret=True)
     y_kernel = y_kernel.reshape(B, S, dk) @ p["wo"]
     np.testing.assert_allclose(np.asarray(y_kernel), np.asarray(y_layer),
                                atol=1e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("backend,expected", [
+    ("cpu", True), ("tpu", False), ("gpu", RuntimeError),
+])
+def test_interpret_mode_follows_backend(monkeypatch, backend, expected):
+    """Interpret mode only on the CPU, compiled on the TPU, and any other
+    backend refused instead of silently interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expected is RuntimeError:
+        with pytest.raises(RuntimeError, match=backend):
+            interpret_mode()
+    else:
+        assert interpret_mode() is expected
