@@ -31,7 +31,9 @@ cross-checks.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
+import sys
 import threading
 from typing import TYPE_CHECKING, Any
 
@@ -49,10 +51,25 @@ from repro.core.faults import (
     HeartbeatRegistry,
 )
 from repro.core.invoker import FanoutProxy, InvokerPool
-from repro.core.kvstore import PURGED, CostModel, ShardedKVStore, sizeof
+from repro.core.kvstore import (
+    PURGED,
+    CostModel,
+    HostTimedKVStore,
+    ShardedKVStore,
+    sizeof,
+)
 from repro.core.optimize import OptimizeConfig, PassStats, ensure_compiled
 from repro.core.schedule import generate_static_schedules
-from repro.core.simclock import run_effects, task_clock
+from repro.core.simclock import (
+    HOST_LOG,
+    EventClock,
+    HostProfile,
+    HostRecord,
+    clock_for_scale,
+    host_span,
+    run_effects,
+    task_clock,
+)
 
 if TYPE_CHECKING:  # import cycle: repro.platform imports repro.core
     from repro.platform import FaaSPlatform, PlatformConfig
@@ -182,6 +199,10 @@ class JobReport:
     # hits/misses/evictions/spills and bytes served locally vs remotely.
     # Empty unless the platform runs with a container cache configured.
     cache_stats: dict[str, int] = dataclasses.field(default_factory=dict)
+    # Host time by layer and frame steps, for a job that
+    # ``WukongEngine.compute`` ran while a JAX profiler session captured
+    # (``HOST_LAYERS``); None otherwise.
+    host_profile: HostRecord | None = None
 
 
 def _platform_stats(platform: "FaaSPlatform | None",
@@ -281,6 +302,44 @@ class _ResultWaiter:
         return run_effects(self.kv.clock, self.wait_g(timeout_s))
 
 
+# Host profile of a job (simclock.HostProfile), while a JAX profiler
+# session captures. Each host nanosecond of the job's ``wukong/job`` span
+# goes to one layer: ``compile`` (the DAG compiler), ``schedule``,
+# ``task_fn`` (task functions), ``kv`` (the store's charged operations,
+# kvstore.HostTimedKVStore), ``invoker`` (frames of invoker pools and the
+# fan-out proxy, which spawn them so), ``walk`` (every other frame: the
+# job's root, executor bodies, the speculative monitor) and ``loop`` (the
+# event loop between frame steps, and ``compute`` outside the rest).
+HOST_LAYERS = ("compile", "schedule", "task_fn", "kv", "walk", "invoker",
+               "loop")
+HOST_SPANS = ("wukong/job", "wukong/compile", "wukong/walk",
+              "wukong/schedule", "wukong/task_fn")
+_host_jobs = itertools.count()
+
+
+def profiler_capturing() -> bool:
+    """Whether a JAX profiler session is capturing in this process. With
+    JAX never imported, none can be, and nothing more is looked at."""
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
+
+
+def _host_profile(clock: Any) -> HostProfile | None:
+    """A HostProfile attached to ``clock`` for the next job, or None
+    unless the clock is an ``EventClock`` and a profiler session captures."""
+    if not isinstance(clock, EventClock) or not profiler_capturing():
+        return None
+    HOST_LOG.hook_gc(profiler_capturing)
+    job = next(_host_jobs)
+    annotation = sys.modules["jax"].profiler.TraceAnnotation
+    # The job's id as metadata, in the profiler's own encoding
+    # ("name#key=value#"): cheaper than keyword arguments.
+    tagged = {name: f"{name}#job={job}#" for name in HOST_SPANS}
+    return HostProfile(clock, job, lambda name: annotation(tagged[name]),
+                       HOST_LAYERS, idle="loop", frames="walk",
+                       task=("wukong/task_fn", "task_fn"))
+
+
 class WukongEngine:
     """The decentralized engine (paper §IV)."""
 
@@ -294,22 +353,45 @@ class WukongEngine:
         The job body is an effect generator (``compute_g``); the clock's
         ``run`` drives it — as the root continuation of the event loop on
         the event substrate, or inline on the calling (actor) thread on
-        the thread/realtime substrates."""
+        the thread/realtime substrates.
+
+        While a JAX profiler session captures, a job that runs on a
+        store and ``EventClock`` of its own (no ``substrate``, the
+        default) is profiled: its host time by layer, in spans ``wukong/job``,
+        ``wukong/compile``, ``wukong/walk``, ``wukong/schedule`` and
+        ``wukong/task_fn`` on the profiler's trace, and in
+        ``JobReport.host_profile`` (``HOST_LAYERS`` above). Nothing is
+        attached on the thread and realtime substrates, nor to an
+        injected or shared substrate (``compute_g``)."""
         cfg = self.config
-        # DAG compiler: rewrite/annotate before any schedule is generated.
-        # Host-side work (compilation, schedule generation) happens before
-        # the clock starts: it is scheduler prep, not simulated time.
-        dag = ensure_compiled(dag, cfg.optimize)
-        if substrate is None:
-            kv: Any = ShardedKVStore(
-                n_shards=cfg.n_kv_shards,
-                cost=cfg.cost,
-                colocate_shards=cfg.colocate_kv_shards,
-                counter_mode=cfg.counter_mode,
-            )
-        else:
-            kv = substrate.kv
-        return kv.clock.run(self._compute_g(dag, kv, substrate))
+        if substrate is not None:
+            dag = ensure_compiled(dag, cfg.optimize)
+            kv: Any = substrate.kv
+            return kv.clock.run(self._compute_g(dag, kv, substrate))
+        clock = clock_for_scale(cfg.cost.time_scale, cfg.cost.substrate)
+        prof = _host_profile(clock)
+        with host_span(prof, "wukong/job"):
+            try:
+                # DAG compiler: rewrite/annotate before any schedule is
+                # generated. Host-side work (compilation, schedule
+                # generation) happens before the clock starts: it is
+                # scheduler prep, not simulated time.
+                with host_span(prof, "wukong/compile", "compile"):
+                    dag = ensure_compiled(dag, cfg.optimize)
+                store = ShardedKVStore if prof is None else HostTimedKVStore
+                kv = store(
+                    n_shards=cfg.n_kv_shards,
+                    cost=cfg.cost,
+                    colocate_shards=cfg.colocate_kv_shards,
+                    counter_mode=cfg.counter_mode,
+                    clock=clock,
+                )
+                with host_span(prof, "wukong/walk"):
+                    report = clock.run(self._compute_g(dag, kv, None))
+            finally:
+                record = prof.finish() if prof is not None else None
+        report.host_profile = record
+        return report
 
     def compute_g(self, dag: DAG, substrate: JobSubstrate):
         """The job as an effect generator, for composition inside an
@@ -322,7 +404,8 @@ class WukongEngine:
         cfg = self.config
         function = substrate.function if substrate is not None else "executor"
         clock = kv.clock
-        schedule_set = generate_static_schedules(dag)
+        with host_span(clock.host_profile, "wukong/schedule", "schedule"):
+            schedule_set = generate_static_schedules(dag)
         # On a shared substrate the clock's cumulative charge counter
         # does not restart per job: report the delta. (With jobs from
         # OTHER tenants charging the same clock concurrently, the

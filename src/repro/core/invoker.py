@@ -85,7 +85,7 @@ class InvokerPool:
         self._closed = False
         self._n_lanes = max(1, n_invokers)
         for i in range(self._n_lanes):
-            clock.spawn(self._lane, name=f"{name}-{i}")
+            clock.spawn(self._lane, name=f"{name}-{i}", layer="invoker")
 
     def _invoke_legacy_g(self, body: Callable[[], Any],
                          extra_ms: float, index: int):
@@ -199,7 +199,7 @@ class FanoutProxy:
         self._sub = kv.subscribe(self.CHANNEL)
         self._stop = threading.Event()
         self.handled_fanouts = 0
-        kv.clock.spawn(self._serve, name="kv-proxy")
+        kv.clock.spawn(self._serve, name="kv-proxy", layer="invoker")
 
     def _serve(self):
         # Event-driven: the proxy parks on its subscription (costing

@@ -68,6 +68,7 @@ from repro.core.simclock import (
     BaseClock,
     _current_frame,
     clock_for_scale,
+    in_layer,
     run_effects,
 )
 
@@ -1037,6 +1038,20 @@ class ShardedKVStore:
         for fn in tuple(self._purge_listeners):
             fn(prefix)
         return removed
+
+
+class HostTimedKVStore(ShardedKVStore):
+    """The store of a job that ``simclock.HostProfile`` profiles: each
+    charged operation (every public effect generator, ``*_g``), from its
+    entry to its return and across its yields, charges the ``kv`` layer
+    (``simclock.in_layer``). ``WukongEngine.compute`` builds one only for
+    a profiled job, so the store of any other job pays nothing for it."""
+
+
+for _name in dir(ShardedKVStore):
+    if _name.endswith("_g") and not _name.startswith("_"):
+        setattr(HostTimedKVStore, _name,
+                in_layer("kv", getattr(ShardedKVStore, _name)))
 
 
 class KVNamespace:

@@ -70,6 +70,10 @@ use real condition variables with real timeouts.
 """
 from __future__ import annotations
 
+import contextlib
+import dataclasses
+import functools
+import gc
 import heapq
 import itertools
 import queue as _queue
@@ -82,13 +86,19 @@ from types import GeneratorType
 from typing import Any, Callable
 
 __all__ = [
+    "HOST_LOG",
     "BaseClock",
     "EventClock",
+    "HostLog",
+    "HostProfile",
+    "HostRecord",
     "RealtimeClock",
     "VirtualClock",
     "charge_meter",
     "clock_for_scale",
     "drain_worker_cache",
+    "host_span",
+    "in_layer",
     "run_effects",
     "simulated_compute",
     "task_clock",
@@ -143,8 +153,17 @@ class task_clock:
         else:
             self._prev = getattr(_task_clock, "clock", None)
             _task_clock.clock = self.clock
+        # Host profile (HostProfile below): the task function's own span
+        # and layer. It never encloses a yield: the task function cannot
+        # suspend.
+        prof = getattr(self.clock, "host_profile", None)
+        self._span = None if prof is None else _HostSpan(prof, *prof.task)
+        if self._span is not None:
+            self._span.__enter__()
 
     def __exit__(self, *exc: Any) -> None:
+        if self._span is not None:
+            self._span.__exit__(*exc)
         if self._frame is not None:
             self._frame.task_clock = self._prev
         else:
@@ -201,6 +220,204 @@ class charge_meter:
             self._frame.charge_acc = self._prev
         else:
             _charge_tap.acc = self._prev
+
+
+# ---------------------------------------------------------------------------
+# Host-time profile of one job, by layer (opt-in, like ``tracer``).
+#
+# The simulated clock says what the modelled cloud would charge; it says
+# nothing of where this host spends its time. A HostProfile attached to an
+# EventClock (``clock.host_profile``; None by default, and then every hook
+# below is one None test) reads the host clock at each frame step, at each
+# entry into and return from a method wrapped by ``in_layer``, and at each
+# span with a layer, and charges the time in between to one layer. Which
+# layers there are, which a frame charges and what the spans are called is
+# the caller's to say (``WukongEngine.compute``); the profile charges what
+# a frame's ``layer`` names (set by ``spawn(..., layer=)``), and the
+# ``frames`` layer where it names none. Spans go to the caller's
+# ``annotate`` (the profiler's ``TraceAnnotation``), never across a yield:
+# frames interleave on one thread, and a span held across a suspension
+# would overlap its siblings. A layer rides on the frame (like
+# ``charge_meter``), so it survives a suspension.
+#
+# This module is the one place that reads the host clock; the profile
+# reads it only while attached, and never changes a simulated quantity.
+# ---------------------------------------------------------------------------
+
+_clock_ns = time.perf_counter_ns  # the host clock HostProfile reads
+
+
+@dataclasses.dataclass(frozen=True)
+class HostRecord:
+    """One profiled job, on ``time.perf_counter``'s clock (in ns).
+
+    ``layers_ns`` sums to ``end_ns - start_ns``; ``frame_steps`` is the
+    clock's ``switches`` during the job."""
+
+    job: int
+    start_ns: int
+    end_ns: int
+    layers_ns: dict[str, int]
+    frame_steps: int
+
+
+class HostLog:
+    """This process's finished job profiles and the garbage collector's
+    pauses while a profiler session captured, bounded by count, oldest
+    dropped first (a record is a few hundred bytes, a pause a pair of
+    floats: some 60 MB when both are full). Process-wide (``HOST_LOG``):
+    whoever times the jobs reads it by their window on
+    ``time.perf_counter``'s clock."""
+
+    JOBS_MAX = 1 << 16
+    GC_MAX = 1 << 18
+
+    def __init__(self) -> None:
+        self.jobs: "deque[HostRecord]" = deque(maxlen=self.JOBS_MAX)
+        # (start_s, seconds) of each collection
+        self.gc_pauses: "deque[tuple[float, float]]" = deque(
+            maxlen=self.GC_MAX)
+        self._capturing: "Callable[[], bool] | None" = None
+        self._gc_start: float | None = None
+
+    def hook_gc(self, capturing: Callable[[], bool]) -> None:
+        """Time every collection from now on while ``capturing()`` says
+        a session captures (the hook stays; outside a session it costs
+        that one check)."""
+        if self._capturing is None:
+            self._capturing = capturing
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_start = (time.perf_counter() if self._capturing()
+                              else None)
+        elif self._gc_start is not None:
+            self.gc_pauses.append(
+                (self._gc_start, time.perf_counter() - self._gc_start))
+            self._gc_start = None
+
+
+HOST_LOG = HostLog()
+
+
+class _HostSpan:
+    """A span of a HostProfile on the caller's trace; with ``layer``, it
+    charges that layer and then gives back the one it found. It encloses
+    no yield, so no other frame's step falls inside it."""
+
+    __slots__ = ("prof", "name", "layer", "ann", "outer")
+
+    def __init__(self, prof: "HostProfile", name: str, layer: str | None):
+        self.prof = prof
+        self.name = name
+        self.layer = layer
+
+    def __enter__(self) -> None:
+        self.ann = self.prof._annotate(self.name)
+        self.ann.__enter__()
+        if self.layer is not None:
+            self.outer = self.prof._switch(self.layer)
+
+    def __exit__(self, *exc: Any) -> None:
+        if self.layer is not None:
+            self.prof._switch(self.outer)
+        self.ann.__exit__(*exc)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def host_span(prof: "HostProfile | None", name: str,
+              layer: str | None = None) -> Any:
+    """Context manager: span ``name`` of ``prof``, charging ``layer`` if
+    given; nothing when ``prof`` is None. It must enclose no yield."""
+    return _NO_SPAN if prof is None else _HostSpan(prof, name, layer)
+
+
+class HostProfile:
+    """Host time of one job on an ``EventClock``, split by layer.
+
+    Constructing one attaches it as ``clock.host_profile`` and starts the
+    job; ``finish`` detaches it and logs the job's ``HostRecord``. In
+    between, every host nanosecond is charged to one of ``layers``: inside
+    a frame step, the frame's layer (``frames`` if its spawn named none);
+    between steps, ``idle``; inside a span with a layer (``host_span``;
+    ``task`` names the span and layer of a task function under
+    ``task_clock``), that layer. ``annotate(name)`` gives a span's context
+    manager on the caller's trace."""
+
+    def __init__(self, clock: "EventClock", job: int,
+                 annotate: Callable[[str], Any], layers: tuple[str, ...], *,
+                 idle: str, frames: str, task: tuple[str, str]):
+        self.clock = clock
+        self.job = job
+        self._annotate = annotate
+        self.layers_ns = dict.fromkeys(layers, 0)
+        self._frames = frames
+        self.task = task
+        self._idle = self._layer = idle
+        self._frame: "_Frame | None" = None  # the frame stepping now
+        self._switches0 = clock.switches
+        self._mark = self.start_ns = _clock_ns()
+        clock.host_profile = self
+
+    def finish(self) -> HostRecord:
+        """Detach, and log and return the job's record."""
+        self.clock.host_profile = None
+        self._switch(self._layer)
+        record = HostRecord(self.job, self.start_ns, self._mark,
+                            self.layers_ns,
+                            self.clock.switches - self._switches0)
+        HOST_LOG.jobs.append(record)
+        return record
+
+    def _switch(self, layer: str) -> str:
+        """Charge the time since the last switch to the layer being left,
+        and enter ``layer``; return the layer left."""
+        now = _clock_ns()
+        left = self._layer
+        self.layers_ns[left] += now - self._mark
+        self._mark = now
+        self._layer = layer
+        return left
+
+    def step_begin(self, frame: "_Frame") -> None:
+        self._frame = frame
+        layer = frame.layer
+        if layer is None:
+            layer = frame.layer = self._frames
+        self._switch(layer)
+
+    def step_end(self) -> None:
+        self._frame = None
+        self._switch(self._idle)
+
+
+def in_layer(layer: str, op: Callable[..., Any]) -> Callable[..., Any]:
+    """The effect-generator method ``op`` of an object with a ``clock``,
+    charged to ``layer`` of the HostProfile attached to that clock:
+    pushed on the running frame at entry and popped at return, so the
+    layer survives the method's yields."""
+
+    @functools.wraps(op)
+    def timed(obj: Any, *args: Any, **kwargs: Any) -> Any:
+        prof = obj.clock.host_profile
+        frame = None if prof is None else prof._frame
+        if frame is None:  # no profile, or outside the frames
+            return (yield from op(obj, *args, **kwargs))
+        outer, frame.layer = frame.layer, layer
+        prof._switch(layer)
+        try:
+            return (yield from op(obj, *args, **kwargs))
+        finally:
+            frame.layer = outer
+            # A generator closed while its frame is not stepping (collected
+            # after its job) gives the layer back without charging.
+            if frame is prof._frame:
+                prof._switch(outer)
+
+    return timed
 
 
 # ---------------------------------------------------------------------------
@@ -287,6 +504,9 @@ class BaseClock:
         # package): when set, every freshly generated effect is
         # journaled via tracer.record(actor, effect, gen). None is free.
         self.tracer: Any = None
+        # Opt-in host-time profile of the running job (HostProfile,
+        # EventClock only). None is free.
+        self.host_profile: "HostProfile | None" = None
 
     def _account(self, ms: float) -> None:
         with self._charge_lock:
@@ -318,7 +538,11 @@ class BaseClock:
     def pool(self, max_workers: int) -> Any:  # .submit(fn) / .shutdown()
         raise NotImplementedError
 
-    def spawn(self, fn: Callable[[], Any], name: str = "") -> None:
+    def spawn(self, fn: Callable[[], Any], name: str = "",
+              layer: str | None = None) -> None:
+        """Run ``fn()`` (an effect generator) as a new actor. ``layer``
+        names the layer a HostProfile charges its host time to (event
+        substrate only; None: the profile's default)."""
         raise NotImplementedError
 
     def actor(self) -> Any:  # context manager registering current thread
@@ -461,7 +685,8 @@ class RealtimeClock(BaseClock):
     def pool(self, max_workers: int) -> _RealtimePool:
         return _RealtimePool(self, max_workers)
 
-    def spawn(self, fn: Callable[[], Any], name: str = "") -> None:
+    def spawn(self, fn: Callable[[], Any], name: str = "",
+              layer: str | None = None) -> None:
         def body() -> None:
             run_effects(self, fn())
 
@@ -655,7 +880,8 @@ class VirtualClock(BaseClock):
         with self.actor():
             return run_effects(self, gen)
 
-    def spawn(self, fn: Callable[[], Any], name: str = "") -> None:
+    def spawn(self, fn: Callable[[], Any], name: str = "",
+              layer: str | None = None) -> None:
         # The actor slot enters the ready queue HERE, on the spawning
         # thread, so scheduling order is a pure function of the event
         # sequence — not of how quickly the OS starts (or recycles) the
@@ -958,7 +1184,7 @@ class _Frame:
 
     __slots__ = ("seq", "fn", "gen", "name", "wait", "wake_reason", "timer",
                  "deferred_ms", "charge_acc", "task_clock", "sink",
-                 "done", "root", "result", "exc")
+                 "layer", "done", "root", "result", "exc")
 
     def __init__(self, seq: int, fn: "Callable[[], Any] | None",
                  name: str, root: bool = False):
@@ -973,6 +1199,7 @@ class _Frame:
         self.charge_acc: "list[float] | None" = None
         self.task_clock: Any = None
         self.sink: Any = None    # kv-stats sink (namespace mirroring)
+        self.layer: "str | None" = None  # HostProfile: the layer charged
         self.done = False
         self.root = root
         self.result: Any = None
@@ -1148,6 +1375,9 @@ class EventClock(BaseClock):
     def _step(self, frame: _Frame, value: Any, exc: "BaseException | None",
               replay: "tuple[Any, ...] | None") -> None:
         _frame_ctx.frame = frame
+        prof = self.host_profile
+        if prof is not None:
+            prof.step_begin(frame)
         try:
             gen = frame.gen
             if gen is None:
@@ -1250,6 +1480,8 @@ class EventClock(BaseClock):
                 return
         finally:
             _frame_ctx.frame = None
+            if prof is not None:
+                prof.step_end()
 
     def _retire(self, frame: _Frame, result: Any) -> None:
         frame.result = result
@@ -1280,9 +1512,11 @@ class EventClock(BaseClock):
         traceback.print_exception(type(exc), exc, exc.__traceback__)
 
     # -- actor lifecycle ----------------------------------------------------
-    def spawn(self, fn: Callable[[], Any], name: str = "") -> None:
+    def spawn(self, fn: Callable[[], Any], name: str = "",
+              layer: str | None = None) -> None:
         with self._mutex:
             frame = _Frame(next(self._seq), fn, name)
+            frame.layer = layer
             self._ready.append(frame)
             self.actors_spawned += 1
             self._cond.notify_all()
