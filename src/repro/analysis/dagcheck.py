@@ -329,8 +329,20 @@ def check_compiled(dag: Any) -> None:
     """Verify a :class:`~repro.core.optimize.CompiledDAG`'s annotations
     against its own graph (``compile_dag`` runs this on every result):
     cluster ids map member tasks to member tasks, delayed fan-ins are
-    true fan-in nodes, and ``leaf_batches`` partition the leaves."""
+    true fan-in nodes, ``leaf_batches`` partition the leaves, and each
+    fused task's provenance ends with its own key and names keys that no
+    other fused task names and that are no longer tasks."""
     tasks = dag.tasks
+    replaced: set[str] = set()
+    for k, keys in dag.fused.items():
+        if k not in tasks or not keys or keys[-1] != k:
+            raise ConsistencyError(
+                f"fused provenance of {k!r} must end with a task's own key")
+        for x in keys:
+            if x in replaced or (x != k and x in tasks):
+                raise ConsistencyError(
+                    f"fused key {x!r} is still a task or fused twice")
+            replaced.add(x)
     for k, cid in dag.clusters.items():
         if k not in tasks or cid not in tasks:
             raise ConsistencyError(

@@ -638,8 +638,9 @@ class CentralizedConfig:
     num_invokers: int = 1          # >1 = parallel-invoker version
     max_concurrency: int = 4096    # lazily-created runtime workers
     job_timeout_s: float = 600.0   # simulated s under VirtualClock
-    # DAG compiler pipeline (chain fusion shrinks the one-Lambda-per-task
-    # graph; the executor-level passes are no-ops here). None = verbatim.
+    # DAG compiler pipeline (producer inlining and chain fusion shrink the
+    # one-Lambda-per-task graph; the executor-level passes are no-ops
+    # here). None = verbatim.
     optimize: OptimizeConfig | None = None
     # Stateful FaaS platform model; None = legacy stochastic draw.
     platform: PlatformConfig | None = None

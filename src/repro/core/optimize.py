@@ -5,9 +5,23 @@ keeping data local to executors (§IV-B–C, §V-B); the follow-up work
 (*Wukong: A Scalable and Locality-Enhanced Framework for Serverless
 Parallel Computing*, PAPERS.md) goes further with task clustering and
 delayed I/O to cut KV-store round trips. This module implements that
-compiler layer as three composable passes over a ``DAG``:
+compiler layer as four composable passes over a ``DAG``:
 
-1. **Linear-chain fusion** (``fuse_chains``): a dependency edge u -> v
+1. **Producer inlining** (``inline_producers``): a task whose body is a
+   bare ``jax.jit`` function of task outputs only absorbs, recursively,
+   every dependency that it alone consumes and that is itself such a
+   task, up to ``max_fusion_len`` tasks. The group becomes one task keyed
+   by its consumer: one jitted program that evaluates the members in
+   topological order, so the absorbed values never leave the device
+   program and the group costs one dispatch, not one per member. Groups
+   of the same structure (member functions and argument wiring) share
+   one compiled program. Where the members average more operations on
+   their inputs than ``ONE_PROGRAM_MAX_FLOPS``, the device and not the
+   dispatches sets the pace, and the task calls them one by one instead.
+   Costed wrappers, partials, closures and literal arguments are not
+   jitted functions of task outputs, so they never engage.
+
+2. **Linear-chain fusion** (``fuse_chains``): a dependency edge u -> v
    with out-degree(u) == 1 and in-degree(v) == 1 carries a value that
    exactly one consumer will ever read. Maximal runs of such edges are
    collapsed into one fused task keyed by the chain tail, so the
@@ -19,7 +33,7 @@ compiler layer as three composable passes over a ``DAG``:
    but no interior edge touches a node with in-degree or out-degree
    above one.
 
-2. **Task clustering** (``cluster_tasks``): annotates every node with a
+3. **Task clustering** (``cluster_tasks``): annotates every node with a
    cluster id — the head of the static *become-path* that a Task
    Executor walks (trivial fan-outs and first-child become edges), with
    fan-in nodes joining the cluster of their primary (first) parent.
@@ -32,7 +46,7 @@ compiler layer as three composable passes over a ``DAG``:
    paper; it deterministically saves one KV ``set`` (plus one base
    round-trip per arriver) at every clustered fan-in node.
 
-3. **Fan-out coalescing** (``coalesce_fanouts``): sibling leaves that
+4. **Fan-out coalescing** (``coalesce_fanouts``): sibling leaves that
    share an identical child signature are grouped into batches (kept
    below the proxy threshold) so one executor invocation runs the whole
    batch, draining the invoker queue ``batch`` times faster on wide
@@ -48,7 +62,9 @@ topological evaluation of the original DAG (see tests/test_optimize.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Iterable, Mapping
+import functools
+import math
+from typing import Any, Callable, Iterable, Mapping
 
 from repro.analysis.dagcheck import check_compiled
 from repro.core.dag import DAG, Task, TaskRef
@@ -58,10 +74,12 @@ from repro.core.dag import DAG, Task, TaskRef
 class OptimizeConfig:
     """Which passes run, and their knobs (all passes default on)."""
 
+    inline_producers: bool = True
     fuse_chains: bool = True
     cluster_tasks: bool = True
     coalesce_fanouts: bool = True
-    max_fusion_len: int = 64     # split pathological chains for retry granularity
+    max_fusion_len: int = 64     # cap on a fused chain or inlined group,
+                                 # for retry granularity
     coalesce_batch: int = 7      # max leaves per batched invocation; kept
                                  # below the default proxy threshold (8) so
                                  # batched spawns stay on the fast path
@@ -72,7 +90,8 @@ ALL_PASSES = OptimizeConfig()
 #: Convenience preset: the identity pipeline (compile_dag returns an
 #: annotated but unrewritten graph).
 NO_PASSES = OptimizeConfig(
-    fuse_chains=False, cluster_tasks=False, coalesce_fanouts=False
+    inline_producers=False, fuse_chains=False, cluster_tasks=False,
+    coalesce_fanouts=False,
 )
 
 
@@ -96,7 +115,8 @@ class CompiledDAG(DAG):
     ``leaf_batches``    — tuple of leaf-key tuples; each batch is started
                           by ONE executor invocation. Covers every leaf
                           (singleton batches when coalescing is off).
-    ``fused``           — fused task key -> original chain keys, head first.
+    ``fused``           — fused task key -> the original keys it replaces,
+                          in evaluation order, the key itself last.
     ``pass_stats``      — per-pass before/after report.
     """
 
@@ -124,7 +144,222 @@ class CompiledDAG(DAG):
 
 
 # ---------------------------------------------------------------------------
-# Pass 1: linear-chain fusion
+# Pass 1: producer inlining
+# ---------------------------------------------------------------------------
+
+
+def _is_jitted(fn: Any) -> bool:
+    """A ``jax.jit`` function, by duck typing (no JAX import here)."""
+    return (callable(getattr(fn, "lower", None))
+            and callable(getattr(fn, "trace", None)))
+
+
+def _inlinable(task: Task) -> bool:
+    return (not task.kwargs and _is_jitted(task.fn)
+            and all(isinstance(a, TaskRef) for a in task.args))
+
+
+def find_producer_groups(dag: DAG, max_len: int = 64) -> list[list[str]]:
+    """Groups of two or more inlinable tasks, each in evaluation order
+    with its consumer (the group's key) last.
+
+    Consumers are visited from the sinks up, so a task is absorbed by its
+    one consumer's group before it could root a group of its own. A group
+    absorbs a dependency only if the group is that task's sole consumer.
+    """
+    tasks, deps, children = dag.tasks, dag.deps, dag.children
+    ok = {k for k, t in tasks.items() if _inlinable(t)}
+    if not ok:
+        return []
+    taken: set[str] = set()
+    groups: list[list[str]] = []
+    for root in reversed(dag.topological_order()):
+        if root not in ok or root in taken:
+            continue
+        members = {root}
+        frontier = [root]
+        while frontier and len(members) < max_len:
+            nxt = []
+            for k in frontier:
+                for d in deps[k]:
+                    if (len(members) < max_len and d in ok
+                            and len(children[d]) == 1):
+                        members.add(d)
+                        nxt.append(d)
+            frontier = nxt
+        if len(members) > 1:
+            taken |= members
+            groups.append(_evaluation_order(root, members, tasks))
+    return groups
+
+
+def _evaluation_order(root: str, members: set[str],
+                      tasks: Mapping[str, Task]) -> list[str]:
+    """Post-order from ``root`` through the members, args in order: a
+    topological order fixed by the group's structure, not its keys."""
+    order: list[str] = []
+    seen = {root}
+    stack = [(root, iter(tasks[root].args))]
+    while stack:
+        k, args = stack[-1]
+        for a in args:
+            if a.key in members and a.key not in seen:
+                seen.add(a.key)
+                stack.append((a.key, iter(tasks[a.key].args)))
+                break
+        else:
+            stack.pop()
+            order.append(k)
+    return order
+
+
+#: Operations that a group's members may average for the group to run as
+#: one program: 2^34, about 87 us at a TPU v5e's bf16 peak, against some
+#: 280 us of host time to dispatch one program there. Above it each
+#: member keeps the device busy for longer than its dispatch takes, so one
+#: program saves no waiting, and it costs device time: on the v5e a 4096^2
+#: group's second product ran 0.93 ms inside the group against 0.72 alone.
+ONE_PROGRAM_MAX_FLOPS = 2.0 ** 34
+
+
+def _evaluate(fns: tuple[Callable[..., Any], ...],
+              wiring: tuple[tuple[tuple[bool, int], ...], ...],
+              inputs: tuple[Any, ...],
+              wrap: Callable[[Any], Any] = lambda v: v) -> Any:
+    """Call the members in evaluation order on the group's inputs and
+    earlier members' values, as ``wiring`` says; the last member's value."""
+    vals: list[Any] = []
+    for fn, args in zip(fns, wiring):
+        vals.append(wrap(fn(*[vals[i] if member else inputs[i]
+                              for member, i in args])))
+    return vals[-1]
+
+
+@functools.lru_cache(maxsize=256)
+def _group_program(fns: tuple[Callable[..., Any], ...],
+                   wiring: tuple[tuple[tuple[bool, int], ...], ...]):
+    """One jitted program evaluating a group, cached by its structure:
+    member functions in evaluation order and, per argument, whether it is
+    an earlier member's value or an input, and which. Bounded, since DAGs
+    built from per-call jitted closures give a new structure each time.
+
+    Each member's value passes an optimization barrier, so XLA does not
+    fuse one member into the next: on a TPU v5e, each 4096^2 block sum
+    folded into the product before it took more device time than the two
+    programs. The program still places and prefetches its buffers as one
+    program, so a member may run slower inside it than alone."""
+    import jax
+
+    def program(*inputs: Any) -> Any:
+        return _evaluate(fns, wiring, inputs, jax.lax.optimization_barrier)
+
+    counts: dict[str, int] = {}
+    for fn in fns:
+        name = getattr(fn, "__name__", "fn").strip("_")
+        counts[name] = counts.get(name, 0) + 1
+    program.__name__ = "inlined_" + "_".join(f"{n}{c}"
+                                             for n, c in counts.items())
+    return jax.jit(program)
+
+
+def _operations(jaxpr: Any) -> float:
+    """Operations of a jaxpr: 2 m n k for each (m, k) @ (k, n) matrix
+    product, batch dimensions included, and one an output element for
+    every other equation; a nested jaxpr counts in place of the equation
+    that calls it, once (a loop's body is not multiplied by its trips)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    total = 0.0
+    for eqn in jaxpr.eqns:
+        inner = [p.jaxpr if isinstance(p, ClosedJaxpr) else p
+                 for v in eqn.params.values()
+                 for p in (v if isinstance(v, tuple) else (v,))
+                 if isinstance(p, (ClosedJaxpr, Jaxpr))]
+        if inner:
+            total += sum(_operations(j) for j in inner)
+        elif eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            k = math.prod(eqn.invars[0].aval.shape[d] for d in contract)
+            total += 2.0 * k * math.prod(eqn.outvars[0].aval.shape)
+        else:
+            total += sum(math.prod(getattr(v.aval, "shape", ()))
+                         for v in eqn.outvars)
+    return total
+
+
+@functools.lru_cache(maxsize=256)
+def _flops_per_member(fns: tuple[Callable[..., Any], ...],
+                      wiring: tuple[tuple[tuple[bool, int], ...], ...],
+                      tree: Any, leaves: tuple[tuple[Any, Any], ...]) -> float:
+    """Operations of the members on inputs of these shapes and dtypes,
+    counted on their traced jaxpr (the same on every backend), per member."""
+    import jax
+
+    specs = tree.unflatten([jax.ShapeDtypeStruct(s, d) for s, d in leaves])
+    traced = jax.make_jaxpr(lambda *xs: _evaluate(fns, wiring, xs))(*specs)
+    return _operations(traced.jaxpr) / len(fns)
+
+
+class InlinedGroup:
+    """The body of an inlined group's task, named as its ``program``.
+
+    On inputs whose members average fewer than ``ONE_PROGRAM_MAX_FLOPS``
+    operations it runs the group's one shared program. Otherwise it calls
+    the members one by one, as the graph before the pass would: the group
+    then saves the store round trips and scheduling of its absorbed tasks,
+    not their dispatches. The count is taken once per group structure and
+    input shapes."""
+
+    def __init__(self, fns: tuple[Callable[..., Any], ...],
+                 wiring: tuple[tuple[tuple[bool, int], ...], ...]) -> None:
+        self.fns, self.wiring = fns, wiring
+        self.program = _group_program(fns, wiring)
+        self.__name__ = self.program.__name__
+
+    def __call__(self, *inputs: Any) -> Any:
+        import jax
+
+        leaves, tree = jax.tree_util.tree_flatten(inputs)
+        shapes = tuple((x.shape, x.dtype) if hasattr(x, "dtype")
+                       else (jax.typeof(x).shape, jax.typeof(x).dtype)
+                       for x in leaves)
+        if (_flops_per_member(self.fns, self.wiring, tree, shapes)
+                < ONE_PROGRAM_MAX_FLOPS):
+            return self.program(*inputs)
+        return _evaluate(self.fns, self.wiring, inputs)
+
+
+def inline_producer_groups(
+    dag: DAG, max_len: int = 64
+) -> tuple[list[Task], dict[str, tuple[str, ...]]]:
+    """Rewrite: each group becomes one task keyed by its consumer, whose
+    args are the group's external inputs, de-duplicated, in order of first
+    use, and whose fn runs the group (:class:`InlinedGroup`)."""
+    drop: set[str] = set()
+    replace: dict[str, Task] = {}
+    provenance: dict[str, tuple[str, ...]] = {}
+    for order in find_producer_groups(dag, max_len):
+        index = {k: i for i, k in enumerate(order)}
+        inputs: dict[str, int] = {}
+        wiring = tuple(
+            tuple((True, index[a.key]) if a.key in index
+                  else (False, inputs.setdefault(a.key, len(inputs)))
+                  for a in dag.tasks[k].args)
+            for k in order)
+        fns = tuple(dag.tasks[k].fn for k in order)
+        root = order[-1]
+        drop.update(order[:-1])
+        replace[root] = Task(key=root, fn=InlinedGroup(fns, wiring),
+                             args=tuple(TaskRef(k) for k in inputs))
+        provenance[root] = tuple(order)
+    out = [
+        replace.get(k, t) for k, t in dag.tasks.items() if k not in drop
+    ]
+    return out, provenance
+
+
+# ---------------------------------------------------------------------------
+# Pass 2: linear-chain fusion
 # ---------------------------------------------------------------------------
 
 
@@ -218,7 +453,7 @@ def fuse_linear_chains(
 
 
 # ---------------------------------------------------------------------------
-# Pass 2: task clustering (annotation only)
+# Pass 3: task clustering (annotation only)
 # ---------------------------------------------------------------------------
 
 
@@ -248,7 +483,7 @@ def compute_clusters(dag: DAG) -> tuple[dict[str, str], frozenset[str]]:
 
 
 # ---------------------------------------------------------------------------
-# Pass 3: fan-out coalescing (annotation only)
+# Pass 4: fan-out coalescing (annotation only)
 # ---------------------------------------------------------------------------
 
 
@@ -279,18 +514,35 @@ def compile_dag(dag: DAG, config: OptimizeConfig | None = None) -> CompiledDAG:
     fused: dict[str, tuple[str, ...]] = {}
     working = dag
 
-    if cfg.fuse_chains:
+    # Each rewrite pass rebuilds the graph only when it changed something:
+    # host-side compilation is a measured hot path on wide DAGs that no
+    # pass rewrites, like tree reductions.
+    if cfg.inline_producers:
         before = len(working)
-        task_list, fused = fuse_linear_chains(working, cfg.max_fusion_len)
+        task_list, fused = inline_producer_groups(working, cfg.max_fusion_len)
         if fused:
             working = DAG(task_list)
             tasks = working.tasks.values()
-        # else: no fusible chains — skip rebuilding (and re-validating)
-        # the whole graph; host-side schedule generation is a measured
-        # hot path on wide fusion-free DAGs like tree reductions.
+        stats.append(PassStats(
+            name="inline_producers", before_tasks=before,
+            after_tasks=len(working),
+            detail=(f"{len(fused)} programs formed, "
+                    f"{before - len(working)} tasks absorbed"),
+        ))
+
+    if cfg.fuse_chains:
+        before = len(working)
+        task_list, chains = fuse_linear_chains(working, cfg.max_fusion_len)
+        if chains:
+            working = DAG(task_list)
+            tasks = working.tasks.values()
+        # Provenance in original keys: an inlined group fused into a chain
+        # contributes its members in place of its own key.
+        for tail, chain in chains.items():
+            fused[tail] = tuple(x for c in chain for x in fused.pop(c, (c,)))
         stats.append(PassStats(
             name="fuse_chains", before_tasks=before, after_tasks=len(working),
-            detail=f"{len(fused)} chains fused",
+            detail=f"{len(chains)} chains fused",
         ))
 
     clusters: dict[str, str] = {}

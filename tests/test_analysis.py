@@ -267,6 +267,25 @@ def test_compiled_dag_leaf_batch_partition_check():
         check_compiled(compiled)
 
 
+@pytest.mark.parametrize("tamper,match", [
+    (lambda c, k: c.fused.update({k: c.fused[k][:-1]}), "must end with"),
+    (lambda c, k: c.fused.update(
+        {"gemm-C-0-1": c.fused[k][:1] + c.fused["gemm-C-0-1"]}), "fused twice"),
+    (lambda c, k: c.fused.update({k: ("gemm-A-0-0",) + c.fused[k]}),
+     "still a task"),
+])
+def test_compiled_dag_fused_provenance_check(tamper, match):
+    from repro.apps import gemm_dag
+
+    compiled = compile_dag(gemm_dag(128, 64))  # inlined groups, then chains
+    key = "gemm-C-0-0"
+    assert compiled.fused[key][-2:] == ("gemm-S-0-0-0-0", key)
+    check_compiled(compiled)
+    tamper(compiled, key)
+    with pytest.raises(ConsistencyError, match=match):
+        check_compiled(compiled)
+
+
 # ---------------------------------------------------------------------------
 # Runtime determinism sanitizer (trace mode + diff_traces)
 # ---------------------------------------------------------------------------

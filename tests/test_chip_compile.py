@@ -4,8 +4,10 @@ The TPU compiler is installed beside the CPU backend and compiles for a
 described, unattached ``v5e:2x2`` topology. It refuses what interpret
 mode accepts: block shapes the tiling cannot take, too much VMEM, a
 program too large for HBM. Each case compiles one program for one chip:
-the Pallas kernels at smollm_360m and xlstm_350m widths, and the block
-programs of the GEMM and TSQR jobs at the sizes ``chip_smoke.py`` runs.
+the Pallas kernels at smollm_360m and xlstm_350m widths, the block
+programs of the GEMM and TSQR jobs at the sizes ``chip_smoke.py`` runs,
+and the inlined GEMM program of the chip benchmark's 1024^2 blocks (its
+4096^2 groups run member by member).
 
 The topology is described only inside the fixture. Describing it loads
 the TPU library, which one process at a time may hold, so it must not
@@ -18,9 +20,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.apps.gemm import _add, _matmul
+from repro.apps.gemm import _add, _matmul, gemm_dag
 from repro.apps.svd import _qr_r, _singular_values, _stack_qr_r
 from repro.configs import get_config
+from repro.core import OptimizeConfig, compile_dag
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.linear_attention import mlstm_chunk
@@ -91,6 +94,17 @@ def _gemm_block(fn):
     return case
 
 
+def _gemm_inlined(grid, block):
+    """The program the DAG compiler makes of one output block's products
+    and sums; its structure depends on the grid, not on the block size."""
+    def case(sds):
+        dag = compile_dag(gemm_dag(8 * grid, 8), OptimizeConfig(
+            fuse_chains=False, cluster_tasks=False, coalesce_fanouts=False))
+        fn = dag.tasks[dag.deps["gemm-C-0-0"][0]].fn.program
+        return fn, (sds((block, block), jnp.float32),) * (2 * grid)
+    return case
+
+
 def _tsqr(fn, *shapes):
     def case(sds):
         return fn, tuple(sds(s, jnp.float32) for s in shapes)
@@ -103,6 +117,7 @@ CASES = {
     "mlstm_chunk-xlstm_350m": _mlstm,
     "gemm_matmul-1024": _gemm_block(_matmul),
     "gemm_add-1024": _gemm_block(_add),
+    "gemm_inlined-8x8-1024": _gemm_inlined(8, 1024),
     "tsqr_qr_r-16384x128": _tsqr(_qr_r, (16384, 128)),
     "tsqr_stack_qr_r-128": _tsqr(_stack_qr_r, (128, 128), (128, 128)),
     "tsqr_singular_values-128": _tsqr(_singular_values, (128, 128)),

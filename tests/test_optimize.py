@@ -6,10 +6,13 @@ under every pass combination. Pass-specific invariants: fusion never
 crosses a fan-in/fan-out boundary, clustering strictly reduces KV ``set``
 counts, coalescing strictly reduces executor invocations.
 """
+import functools
 import itertools
 import operator
 import random
 
+import jax
+import numpy as np
 import pytest
 
 from repro.core import (
@@ -33,6 +36,7 @@ from repro.core.optimize import (
     coalesce_leaves,
     compute_clusters,
     find_chains,
+    find_producer_groups,
     fuse_linear_chains,
     fusible_edges,
 )
@@ -227,6 +231,268 @@ def test_fused_task_preserves_kwargs_and_literals():
     assert rep.results == {"b": 45}
 
 
+# -- pass invariants: producer inlining ------------------------------------
+
+INLINE_ONLY = OptimizeConfig(fuse_chains=False, cluster_tasks=False,
+                             coalesce_fanouts=False)
+NO_INLINE = OptimizeConfig(inline_producers=False)
+
+
+@jax.jit
+def _neg(x):
+    return -x
+
+
+@jax.jit
+def _twice(x):
+    return 2.0 * x
+
+
+@jax.jit
+def _sub(x, y):
+    return x - y
+
+
+def _producer_dag(case):
+    """src -> p -> c, with ``p`` built as ``case`` says; ``c`` is a jitted
+    function of task outputs, so it roots a group whenever ``p`` may join."""
+    from repro.apps.costing import flop_costed
+
+    g = GraphBuilder()
+    src = g.add(lambda: np.arange(4.0, dtype=np.float32), name="src")
+    if case == "exclusive_jitted":
+        p = g.add(_neg, src, name="p")
+    elif case == "two_consumers":
+        p = g.add(_neg, src, name="p")
+        g.add(_twice, p, name="other")
+    elif case == "costed_wrapper":
+        p = g.add(flop_costed(_neg, 4.0, ms_per_flop=0.5), src, name="p")
+    elif case == "partial":
+        p = g.add(functools.partial(_sub, y=1.0), src, name="p")
+    elif case == "numpy_body":
+        p = g.add(np.negative, src, name="p")
+    elif case == "literal_arg":
+        p = g.add(_sub, src, 1.0, name="p")
+    g.add(_twice, p, name="c")
+    return g.build()
+
+
+@pytest.mark.parametrize("case,absorbed", [
+    ("exclusive_jitted", True),
+    ("two_consumers", False),
+    ("costed_wrapper", False),
+    ("partial", False),
+    ("numpy_body", False),
+    ("literal_arg", False),
+])
+def test_inlining_absorbs_only_exclusive_jitted_producers(case, absorbed):
+    dag = _producer_dag(case)
+    compiled = compile_dag(dag, INLINE_ONLY)
+    assert ("p" not in compiled.tasks) is absorbed
+    assert (compiled.fused.get("c") == ("p", "c")) is absorbed
+    assert compiled.deps["c"] == (("src",) if absorbed else ("p",))
+    want = seq_eval(dag)
+    got = WukongEngine().compute(compiled).results
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
+
+
+def test_inlining_caps_a_group_at_max_fusion_len():
+    g = GraphBuilder()
+    cur = g.add(lambda: np.float32(1.0), name="src")
+    for i in range(10):
+        cur = g.add(_twice, cur, name=f"j{i}")
+    dag = g.build()
+    groups = find_producer_groups(dag, max_len=4)
+    assert [len(grp) for grp in groups] == [4, 4, 2]
+    compiled = compile_dag(dag, OptimizeConfig(
+        max_fusion_len=4, fuse_chains=False, cluster_tasks=False,
+        coalesce_fanouts=False))
+    assert len(compiled) == 1 + 3  # src and three groups
+    assert all(len(keys) <= 4 for keys in compiled.fused.values())
+    assert WukongEngine().compute(compiled).results == {"j9": 1024.0}
+
+
+def test_inlined_programs_cache_is_bounded_for_per_call_closures():
+    from repro.core.optimize import _group_program
+
+    def dag_with_fresh_closures():
+        g = GraphBuilder()
+        src = g.add(lambda: np.float32(1.0), name="src")
+        p = g.add(jax.jit(lambda x: x + 1), src, name="p")
+        g.add(jax.jit(lambda x: x * 2), p, name="c")
+        return g.build()
+
+    cap = _group_program.cache_info().maxsize
+    for _ in range(cap + 8):
+        compile_dag(dag_with_fresh_closures(), INLINE_ONLY)
+    assert _group_program.cache_info().currsize <= cap
+
+
+@pytest.mark.parametrize("n,block,config,tasks", [
+    (512, 64, ALL_PASSES, 192),
+    (128, 64, ALL_PASSES, 12),
+    (512, 64, NO_INLINE, 1088),
+    (128, 64, NO_INLINE, 20),
+])
+def test_inlining_gemm_task_counts(n, block, config, tasks):
+    from repro.apps import gemm_dag
+
+    compiled = compile_dag(gemm_dag(n, block), config)
+    assert len(compiled) == tasks
+    inline = [s for s in compiled.pass_stats if s.name == "inline_producers"]
+    if config.inline_producers:
+        b = n // block
+        (row,) = inline
+        assert row.detail == (f"{b * b} programs formed, "
+                              f"{b * b * (2 * b - 2)} tasks absorbed")
+    else:
+        assert inline == []
+
+
+@pytest.mark.parametrize("n", [512, 128])
+def test_inlined_gemm_matches_unfused_and_traces_once(n, monkeypatch):
+    from repro.apps import gemm_dag
+    from repro.core import optimize
+
+    traces = []
+    evaluate = optimize._evaluate
+
+    def counting(fns, wiring, inputs, wrap=lambda v: v):
+        if wrap is jax.lax.optimization_barrier:  # the program's body
+            traces.append(len(fns))
+        return evaluate(fns, wiring, inputs, wrap)
+
+    monkeypatch.setattr(optimize, "_evaluate", counting)
+
+    def run(config, seed_a, seed_b):
+        dag = gemm_dag(n, 64, seed_a=seed_a, seed_b=seed_b)
+        return WukongEngine(EngineConfig(optimize=config)).compute(dag).results
+
+    seeds = [(11, 12), (13, 14)]
+    traced = []
+    for sa, sb in seeds:
+        on = run(ALL_PASSES, sa, sb)
+        traced.append(len(traces))
+        off = run(NO_INLINE, sa, sb)
+        assert on.keys() == off.keys() and len(on) == (n // 64) ** 2
+        for k in on:
+            np.testing.assert_allclose(np.asarray(on[k]), np.asarray(off[k]),
+                                       rtol=1e-5, atol=1e-5)
+    # The second job, on other seeds, traced nothing: every output block of
+    # both jobs ran one program, compiled once. The root aliases'
+    # producers are the groups' tasks.
+    assert traced[1] == traced[0] <= 1
+    programs = set()
+    for sa, sb in seeds:
+        dag = compile_dag(gemm_dag(n, 64, seed_a=sa, seed_b=sb), INLINE_ONLY)
+        programs |= {dag.tasks[dag.deps[r][0]].fn.program for r in dag.roots}
+    (program,) = programs
+    assert program._cache_size() == 1
+
+
+@pytest.mark.parametrize("limit,one_program", [
+    (float("inf"), True),
+    (0.0, False),
+])
+def test_inlined_group_runs_one_program_below_the_flops_limit(
+        limit, one_program, monkeypatch):
+    from repro.apps import gemm_dag
+    from repro.core import optimize
+
+    member_calls = []
+    evaluate = optimize._evaluate
+
+    def counting(fns, wiring, inputs, wrap=lambda v: v):
+        if wrap is not jax.lax.optimization_barrier:  # member by member
+            member_calls.append(len(fns))
+        return evaluate(fns, wiring, inputs, wrap)
+
+    monkeypatch.setattr(optimize, "_evaluate", counting)
+    monkeypatch.setattr(optimize, "ONE_PROGRAM_MAX_FLOPS", limit)
+    dag = gemm_dag(128, 64, seed_a=21, seed_b=22)
+    on = WukongEngine(EngineConfig(optimize=ALL_PASSES)).compute(dag)
+    off = WukongEngine(EngineConfig(optimize=NO_INLINE)).compute(
+        gemm_dag(128, 64, seed_a=21, seed_b=22))
+    assert on.tasks == 12 and off.tasks == 20
+    assert member_calls == ([] if one_program else [3] * 4)
+    for k in off.results:
+        np.testing.assert_allclose(np.asarray(on.results[k]),
+                                   np.asarray(off.results[k]),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("grid,block,one_program", [
+    (2, 64, True),
+    (8, 1024, True),     # gemm_8192.b1024: 1.15e9 operations a member
+    (2, 4096, False),    # gemm_8192.b4096: 9.16e10 operations a member
+])
+def test_flops_per_member_of_gemm_groups(grid, block, one_program):
+    import jax.numpy as jnp
+
+    from repro.apps import gemm_dag
+    from repro.core.optimize import ONE_PROGRAM_MAX_FLOPS, _flops_per_member
+
+    dag = compile_dag(gemm_dag(64 * grid, 64), INLINE_ONLY)
+    group = dag.tasks[dag.deps["gemm-C-0-0"][0]].fn
+    leaves = ((block, block), jnp.dtype(jnp.float32))
+    tree = jax.tree_util.tree_structure((0,) * (2 * grid))
+    flops = _flops_per_member(group.fns, group.wiring, tree,
+                              (leaves,) * (2 * grid))
+    members = 2 * grid - 1
+    assert flops == (grid * 2 * block ** 3 + (grid - 1) * block ** 2) / members
+    assert (flops < ONE_PROGRAM_MAX_FLOPS) is one_program
+
+
+def test_inlined_tsqr_matches_expected():
+    from repro.apps.svd import tsqr_singular_values_expected, tsqr_svd_dag
+
+    compiled = compile_dag(tsqr_svd_dag(1024, 32, 8), ALL_PASSES)
+    assert compiled.tasks["svd1-R3-0"].fn.__name__.startswith("inlined_")
+    s = WukongEngine().compute(compiled).results["svd1-S"]
+    np.testing.assert_allclose(np.asarray(s),
+                               tsqr_singular_values_expected(1024, 32, 8),
+                               rtol=1e-4)
+
+
+def _bypass_dags():
+    from repro.apps import gemm_dag
+    from repro.runtime.orchestrator import build_training_workflow
+
+    train, _, _ = build_training_workflow(
+        n_steps=4, step_fn=lambda st, b: (st + b, {"loss": st}),
+        init_fn=lambda: 0.0, data_fn=lambda i: float(i + 1))
+    return {"costed_gemm": gemm_dag(128, 64, ms_per_flop=1e-6),
+            "training_workflow": train}
+
+
+@pytest.mark.parametrize("name", ["costed_gemm", "training_workflow"])
+def test_inlining_bypasses_costed_and_closure_bodies(name):
+    on = WukongEngine(EngineConfig(optimize=ALL_PASSES)).compute(
+        _bypass_dags()[name])
+    off = WukongEngine(EngineConfig(optimize=NO_INLINE)).compute(
+        _bypass_dags()[name])
+    assert on.optimizer[0].name == "inline_producers"
+    assert on.optimizer[0].after_tasks == on.optimizer[0].before_tasks
+    assert on.tasks == off.tasks
+    assert on.charged_ms == off.charged_ms
+    assert on.kv_stats == off.kv_stats
+    assert on.results.keys() == off.results.keys()
+
+
+def test_training_workflow_runs_without_the_compiler():
+    from repro.runtime.orchestrator import run_training_workflow
+    from repro.runtime.orchestrator import build_training_workflow
+
+    dag, final, metrics = build_training_workflow(
+        n_steps=3, step_fn=lambda st, b: (st + b, {}), init_fn=lambda: 0.0,
+        data_fn=lambda i: 1.0)
+    rep = run_training_workflow(dag, final, metrics).report
+    assert rep.optimizer == ()
+    assert rep.results[final] == 3.0
+
+
 # -- pass invariants: clustering (delayed I/O) ------------------------------
 
 
@@ -330,8 +596,10 @@ def test_pass_stats_reported():
     rep = WukongEngine(
         EngineConfig(optimize=ALL_PASSES)).compute(mixed_dag())
     names = [s.name for s in rep.optimizer]
-    assert names == ["fuse_chains", "cluster_tasks", "coalesce_fanouts"]
-    fuse = rep.optimizer[0]
+    assert names == ["inline_producers", "fuse_chains", "cluster_tasks",
+                     "coalesce_fanouts"]
+    inline, fuse = rep.optimizer[:2]
+    assert inline.after_tasks == inline.before_tasks  # lambdas: no engagement
     assert fuse.after_tasks < fuse.before_tasks
 
 
