@@ -21,6 +21,7 @@ ARCHS = [
     "mixtral_8x22b",
     "chameleon_34b",
     "whisper_large_v3",
+    "deepseek_v2_lite",
 ]
 
 ALIASES = {a.replace("_", "-"): a for a in ARCHS}
@@ -47,10 +48,16 @@ def reduced(cfg: ModelConfig, seq_len: int = 64) -> ModelConfig:
     moe = cfg.moe
     if moe is not None:
         moe = dataclasses.replace(moe, n_experts=min(4, moe.n_experts),
-                                  top_k=min(2, moe.top_k))
+                                  top_k=min(2, moe.top_k),
+                                  d_expert=32 if moe.d_expert else None)
+    mla = cfg.mla
+    if mla is not None:
+        mla = dataclasses.replace(mla, kv_lora_rank=32, qk_nope_dim=16,
+                                  qk_rope_dim=8, v_dim=16)
     return dataclasses.replace(
         cfg,
-        n_layers=2 * period,
+        n_layers=cfg.n_dense_lead + 2 * period,
+        mla=mla,
         d_model=64,
         n_heads=n_heads,
         n_kv_heads=n_kv,

@@ -435,6 +435,9 @@ def in_layer(layer: str, op: Callable[..., Any]) -> Callable[..., Any]:
 _WORKER_CACHE_MAX = 2048
 _worker_cache: "list[_CachedWorker]" = []
 _worker_cache_lock = threading.Lock()
+# Bumped by every drain: a worker whose job was dispatched before the
+# latest drain retires when the job ends instead of parking.
+_worker_cache_epoch = 0
 
 
 class _CachedWorker(threading.Thread):
@@ -442,6 +445,7 @@ class _CachedWorker(threading.Thread):
         super().__init__(daemon=True, name="simclock-worker")
         self._sem = threading.Semaphore(0)
         self._job: Callable[[], None] | None = None
+        self._epoch = 0
         self.start()
 
     def run(self) -> None:
@@ -452,11 +456,13 @@ class _CachedWorker(threading.Thread):
                 return
             job()  # an escaping exception retires this thread (no recycle)
             with _worker_cache_lock:
-                if len(_worker_cache) >= _WORKER_CACHE_MAX:
+                if (len(_worker_cache) >= _WORKER_CACHE_MAX
+                        or self._epoch != _worker_cache_epoch):
                     return
                 _worker_cache.append(self)
 
     def dispatch(self, job: "Callable[[], None] | None") -> None:
+        self._epoch = _worker_cache_epoch
         self._job = job
         self._sem.release()
 
@@ -471,10 +477,13 @@ def drain_worker_cache() -> int:
     """Retire every cached worker thread and return how many were
     drained. Call between benchmark iterations (or test runs) so idle
     threads from a thread-substrate run don't linger into — and skew
-    the wall-time of — event-substrate runs."""
+    the wall-time of — event-substrate runs. A worker still finishing a
+    job dispatched before the drain retires instead of parking."""
+    global _worker_cache_epoch
     with _worker_cache_lock:
         workers = _worker_cache[:]
         _worker_cache.clear()
+        _worker_cache_epoch += 1
     for worker in workers:
         worker.dispatch(None)  # `run` exits on a None job
     return len(workers)

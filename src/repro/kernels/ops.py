@@ -1,16 +1,19 @@
 """Jitted public wrappers for the Pallas kernels.
 
 On the TPU they run compiled. On the CPU backend, where the test suite
-runs, they run in Pallas interpret mode, validated against ``ref.py``;
-any other backend is an error. The wrappers pad ragged shapes up to
+runs, they run in Pallas interpret mode, validated against ``ref.py``
+(megablox's grouped product against ``jax.lax.ragged_dot``); any other
+backend is an error. The wrappers pad ragged shapes up to
 block multiples and handle layout.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import flash_attention as _fa
@@ -61,3 +64,23 @@ def mlstm_chunk(q, k, v, log_f, i_gate, *, chunk=64):
     return _la.mlstm_chunk(q, k, v, log_f, i_gate,
                            chunk=min(chunk, q.shape[1]),
                            interpret=interpret_mode())
+
+
+# Tiles of the grouped product: 512 rows, and at most 512 x 1408 of the
+# weight a step (a larger tile overflows the v5e's VMEM in the backward
+# pass).
+GMM_ROWS, GMM_WIDE, GMM_NARROW = 512, 1408, 512
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs``'s rows in consecutive groups, group g's times ``rhs[g]``,
+    in float32: megablox's grouped product, with its own backward pass.
+
+    Only the grouped rows are computed: rows past ``sum(group_sizes)``
+    come out undefined, and so does their gradient."""
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tk, tn = (min(k, GMM_NARROW), min(n, GMM_WIDE)) if k >= n else \
+        (min(k, GMM_WIDE), min(n, GMM_NARROW))
+    return _gmm(lhs, rhs, group_sizes, jnp.float32, (math.gcd(m, GMM_ROWS), tk, tn),
+                None, None, False, interpret_mode())

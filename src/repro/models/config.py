@@ -2,10 +2,12 @@
 
 A model is a cycle of block *patterns*. Each pattern entry names a mixer
 and an MLP type, e.g. ``"attn+moe"`` (Mixtral), ``"mamba+dense"`` (Jamba),
-``"mlstm"`` (xLSTM — no separate FFN). Layers are stacked per pattern
-position so ``jax.lax.scan`` can run the repeated super-block with one
-lowered copy of the layer HLO (critical for compile time and HLO size at
-126 layers).
+``"mlstm"`` (xLSTM — no separate FFN), ``"mla+moe"`` (DeepSeek-V2). Layers
+are stacked per pattern position so ``jax.lax.scan`` can run the repeated
+super-block with one lowered copy of the layer HLO (critical for compile
+time and HLO size at 126 layers). ``n_dense_lead`` leading layers of the
+pattern's mixer with a dense MLP of width ``d_ff`` run ahead of it
+(DeepSeek's ``first_k_dense_replace``).
 """
 from __future__ import annotations
 
@@ -14,9 +16,55 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """Routed experts. ``dispatch="capacity"`` is GShard's dispatch (top-k
+    logits, softmax over the k, tokens past an expert's capacity dropped);
+    ``"dropless"`` is DeepSeek-V2's: a softmax over all ``n_experts``, the
+    top k of it as weights (not renormalised, scale 1), no assignment ever
+    dropped, computed for the experts this device holds, ``first_held`` to
+    ``first_held + n_held``."""
+
     n_experts: int
     top_k: int
     router_jitter: float = 0.0
+    dispatch: str = "capacity"          # capacity | dropless
+    d_expert: int | None = None         # expert width; None: the model's d_ff
+    n_shared: int = 0                   # shared experts, d_expert wide each
+    aux_alpha: float = 0.0              # dropless: sequence-level balance loss
+    first_held: int = 0
+    n_held: int | None = None           # None: every expert
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention without query compression (DeepSeek-V2):
+    keys and values come up from a ``kv_lora_rank`` latent; the rotary part
+    of each query and key head is ``qk_rope_dim`` wide, one rotary key
+    shared by every head."""
+
+    kv_lora_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_dim: int
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 states it."""
+
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +80,9 @@ class ModelConfig:
     block_pattern: tuple[str, ...] = ("attn+dense",)
     head_dim: int | None = None
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
+    yarn: YarnConfig | None = None
+    n_dense_lead: int = 0       # leading dense layers ahead of the pattern
     sliding_window: int | None = None
     qkv_bias: bool = False
     activation: str = "swiglu"  # swiglu | squared_relu | gelu
@@ -65,11 +116,17 @@ class ModelConfig:
 
     @property
     def n_repeats(self) -> int:
-        assert self.n_layers % self.pattern_period == 0, (
-            f"{self.name}: n_layers={self.n_layers} not divisible by "
+        n = self.n_layers - self.n_dense_lead
+        assert n % self.pattern_period == 0, (
+            f"{self.name}: {n} patterned layers not divisible by "
             f"pattern period {self.pattern_period}"
         )
-        return self.n_layers // self.pattern_period
+        return n // self.pattern_period
+
+    @property
+    def lead_entry(self) -> str:
+        """The leading dense layers' pattern entry."""
+        return self.mixer_of(self.block_pattern[0]) + "+dense"
 
     @property
     def d_inner(self) -> int:
@@ -91,10 +148,21 @@ class ModelConfig:
         d, hd = self.d_model, self.hd
         per_pattern_total = 0.0
         per_pattern_active = 0.0
-        for entry in self.block_pattern:
+        lead_total = 0.0
+        entries = [(e, 1) for e in self.block_pattern]
+        if self.n_dense_lead:
+            entries.append((self.lead_entry, 0))
+        for entry, patterned in entries:
             mixer, mlp = self.mixer_of(entry), self.mlp_of(entry)
             p = 0.0
-            if mixer == "attn":
+            if mixer == "mla":
+                a, H = self.mla, self.n_heads
+                p += d * H * a.qk_dim                      # q
+                p += d * (a.kv_lora_rank + a.qk_rope_dim)  # kv down + rope key
+                p += a.kv_lora_rank                        # latent norm
+                p += a.kv_lora_rank * H * (a.qk_nope_dim + a.v_dim)  # kv up
+                p += H * a.v_dim * d                       # o
+            elif mixer == "attn":
                 p += d * (self.n_heads * hd)            # q
                 p += 2 * d * (self.n_kv_heads * hd)     # k, v
                 p += (self.n_heads * hd) * d            # o
@@ -119,15 +187,23 @@ class ModelConfig:
                 mult = 3 if self.activation == "swiglu" else 2
                 mlp_total = mlp_active = mult * d * self.d_ff + d
             elif mlp == "moe":
-                assert self.moe is not None
+                moe = self.moe
+                assert moe is not None
                 mult = 3 if self.activation == "swiglu" else 2
-                per_expert = mult * d * self.d_ff
-                mlp_total = self.moe.n_experts * per_expert + d * self.moe.n_experts + d
-                mlp_active = self.moe.top_k * per_expert + d * self.moe.n_experts + d
+                per_expert = mult * d * (moe.d_expert or self.d_ff)
+                # held experts, and the expected share of the k a token meets here
+                routed = moe.top_k * moe.held / moe.n_experts
+                outside = d * moe.n_experts + d + moe.n_shared * per_expert
+                mlp_total = moe.held * per_expert + outside
+                mlp_active = routed * per_expert + outside
+            if not patterned:
+                lead_total += p + mlp_total
+                continue
             per_pattern_total += p + mlp_total
             per_pattern_active += p + mlp_active
-        total = per_pattern_total * self.n_repeats
-        active = per_pattern_active * self.n_repeats
+        lead_total *= self.n_dense_lead
+        total = per_pattern_total * self.n_repeats + lead_total
+        active = per_pattern_active * self.n_repeats + lead_total
         if self.enc_dec:
             # encoder: full-attn + dense mlp, plus decoder cross-attn
             enc_block = (2 * d * (self.n_heads * hd) * 2) / 2  # q,k,v,o approx

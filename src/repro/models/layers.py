@@ -11,11 +11,13 @@ oracle and the CPU/dry-run path.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kernel_ops
 from repro.models.config import ModelConfig
 
 Params = dict[str, Any]
@@ -60,10 +62,12 @@ def rope_freqs(hd: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, n, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               inv_freq: jax.Array | None = None) -> jax.Array:
+    """Rotate-half rotary embedding. x: (..., S, n, hd); positions:
+    broadcastable to (..., S); ``inv_freq`` (hd/2,) in place of theta's."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)  # (hd/2,)
+    freqs = rope_freqs(hd, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # (...,S,hd/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
@@ -127,12 +131,14 @@ def sdpa(
     window: int | None = None,
     q_offset: int | jax.Array = 0,
     kv_len: jax.Array | None = None,   # valid prefix length (decode)
+    scale: float | None = None,        # None: hd ** -0.5
 ) -> jax.Array:
     """Grouped-query scaled-dot-product attention, pure-jnp oracle path.
 
     Computes in fp32 for the softmax, returns q.dtype. ``q_offset`` is the
     absolute position of q[0] (decode/prefill continuation). ``kv_len``
-    masks the KV tail (preallocated decode caches).
+    masks the KV tail (preallocated decode caches). ``v`` may be narrower
+    or wider than ``q`` and ``k``; the output takes its width.
     """
     B, Sq, H, hd = q.shape
     Skv, K = k.shape[1], k.shape[2]
@@ -140,7 +146,7 @@ def sdpa(
     qg = q.reshape(B, Sq, K, G, hd)
     logits = jnp.einsum("bskgh,btkh->bkgst", qg, k,
                         preferred_element_type=jnp.float32)
-    logits = logits * (hd ** -0.5)
+    logits = logits * (hd ** -0.5 if scale is None else scale)
 
     qpos = jnp.arange(Sq) + q_offset            # (Sq,)
     kpos = jnp.arange(Skv)                      # (Skv,)
@@ -154,7 +160,7 @@ def sdpa(
     logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkh->bskgh", probs, v)
-    return out.reshape(B, Sq, H, hd)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def attention(
@@ -269,15 +275,18 @@ MOE_GROUP = 2048          # tokens per dispatch group (bounds dispatch FLOPs)
 
 
 def init_moe(key, cfg: ModelConfig) -> tuple[Params, Params]:
-    assert cfg.moe is not None
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.moe.n_experts
+    """The router scores all ``n_experts``; only the held experts' weights
+    are here, and the shared experts' as one SwiGLU of their summed width."""
+    moe = cfg.moe
+    assert moe is not None
+    d, f, E = cfg.d_model, moe.d_expert or cfg.d_ff, moe.n_experts
     dt = dtype_of(cfg)
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
     p = {
         "router": _init(ks[0], (d, E), d ** -0.5, jnp.float32),
-        "w_gate": _init(ks[1], (E, d, f), d ** -0.5, dt),
-        "w_up": _init(ks[2], (E, d, f), d ** -0.5, dt),
-        "w_down": _init(ks[3], (E, f, d), f ** -0.5, dt),
+        "w_gate": _init(ks[1], (moe.held, d, f), d ** -0.5, dt),
+        "w_up": _init(ks[2], (moe.held, d, f), d ** -0.5, dt),
+        "w_down": _init(ks[3], (moe.held, f, d), f ** -0.5, dt),
     }
     s = {
         "router": (EMBED, None),
@@ -285,6 +294,9 @@ def init_moe(key, cfg: ModelConfig) -> tuple[Params, Params]:
         "w_up": (EXPERTS, EMBED, FF),
         "w_down": (EXPERTS, FF, EMBED),
     }
+    if moe.n_shared:
+        p["shared"], s["shared"] = init_mlp(
+            ks[4], dataclasses.replace(cfg, d_ff=moe.n_shared * f))
     return p, s
 
 
@@ -336,3 +348,77 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     ye = jnp.einsum("gecf,efd->gecd", h, p["w_down"])       # (G,E,C,d)
     yg = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), ye)
     return yg.reshape(B, S, d)
+
+
+# --------------------------------------------------------------------------
+# Mixture of Experts without dropping (DeepSeek-V2 routing, held experts)
+# --------------------------------------------------------------------------
+
+def moe_dropless(p: Params, x: jax.Array, cfg: ModelConfig
+                 ) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """DeepSeek's routed and shared experts, with no assignment dropped.
+
+    The router scores every token over all ``n_experts`` in float32, takes
+    a softmax and then its top k (greedy) as the weights, neither
+    renormalised nor scaled. This device holds experts ``first_held`` to
+    ``first_held + held``: the assignments to them are sorted by expert and
+    run through grouped products (``kernels.ops.grouped_matmul``), whatever their
+    imbalance; the other assignments are another device's part and add
+    nothing here. The shared experts' SwiGLU is added for every token.
+
+    Returns the layer's output and its counters: ``moe_aux``, the
+    sequence-level balance loss over all experts times ``aux_alpha``
+    (DeepSeek-V2 §2.2.3; its gradient flows into the scores);
+    ``moe_held_rows``, rows the held experts' products computed;
+    ``moe_load_max``, the most rows any held expert got;
+    ``moe_dropped``, held assignments left without a row (always 0).
+    """
+    moe = cfg.moe
+    assert moe is not None and moe.dispatch == "dropless"
+    E, k, n = moe.n_experts, moe.top_k, moe.held
+    B, S, d = x.shape
+    T = B * S
+    xt = x.reshape(T, d)
+    with jax.named_scope("moe_route"):
+        # float32 products at full precision: the TPU's default would round
+        # the router's operands to bfloat16.
+        logits = jnp.dot(xt.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, k)                 # (T, k)
+        # f_e: picks of e in the row over S k / E; P_e: e's mean score there.
+        picks = jnp.sum(jax.nn.one_hot(chosen, E, dtype=jnp.float32), axis=1)
+        f = jnp.sum(picks.reshape(B, S, E), axis=1) * (E / (S * k))
+        share = jnp.mean(probs.reshape(B, S, E), axis=1)
+        aux = moe.aux_alpha * jnp.mean(jnp.sum(f * share, axis=-1))
+    with jax.named_scope("moe_experts"):
+        local = chosen.reshape(T * k) - moe.first_held
+        held = (local >= 0) & (local < n)
+        # Held assignments first, by expert; the rest after them.
+        order = jnp.argsort(jnp.where(held, local, n), stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(local, n, dtype=jnp.int32), axis=0)
+        rows = jnp.sum(sizes)
+        # Rows past the held ones belong to no group, and the grouped
+        # product leaves them undefined: each product's operand and result
+        # there is selected to zero before it meets any arithmetic, forward
+        # and backward.
+        valid = (jnp.arange(T * k) < rows)[:, None]
+
+        def grouped(lhs, w):
+            return jnp.where(valid, kernel_ops.grouped_matmul(lhs, w, sizes), 0.0)
+
+        xs = jnp.where(valid, xt[order // k], 0)                   # (T k, d)
+        h = jax.nn.silu(grouped(xs, p["w_gate"])) * grouped(xs, p["w_up"])
+        ys = grouped(h.astype(x.dtype), p["w_down"])
+        ys = ys * weights.reshape(T * k)[order][:, None]
+        # Back to (token, slot) order, then the sum over each token's slots.
+        inv = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        y = jnp.sum(ys[inv].reshape(T, k, d), axis=1).astype(x.dtype)
+    if moe.n_shared:
+        with jax.named_scope("moe_shared"):
+            y = y + mlp(p["shared"], xt, cfg)
+    stats = {"moe_aux": aux, "moe_held_rows": rows,
+             "moe_load_max": jnp.max(sizes),
+             "moe_dropped": jnp.sum(held.astype(jnp.int32)) - rows}
+    return y.reshape(B, S, d), stats

@@ -17,7 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models import ssm
+from repro.models import mla, ssm
 from repro.models.config import ModelConfig
 from repro.models.layers import (
     EMBED,
@@ -36,6 +36,7 @@ from repro.models.layers import (
     init_moe,
     init_rmsnorm,
     mlp,
+    moe_dropless,
     moe_mlp,
     rmsnorm,
     sdpa,
@@ -51,6 +52,8 @@ MAX_ABS_POS = 32768  # learned-position table for enc-dec (whisper decoder)
 def _init_mixer(key, mixer: str, cfg: ModelConfig) -> tuple[Params, Params]:
     if mixer == "attn":
         return init_attention(key, cfg)
+    if mixer == "mla":
+        return mla.init_mla(key, cfg)
     if mixer == "mamba":
         return ssm.init_mamba(key, cfg)
     if mixer == "mlstm":
@@ -120,6 +123,11 @@ def init_model(key, cfg: ModelConfig) -> tuple[Params, Params]:
         for pos in range(cfg.pattern_period)
     ]
     s["blocks"] = blocks_s
+    if cfg.n_dense_lead:
+        lead = [_init_block(ks[next(ki)], cfg.lead_entry, cfg, cross=False)
+                for _ in range(cfg.n_dense_lead)]
+        p["lead"] = _stack([bp for bp, _ in lead])
+        s["lead"] = _stack_specs(lead[0][1])
 
     if cfg.enc_dec:
         enc_p = []
@@ -147,12 +155,17 @@ def init_model(key, cfg: ModelConfig) -> tuple[Params, Params]:
 # ---------------------------------------------------------------------------
 
 def _block_fwd(bp: Params, x: jax.Array, entry: str, cfg: ModelConfig,
-               enc_out: jax.Array | None = None) -> jax.Array:
+               enc_out: jax.Array | None = None
+               ) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """One layer; returns its output and its counters (dropless MoE only)."""
     mixer, mlp_kind = cfg.mixer_of(entry), cfg.mlp_of(entry)
+    stats: dict[str, jax.Array] = {}
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
     if mixer == "attn":
         use_rope = not cfg.enc_dec
         y = attention(bp["mixer"], h, cfg, causal=True, use_rope=use_rope)
+    elif mixer == "mla":
+        y = mla.mla(bp["mixer"], h, cfg)
     elif mixer == "mamba":
         y, _ = ssm.mamba(bp["mixer"], h, cfg)
     elif mixer == "mlstm":
@@ -168,10 +181,27 @@ def _block_fwd(bp: Params, x: jax.Array, entry: str, cfg: ModelConfig,
                           use_rope=False)
     if mlp_kind is not None:
         h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
-        y = (moe_mlp(bp["mlp"], h, cfg) if mlp_kind == "moe"
-             else mlp(bp["mlp"], h, cfg))
+        if mlp_kind != "moe":
+            y = mlp(bp["mlp"], h, cfg)
+        elif cfg.moe.dispatch == "dropless":
+            y, stats = moe_dropless(bp["mlp"], h, cfg)
+        else:
+            y = moe_mlp(bp["mlp"], h, cfg)
         x = x + y
-    return x
+    return x, stats
+
+
+def merge_stats(stats: list[dict[str, jax.Array]]) -> dict[str, jax.Array]:
+    """Counters of several layers (each may be stacked) as one set: the
+    balance losses, rows and drops summed, the load's maximum kept."""
+    out: dict[str, jax.Array] = {}
+    for st in stats:
+        for name, v in st.items():
+            v = jnp.max(v) if name == "moe_load_max" else jnp.sum(v)
+            if name in out:
+                v = jnp.maximum(out[name], v) if name == "moe_load_max" else out[name] + v
+            out[name] = v
+    return out
 
 
 def _enc_block_fwd(bp: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -193,24 +223,40 @@ def _scan_blocks(params_stacked: Any, x: jax.Array, fwd) -> jax.Array:
 
 
 def _scan_superblocks(p: Params, cfg: ModelConfig, x: jax.Array,
-                      enc_out: jax.Array | None) -> jax.Array:
+                      enc_out: jax.Array | None
+                      ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """scan over n_repeats; each step applies the whole block pattern in
-    order (preserves e.g. Jamba's 1:7 mamba:attn interleave)."""
+    order (preserves e.g. Jamba's 1:7 mamba:attn interleave). The leading
+    dense layers, if any, run first under a scan of their own. Returns the
+    output and the layers' counters, merged."""
 
-    def superblock(carry, bps):
-        h = carry
-        for pos, entry in enumerate(cfg.block_pattern):
-            h = _block_fwd(bps[pos], h, entry, cfg, enc_out)
-        return h, None
+    def run(blocks, pattern, x):
+        def superblock(carry, bps):
+            h = carry
+            stats = []
+            for pos, entry in enumerate(pattern):
+                h, st = _block_fwd(bps[pos], h, entry, cfg, enc_out)
+                stats.append(st)
+            return h, stats
 
-    f = jax.checkpoint(superblock) if cfg.remat else superblock
-    if cfg.scan_layers:
-        x, _ = jax.lax.scan(f, x, tuple(p["blocks"]))
-    else:  # unrolled: exact HLO-level cost analysis (dry-run roofline)
-        for r in range(cfg.n_repeats):
-            bps = jax.tree.map(lambda t: t[r], tuple(p["blocks"]))
-            x, _ = f(x, bps)
-    return x
+        f = jax.checkpoint(superblock) if cfg.remat else superblock
+        if cfg.scan_layers:
+            return jax.lax.scan(f, x, blocks)
+        # unrolled: exact HLO-level cost analysis (dry-run roofline)
+        ys = []
+        for r in range(jax.tree.leaves(blocks)[0].shape[0]):
+            x, st = f(x, jax.tree.map(lambda t: t[r], blocks))
+            ys.append(st)
+        return x, ys
+
+    stats = []
+    if cfg.n_dense_lead:
+        x, st = run((p["lead"],), (cfg.lead_entry,), x)
+        stats.append(st)
+    x, st = run(tuple(p["blocks"]), cfg.block_pattern, x)
+    stats.append(st)
+    return x, merge_stats(jax.tree.leaves(
+        stats, is_leaf=lambda t: isinstance(t, dict)))
 
 
 def encode(p: Params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
@@ -229,13 +275,14 @@ def encode(p: Params, cfg: ModelConfig, enc_embeds: jax.Array) -> jax.Array:
     return rmsnorm(p["enc_norm"], x, cfg.norm_eps)
 
 
-def forward(
+def forward_with_stats(
     p: Params,
     cfg: ModelConfig,
     tokens: jax.Array,                    # (B, S) int32
     enc_embeds: jax.Array | None = None,  # (B, F, d) stub frontend
-) -> jax.Array:
-    """Token logits for training / prefill. Returns (B, S, vocab)."""
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Token logits (B, S, vocab) for training / prefill, and the layers'
+    counters (``merge_stats``; empty but for dropless MoE layers)."""
     x = p["embed"][tokens].astype(dtype_of(cfg))
     enc_out = None
     if cfg.enc_dec:
@@ -244,11 +291,17 @@ def forward(
         S = tokens.shape[1]
         x = x + p["dec_pos"][:S][None]
 
-    x = _scan_superblocks(p, cfg, x, enc_out)
+    x, stats = _scan_superblocks(p, cfg, x, enc_out)
 
     x = rmsnorm(p["final_norm"], x, cfg.norm_eps)
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
-    return (x @ head).astype(jnp.float32)
+    return (x @ head).astype(jnp.float32), stats
+
+
+def forward(p: Params, cfg: ModelConfig, tokens: jax.Array,
+            enc_embeds: jax.Array | None = None) -> jax.Array:
+    """Token logits for training / prefill. Returns (B, S, vocab)."""
+    return forward_with_stats(p, cfg, tokens, enc_embeds)[0]
 
 
 def loss_fn(
@@ -257,14 +310,19 @@ def loss_fn(
     tokens: jax.Array,
     labels: jax.Array,
     enc_embeds: jax.Array | None = None,
-) -> jax.Array:
-    logits = forward(p, cfg, tokens, enc_embeds)
+) -> tuple[jax.Array, dict[str, jax.Array]]:
+    """Mean cross-entropy plus the z-loss, plus the MoE layers' balance
+    losses where they have one; and the layers' counters."""
+    logits, stats = forward_with_stats(p, cfg, tokens, enc_embeds)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None],
                                axis=-1).squeeze(-1)
     ce = (logz - gold).mean()
     zloss = 1e-4 * jnp.square(logz).mean()   # logit drift regularizer
-    return ce + zloss
+    loss = ce + zloss
+    if "moe_aux" in stats:
+        loss = loss + stats["moe_aux"]
+    return loss, stats
 
 
 # ---------------------------------------------------------------------------
@@ -277,10 +335,19 @@ def _attn_cache_len(cfg: ModelConfig, seq_len: int) -> int:
     return seq_len
 
 
+def _refuse_latent_decode(cfg: ModelConfig) -> None:
+    if cfg.mla is not None or cfg.n_dense_lead:
+        raise NotImplementedError(
+            f"{cfg.name}: decoding through a latent (MLA) cache, and leading "
+            "dense layers in decode, are not implemented; training and "
+            "prefill (forward) are")
+
+
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
                abstract: bool = False) -> Any:
     """Decode-state pytree. One entry per pattern position, leaves stacked
     over n_repeats. ``abstract=True`` returns ShapeDtypeStructs (dry-run)."""
+    _refuse_latent_decode(cfg)
     R = cfg.n_repeats
     K, hd = cfg.n_kv_heads, cfg.hd
     dt = dtype_of(cfg)
@@ -392,6 +459,7 @@ def decode_step(
 ) -> tuple[jax.Array, Any]:
     """One serving step: append token at ``pos``, return next-token logits
     (B, vocab) and the updated cache."""
+    _refuse_latent_decode(cfg)
     x = p["embed"][token][:, None, :].astype(dtype_of(cfg))  # (B,1,d)
     if cfg.enc_dec:
         x = x + p["dec_pos"][pos][None, None, :]

@@ -2,10 +2,13 @@
 
 ``build_train_step`` returns a pure function
 ``(params, opt_state, batch) -> (params, opt_state, metrics)`` ready for
-``jax.jit`` with shardings. Gradient accumulation over microbatches is a
-``lax.scan`` so activation memory is one microbatch while the weight
-gradient buffer lives across the scan (standard large-batch trick; also
-the knob §Perf turns for memory-bound cells).
+``jax.jit`` with shardings. ``metrics`` carries the loss (with any MoE
+balance loss in it), the gradient norm, the schedule's scale and the
+model's counters (``models.model.merge_stats``) as device scalars.
+Gradient accumulation over microbatches is a ``lax.scan`` so activation
+memory is one microbatch while the weight gradient buffer lives across the
+scan (standard large-batch trick; also the knob §Perf turns for
+memory-bound cells).
 """
 from __future__ import annotations
 
@@ -30,7 +33,7 @@ def build_train_step(cfg: ModelConfig, opt: AdamWConfig,
         labels = batch["labels"]
         enc = batch.get("enc_embeds")
         if n_microbatches == 1:
-            loss, grads = jax.value_and_grad(loss_of)(
+            (loss, stats), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 params, tokens, labels, enc)
         else:
             B = tokens.shape[0]
@@ -47,23 +50,27 @@ def build_train_step(cfg: ModelConfig, opt: AdamWConfig,
                 loss_acc, g_acc = carry
                 t, l = xs[0], xs[1]
                 e = xs[2] if menc is not None else None
-                loss, g = jax.value_and_grad(loss_of)(params, t, l, e)
+                (loss, st), g = jax.value_and_grad(loss_of, has_aux=True)(
+                    params, t, l, e)
                 g_acc = jax.tree.map(jnp.add, g_acc, g)
-                return (loss_acc + loss, g_acc), None
+                return (loss_acc + loss, g_acc), st
 
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             xs = (mtok, mlab) + ((menc,) if menc is not None else ())
-            (loss, grads), _ = jax.lax.scan(
+            (loss, grads), stats = jax.lax.scan(
                 acc_step, (jnp.zeros(()), zeros), xs)
             loss = loss / n_microbatches
             grads = jax.tree.map(lambda g: g / n_microbatches, grads)
+            stats = M.merge_stats([stats])
+            if "moe_aux" in stats:      # a part of the loss: its mean
+                stats["moe_aux"] = stats["moe_aux"] / n_microbatches
 
         lr_scale = cosine_schedule(opt_state["count"], warmup=opt.warmup)
         params, opt_state, om = adamw_update(
             grads, opt_state, params, opt, lr_scale)
         metrics = {"loss": loss, "grad_norm": om["grad_norm"],
-                   "lr_scale": lr_scale}
+                   "lr_scale": lr_scale, **stats}
         return params, opt_state, metrics
 
     return train_step
