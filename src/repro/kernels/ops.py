@@ -2,9 +2,9 @@
 
 On the TPU they run compiled. On the CPU backend, where the test suite
 runs, they run in Pallas interpret mode, validated against ``ref.py``
-(megablox's grouped product against ``jax.lax.ragged_dot``); any other
-backend is an error. The wrappers pad ragged shapes up to
-block multiples and handle layout.
+(megablox's grouped product against ``jax.lax.ragged_dot``, splash
+attention against ``layers.sdpa``); any other backend is an error. The
+wrappers choose block sizes and handle layout.
 """
 from __future__ import annotations
 
@@ -13,10 +13,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as _splash
 from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 
 from repro.kernels import decode_attention as _dec
-from repro.kernels import flash_attention as _fa
 from repro.kernels import linear_attention as _la
 
 
@@ -34,22 +34,6 @@ def interpret_mode() -> bool:
     raise RuntimeError(
         f"Pallas TPU kernels run compiled on 'tpu' or interpreted on "
         f"'cpu'; the default backend is {backend!r}")
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window",
-                                             "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal=True, window=None,
-                    block_q=128, block_k=128):
-    S = q.shape[1]
-    bq, bk = min(block_q, S), min(block_k, S)
-    pad = (-S) % bq
-    if pad:
-        cfg = [(0, 0), (0, pad), (0, 0), (0, 0)]
-        q, k, v = (jnp.pad(t, cfg) for t in (q, k, v))
-    out = _fa.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=bq, block_k=bk,
-                              interpret=interpret_mode())
-    return out[:, :S] if pad else out
 
 
 @functools.partial(jax.jit, static_argnames=("block_k",))
@@ -84,3 +68,61 @@ def grouped_matmul(lhs, rhs, group_sizes):
         (min(k, GMM_WIDE), min(n, GMM_NARROW))
     return _gmm(lhs, rhs, group_sizes, jnp.float32, (math.gcd(m, GMM_ROWS), tk, tn),
                 None, None, False, interpret_mode())
+
+
+# Query and key blocks of the fused attention kernel, in order of
+# preference: the first that divides the sequence is taken. At S 2048 on a
+# v5e, 1024 ran one layer's forward and backward fastest (PERF.md).
+SPLASH_BLOCKS = (1024, 512, 256, 128)
+
+
+def splash_block(seq: int) -> int | None:
+    """The fused attention kernel's block for a sequence of ``seq``, or
+    None where no block tiles it."""
+    return next((b for b in SPLASH_BLOCKS if seq % b == 0), None)
+
+
+@functools.lru_cache(maxsize=64)
+def _splash_kernel(seq: int, heads: int, causal: bool, window: int | None,
+                   block: int, interpret: bool):
+    """Splash attention over ``heads`` query heads of ``seq`` positions.
+
+    The mask is ``layers.sdpa``'s: key ``t`` is seen from query ``s``
+    where ``t <= s`` (causal) and ``t > s - window`` (a window). Its
+    block structure is computed once here, as concrete arrays, so that
+    tracing a step does not compute it again for every layer. The
+    backward pass is one kernel, which computes dq beside dk and dv."""
+    shape = (seq, seq)
+    if window is not None:
+        mask = _splash.LocalMask(shape, (window - 1, 0 if causal else None), 0)
+    elif causal:
+        mask = _splash.CausalMask(shape)
+    else:
+        mask = _splash.FullMask(shape)
+    sizes = _splash.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        use_fused_bwd_kernel=True)
+    with jax.ensure_compile_time_eval():
+        return _splash.make_splash_mha(
+            _splash.MultiHeadMask((mask,) * heads), block_sizes=sizes,
+            head_shards=1, q_seq_shards=1, interpret=interpret)
+
+
+def splash_attention(q, k, v, *, causal: bool, window: int | None,
+                     scale: float):
+    """Grouped-query self-attention through JAX's splash attention kernel,
+    with its own backward pass: the S x S scores stay in VMEM.
+
+    Takes and returns ``layers.sdpa``'s layout, (B, S, H, hd) queries and
+    (B, S, K, hd) keys and values, H a multiple of K, S tiled by
+    ``splash_block``. The kernel does not scale the queries: they are
+    scaled here in float32 and rounded once to their dtype. Its softmax
+    runs in float32."""
+    _, S, H, _ = q.shape
+    kernel = _splash_kernel(S, H, causal, window, splash_block(S),
+                            interpret_mode())
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    heads_first = functools.partial(jnp.swapaxes, axis1=1, axis2=2)
+    out = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(out)

@@ -104,7 +104,6 @@ class ModelConfig:
     # training
     remat: bool = True
     scan_layers: bool = True    # False: unroll (exact HLO cost analysis)
-    use_pallas: bool = False    # Pallas kernels on TPU; pure-jnp oracle off
 
     @property
     def hd(self) -> int:

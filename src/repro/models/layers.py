@@ -5,12 +5,13 @@ Everything is functional: ``init_*`` returns ``(params, specs)`` where
 (resolved to mesh axes by ``repro.runtime.sharding``). Layer ``apply``
 functions are pure and jit/scan/shard_map friendly.
 
-Attention dispatches to the Pallas flash kernel when
-``cfg.use_pallas=True`` (TPU target); the default pure-jnp path is the
-oracle and the CPU/dry-run path.
+Full-sequence self-attention runs through the fused splash attention
+kernel on the TPU where its blocks tile the sequence; everywhere else,
+the CPU among them, through ``sdpa``, the pure-jnp oracle.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Any
 
@@ -163,6 +164,26 @@ def sdpa(
     return out.reshape(B, Sq, H, v.shape[-1])
 
 
+# Attention calls traced, by path: "fused", or "sdpa/<reason>" with the
+# reason "backend", "cross" or "untiled". It counts traced call sites, not
+# layers: a layer scan traces its body once, and remat may trace it again.
+ATTENTION_PATHS: collections.Counter[str] = collections.Counter()
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _attention_path(S: int, cross: bool) -> str:
+    if not _on_tpu():
+        return "sdpa/backend"
+    if cross:
+        return "sdpa/cross"
+    if kernel_ops.splash_block(S) is None:
+        return "sdpa/untiled"
+    return "fused"
+
+
 def attention(
     p: Params,
     x: jax.Array,
@@ -173,22 +194,29 @@ def attention(
     xkv: jax.Array | None = None,     # cross attention source
     use_rope: bool = True,
 ) -> jax.Array:
-    """Full-sequence attention (training / prefill)."""
+    """Full-sequence attention (training / prefill).
+
+    Self-attention whose sequence the kernel's blocks tile runs fused on
+    the TPU (``kernels.ops.splash_attention``, under the named scope
+    ``attention_fused``); the rest through ``sdpa``. ``ATTENTION_PATHS``
+    counts the choice as it is traced."""
     B, S, _ = x.shape
-    src = x if xkv is None else xkv
+    cross = xkv is not None
+    src = xkv if cross else x
     q, k, v = _project_qkv(p, x, src, cfg)
-    if use_rope and xkv is None:
+    if use_rope and not cross:
         pos = positions if positions is not None else jnp.arange(S)
         q = apply_rope(q, pos, cfg.rope_theta)
         k = apply_rope(k, pos, cfg.rope_theta)
-    if cfg.use_pallas:
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(
-            q, k, v, causal=causal and xkv is None,
-            window=cfg.sliding_window if xkv is None else None)
+    path = _attention_path(S, cross)
+    ATTENTION_PATHS[path] += 1
+    causal, window = causal and not cross, None if cross else cfg.sliding_window
+    if path == "fused":
+        with jax.named_scope("attention_fused"):
+            out = kernel_ops.splash_attention(q, k, v, causal=causal, window=window,
+                                              scale=q.shape[-1] ** -0.5)
     else:
-        out = sdpa(q, k, v, causal=causal and xkv is None,
-                   window=cfg.sliding_window if xkv is None else None)
+        out = sdpa(q, k, v, causal=causal, window=window)
     return out.reshape(B, S, -1) @ p["wo"]
 
 

@@ -6,8 +6,9 @@ mode accepts: block shapes the tiling cannot take, too much VMEM, a
 program too large for HBM. Each case compiles one program for one chip:
 the Pallas kernels at smollm_360m and xlstm_350m widths, the block
 programs of the GEMM and TSQR jobs at the sizes ``chip_smoke.py`` runs,
-and the inlined GEMM program of the chip benchmark's 1024^2 blocks (its
-4096^2 groups run member by member).
+the inlined GEMM program of the chip benchmark's 1024^2 blocks (its
+4096^2 groups run member by member), and the fused attention of the
+benchmark's SmolLM steps, forward and backward.
 
 The topology is described only inside the fixture. Describing it loads
 the TPU library, which one process at a time may hold, so it must not
@@ -24,6 +25,7 @@ from repro.apps.gemm import _add, _matmul, gemm_dag
 from repro.apps.svd import _qr_r, _singular_values, _stack_qr_r
 from repro.configs import get_config
 from repro.core import OptimizeConfig, compile_dag
+from repro.kernels import ops as kernel_ops
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.linear_attention import mlstm_chunk
@@ -139,3 +141,28 @@ def test_compiles_for_one_v5e_chip(one_chip, name):
                               "mlstm_chunk"):
         # A compiled Pallas kernel, not the interpreter's loop of XLA ops.
         assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", [4, 8])
+def test_fused_attention_compiles_for_one_v5e_chip(one_chip, monkeypatch, batch):
+    """``kernels.ops.splash_attention``'s forward and backward at the
+    SmolLM cells' shapes: S 2048, 15 query and 5 KV heads of 64."""
+    # The wrapper asks the backend, which is the CPU here.
+    monkeypatch.setattr(kernel_ops, "interpret_mode", lambda: False)
+    H, K, hd = _smollm_attention()
+    q, kv = (batch, 2048, H, hd), (batch, 2048, K, hd)
+
+    def fwd_bwd(q, k, v, d_out):
+        out, vjp = jax.vjp(functools.partial(
+            kernel_ops.splash_attention, causal=True, window=None,
+            scale=hd ** -0.5), q, k, v)
+        return (out, *vjp(d_out))
+
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+            for s in (q, kv, kv, q)]
+    compiled = jax.jit(fwd_bwd).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
+    # The forward kernel and the backward one, which computes dq with dk and dv.
+    assert compiled.as_text().count("tpu_custom_call") == 2

@@ -15,7 +15,7 @@ except ImportError:  # property tests skip without the dev extra
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.linear_attention import mlstm_chunk
-from repro.kernels.ops import interpret_mode
+from repro.kernels.ops import interpret_mode, splash_attention
 from repro.kernels.ref import (
     decode_attention_ref,
     flash_attention_ref,
@@ -125,6 +125,42 @@ def test_flash_attention_matches_model_sdpa():
     ref = sdpa(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("B,S,H,K,hd,causal,window", [
+    (2, 256, 2, 2, 64, True, None),      # one query head a KV head
+    (2, 384, 6, 2, 64, True, None),      # three, in three blocks of 128
+    (2, 256, 3, 1, 128, False, None),    # not causal, hd 128
+    (1, 384, 3, 1, 64, True, 200),       # a window across blocks
+    (1, 384, 2, 2, 64, False, 200),
+])
+def test_splash_attention_matches_sdpa(dtype, tol, B, S, H, K, hd, causal,
+                                       window):
+    """The fused kernel (interpreted) against ``layers.sdpa`` in float32
+    on the same inputs: the output and the gradients of q, k and v, to
+    float32 rounding, or bfloat16's for bfloat16 inputs."""
+    from repro.models.layers import sdpa
+    ks = jax.random.split(jax.random.PRNGKey(S + H + hd), 4)
+    q = rand(ks[0], (B, S, H, hd), dtype)
+    k = rand(ks[1], (B, S, K, hd), dtype)
+    v = rand(ks[2], (B, S, K, hd), dtype)
+    d_out = rand(ks[3], (B, S, H, hd), dtype)
+    out, vjp = jax.vjp(lambda *a: splash_attention(
+        *a, causal=causal, window=window, scale=hd ** -0.5), q, k, v)
+    ref, ref_vjp = jax.vjp(lambda *a: sdpa(
+        *(t.astype(jnp.float32) for t in a), causal=causal, window=window),
+        q, k, v)
+    assert out.dtype == dtype and out.shape == ref.shape
+    assert _rel(out, ref) < tol
+    for got, want in zip(vjp(d_out), ref_vjp(d_out.astype(jnp.float32))):
+        assert got.dtype == dtype
+        assert _rel(got, want) < tol
 
 
 def test_mlstm_kernel_matches_model_layer():
