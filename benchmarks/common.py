@@ -4,16 +4,12 @@ All paper-figure benchmarks run the *real* engines on *real* DAGs with
 jitted JAX payloads; only the FaaS substrate costs (invocation latency,
 KV transfer, TCP handling, per-task compute duration) are simulated.
 
-By default (``SIM_SCALE == 0``) everything runs on the deterministic
-virtual discrete-event clock (repro.core.simclock): simulated seconds
-cost zero wall time, results and charged ms are bit-identical across
-runs, and ``wall_s`` in every row is the simulated makespan. Setting
-``REPRO_SIM_SCALE > 0`` switches to the seed real-time mode (simulated
-latencies really sleep, scaled by SIM_SCALE) — only needed for sanity
-cross-checks of the virtual substrate. Within one figure all engines
-share the same clock mode, so the paper's *relative* claims are the
-reproduction targets (absolute AWS seconds are not reproducible in this
-container — DESIGN.md §1).
+Everything runs on the deterministic discrete-event clock
+(repro.core.simclock's ``EventClock``): simulated seconds cost zero
+wall time, results and charged ms are bit-identical across runs, and
+``wall_s`` in every row is the simulated makespan. The paper's
+*relative* claims are the reproduction targets (absolute AWS seconds
+are not reproducible in this container — DESIGN.md §1).
 """
 from __future__ import annotations
 
@@ -33,12 +29,7 @@ from repro.core import (
     WukongEngine,
 )
 
-SIM_SCALE = float(os.environ.get("REPRO_SIM_SCALE", "0"))
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
-
-
-def cost(scale: float = SIM_SCALE, **kw: Any) -> CostModel:
-    return CostModel(time_scale=scale, **kw)
 
 
 # Effective per-core throughput of the simulated cluster. Task compute
@@ -53,22 +44,19 @@ GFLOPS_SIM = float(os.environ.get("REPRO_GFLOPS_SIM", "0.02")) * 1e9
 
 
 def ms_per_flop() -> float:
-    """Simulated ms charged per analytic flop (clock-mode agnostic: the
-    realtime clock sleeps it scaled, the virtual clock just advances)."""
+    """Simulated ms charged per analytic flop."""
     return 1e3 / GFLOPS_SIM
 
 
-def wukong(scale: float = SIM_SCALE, **kw: Any) -> WukongEngine:
-    return WukongEngine(EngineConfig(cost=cost(scale), **kw))
+def wukong(**kw: Any) -> WukongEngine:
+    return WukongEngine(EngineConfig(cost=CostModel(), **kw))
 
 
-def wukong_optimized(scale: float = SIM_SCALE,
-                     optimize: OptimizeConfig = ALL_PASSES,
+def wukong_optimized(optimize: OptimizeConfig = ALL_PASSES,
                      **kw: Any) -> WukongEngine:
     """WUKONG with the DAG compiler pipeline (optimized-vs-unoptimized
     series; pass an ``OptimizeConfig`` for single-pass ablations)."""
-    return WukongEngine(EngineConfig(cost=cost(scale), optimize=optimize,
-                                     **kw))
+    return WukongEngine(EngineConfig(cost=CostModel(), optimize=optimize, **kw))
 
 
 # -- data-plane factor series (striping + batched round trips) --------------
@@ -83,43 +71,38 @@ DATAPLANE_KV_MBPS = 5.0          # per-shard lane in the emulated regime
 DATAPLANE_STRIPE_BYTES = 8 << 10  # stripe target: a 64 KiB GEMM block -> 8
 
 
-def wukong_dataplane(scale: float = SIM_SCALE, **kw: Any) -> WukongEngine:
+def wukong_dataplane(**kw: Any) -> WukongEngine:
     """Optimized WUKONG with the PR 2 data plane ON: striped large
     objects + batched (mget / counter-registration) round trips."""
-    c = cost(scale, kv_bandwidth_mbps=DATAPLANE_KV_MBPS,
+    c = CostModel(kv_bandwidth_mbps=DATAPLANE_KV_MBPS,
              stripe_threshold_bytes=DATAPLANE_STRIPE_BYTES)
     return WukongEngine(EngineConfig(cost=c, optimize=ALL_PASSES,
                                      batch_kv_round_trips=True, **kw))
 
 
-def wukong_dataplane_off(scale: float = SIM_SCALE, **kw: Any) -> WukongEngine:
+def wukong_dataplane_off(**kw: Any) -> WukongEngine:
     """Optimized WUKONG with the PR 1 data plane: one shard lane per
     object (striping off), one round trip per key (batching off). Same
     emulated regime as ``wukong_dataplane`` — the ablation baseline."""
-    c = cost(scale, kv_bandwidth_mbps=DATAPLANE_KV_MBPS,
+    c = CostModel(kv_bandwidth_mbps=DATAPLANE_KV_MBPS,
              stripe_threshold_bytes=0)
     return WukongEngine(EngineConfig(cost=c, optimize=ALL_PASSES,
                                      batch_kv_round_trips=False, **kw))
 
 
-def wukong_locality(scale: float = SIM_SCALE, cache: "Any | None" = None,
+def wukong_locality(cache: "Any | None" = None,
                     optimize: OptimizeConfig = ALL_PASSES,
-                    invokers: int = 8, substrate: "str | None" = None,
-                    **kw: Any) -> WukongEngine:
+                    invokers: int = 8, **kw: Any) -> WukongEngine:
     """WUKONG on the stateful platform in the emulated data-intensive
     regime, with an optional container cache (``CacheConfig``) — the
     fig18 locality series. Same KV regime as ``wukong_dataplane``, so
     the cacheless arm is the PR 2 data plane and the cached arms isolate
-    exactly the multi-tier cache + locality-aware placement. When
-    ``substrate`` is None the CostModel default applies (the event
-    engine, or ``REPRO_SIM_SUBSTRATE`` — how the CI matrix steers the
-    fig18 job)."""
+    exactly the multi-tier cache + locality-aware placement."""
     from repro.platform import PlatformConfig
 
-    c = cost(scale, kv_bandwidth_mbps=DATAPLANE_KV_MBPS,
+    c = CostModel(kv_bandwidth_mbps=DATAPLANE_KV_MBPS,
              stripe_threshold_bytes=DATAPLANE_STRIPE_BYTES,
-             cold_start_ms=250.0,
-             **({} if substrate is None else {"substrate": substrate}))
+             cold_start_ms=250.0)
     return WukongEngine(EngineConfig(
         cost=c, optimize=optimize, batch_kv_round_trips=True,
         num_initial_invokers=invokers, num_proxy_invokers=invokers,
@@ -129,8 +112,7 @@ def wukong_locality(scale: float = SIM_SCALE, cache: "Any | None" = None,
 # -- stateful platform presets (fig14: warm pool / throttling / billing) ----
 
 
-def wukong_platform(scale: float = SIM_SCALE,
-                    platform: "Any | None" = None,
+def wukong_platform(platform: "Any | None" = None,
                     **kw: Any) -> WukongEngine:
     """Optimized WUKONG on the stateful platform model (repro.platform):
     warm-container pool + concurrency throttle + billing meter. Pass a
@@ -138,62 +120,53 @@ def wukong_platform(scale: float = SIM_SCALE,
     knobs; cost-model overrides ride ``kw['cost']``."""
     from repro.platform import PlatformConfig
 
-    c = kw.pop("cost", None) or cost(scale, cold_start_ms=250.0)
+    c = kw.pop("cost", None) or CostModel(cold_start_ms=250.0)
     return WukongEngine(EngineConfig(
         cost=c, optimize=ALL_PASSES,
         platform=platform or PlatformConfig(), **kw))
 
 
-def parallel_invoker_optimized(scale: float = SIM_SCALE,
-                               n: int = 20) -> ParallelInvokerEngine:
+def parallel_invoker_optimized(n: int = 20) -> ParallelInvokerEngine:
     """Centralized best-iteration with the DAG compiler (chain fusion
     shrinks its one-Lambda-per-task graph)."""
-    return ParallelInvokerEngine(cost=cost(scale), num_invokers=n,
+    return ParallelInvokerEngine(cost=CostModel(), num_invokers=n,
                                  optimize=ALL_PASSES)
 
 
-def strawman(scale: float = SIM_SCALE) -> StrawmanEngine:
-    return StrawmanEngine(cost=cost(scale))
+def strawman() -> StrawmanEngine:
+    return StrawmanEngine(cost=CostModel())
 
 
-def pubsub(scale: float = SIM_SCALE) -> PubSubEngine:
-    return PubSubEngine(cost=cost(scale))
+def pubsub() -> PubSubEngine:
+    return PubSubEngine(cost=CostModel())
 
 
-def parallel_invoker(scale: float = SIM_SCALE,
-                     n: int = 20) -> ParallelInvokerEngine:
-    return ParallelInvokerEngine(cost=cost(scale), num_invokers=n)
+def parallel_invoker(n: int = 20) -> ParallelInvokerEngine:
+    return ParallelInvokerEngine(cost=CostModel(), num_invokers=n)
 
 
-def serverful_ec2(scale: float = SIM_SCALE) -> ServerfulEngine:
+def serverful_ec2() -> ServerfulEngine:
     # paper: five t2.2xlarge VMs x five workers
     return ServerfulEngine(ServerfulConfig(
-        cost=cost(scale), n_workers=25, worker_bandwidth_mbps=1000.0,
+        cost=CostModel(), n_workers=25, worker_bandwidth_mbps=1000.0,
         n_vms=5, vm_price_per_hour_usd=0.3712))
 
 
-def serverful_laptop(scale: float = SIM_SCALE) -> ServerfulEngine:
+def serverful_laptop() -> ServerfulEngine:
     # paper: two-core i5 laptop, four workers — owned hardware, so the
     # fixed-cluster billing model charges no VM-hours
     return ServerfulEngine(ServerfulConfig(
-        cost=cost(scale), n_workers=4, worker_bandwidth_mbps=4000.0,
+        cost=CostModel(), n_workers=4, worker_bandwidth_mbps=4000.0,
         n_vms=0, vm_price_per_hour_usd=0.0))
 
 
-def timed(engine, dag, repeats: int = 1,
-          warmup: "bool | None" = None) -> dict[str, Any]:
+def timed(engine, dag, repeats: int = 1) -> dict[str, Any]:
     """Run and report simulated-environment wall seconds (mean over
-    repeats) plus engine counters. ``warmup`` runs the DAG once first so
-    one-time XLA compilation of the task payloads is not charged to
-    whichever engine happens to run first; it defaults to on only in
-    real-time mode — under the virtual clock ``wall_s`` is simulated
-    makespan, which host-side compilation cannot perturb."""
-    if warmup is None:
-        warmup = SIM_SCALE > 0
+    repeats) plus engine counters. ``wall_s`` is the simulated makespan,
+    which host-side work (XLA compilation of the task payloads) cannot
+    perturb."""
     walls = []
     rep = None
-    if warmup:
-        engine.compute(dag)
     for _ in range(repeats):
         rep = engine.compute(dag)
         walls.append(rep.wall_s)

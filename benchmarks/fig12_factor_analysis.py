@@ -18,6 +18,7 @@ from __future__ import annotations
 from benchmarks import common
 from repro.apps import tree_reduction_dag
 from repro.core import (
+    CostModel,
     EngineConfig,
     ParallelInvokerEngine,
     PubSubEngine,
@@ -39,21 +40,21 @@ def run(n: int = 512, delay_ms: float = 20.0,
     # paper, so every earlier version runs with colocated shards.
     steps = [
         ("1_strawman", StrawmanEngine(
-            cost=common.cost(), colocate_kv_shards=True)),
+            cost=CostModel(), colocate_kv_shards=True)),
         ("2_pubsub", PubSubEngine(
-            cost=common.cost(), colocate_kv_shards=True)),
+            cost=CostModel(), colocate_kv_shards=True)),
         ("3_parallel_invoker", ParallelInvokerEngine(
-            cost=common.cost(), colocate_kv_shards=True)),
+            cost=CostModel(), colocate_kv_shards=True)),
         # decentralized Task Executors (static schedules + local caches):
         ("4_decentralized", WukongEngine(EngineConfig(
-            cost=common.cost(), use_proxy=False, colocate_kv_shards=True))),
+            cost=CostModel(), use_proxy=False, colocate_kv_shards=True))),
         ("5_plus_proxy", WukongEngine(EngineConfig(
-            cost=common.cost(), use_proxy=True, colocate_kv_shards=True))),
+            cost=CostModel(), use_proxy=True, colocate_kv_shards=True))),
         ("6_sharded_vms", WukongEngine(EngineConfig(
-            cost=common.cost(), use_proxy=True, colocate_kv_shards=False))),
+            cost=CostModel(), use_proxy=True, colocate_kv_shards=False))),
         # ...and what full WUKONG would cost WITHOUT the warm pool:
         ("7_cold_pool", WukongEngine(EngineConfig(
-            cost=common.cost(warm_fraction=cold_warm_fraction,
+            cost=CostModel(warm_fraction=cold_warm_fraction,
                              invoke_sigma=cold_invoke_sigma),
             use_proxy=True, colocate_kv_shards=False))),
     ]

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 from benchmarks import common
 from repro.apps import tree_reduction_dag
-from repro.core import ServerfulConfig, ServerfulEngine
+from repro.core import CostModel, ServerfulConfig, ServerfulEngine
 from repro.platform import PlatformConfig
 
 
@@ -100,7 +100,7 @@ def run(n: int = 512,
                                    f"->cap={fanout_cap}"))
 
     # -- 3. fixed-cluster cost comparison -----------------------------------
-    eng = ServerfulEngine(ServerfulConfig(cost=common.cost()))
+    eng = ServerfulEngine(ServerfulConfig(cost=CostModel()))
     r = common.timed(eng, dag)
     rows.append(_pstat_row("serverful_cluster", r,
                            derived="5xVM fixed"))

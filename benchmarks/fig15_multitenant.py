@@ -29,6 +29,7 @@ import dataclasses
 
 from benchmarks import common
 from repro.core import (
+    CostModel,
     EngineConfig,
     JobOrchestrator,
     OrchestratorConfig,
@@ -52,7 +53,7 @@ def tenants_for(count: int) -> "tuple[TenantSpec, ...]":
 def _engine_config() -> EngineConfig:
     # Per-job engine preset: small invoker pools (N jobs run at once on
     # one machine) on the shared benchmark cost model.
-    return EngineConfig(cost=common.cost(cold_start_ms=250.0),
+    return EngineConfig(cost=CostModel(cold_start_ms=250.0),
                         num_initial_invokers=4, num_proxy_invokers=4,
                         max_concurrency=512)
 

@@ -1,32 +1,21 @@
-"""Fig. 16 (beyond-paper): scaling curve of the two simulation substrates.
+"""Fig. 16 (beyond-paper): scaling curve of the simulation clock.
 
-The event-driven substrate (PR 6) replaces thread-per-actor execution
-with continuations driven from the clock's ready queue, so simulating a
-DAG costs generator dispatches instead of OS threads + context
-switches. This figure measures that substitution directly, at two
-levels:
+The event-driven clock (PR 6) runs actors as continuations driven from
+its ready queue, so simulating a DAG costs generator dispatches instead
+of OS threads and context switches. This figure measures how fast the
+simulator itself runs, at two levels:
 
-1. **Substrate-level tree reduction** — pure actors (one generator per
-   leaf/node) on clock queues, no engine around them. This isolates the
-   actor-switching cost the refactor removes; it is where the honest
-   thread-vs-event gap lives (the full engine adds substrate-agnostic
-   Python work — kv simulation, executor walks, metrics — that dilutes
-   the ratio to ~2x). The CI gate asserts the event substrate is
-   >= 5x faster here at the 4096-leaf tier, bit-identical across runs,
-   and charges exactly what the thread substrate charges.
+1. **Clock-level tree reduction** — pure actors (one generator per
+   leaf/node) on clock queues, no engine around them: the raw cost of a
+   frame step.
 2. **Engine-level scaling curve** — the real ``WukongEngine`` on tree
-   reductions from 8k to 10^6 tasks. Both substrates run the 8k tier
-   (cross-substrate charged_ms equality); beyond that only the event
-   substrate is feasible (the thread path would need one OS thread per
-   concurrent executor — 64k+ at the 10^5 tier). The CI gate asserts
-   the 10^5-task tier completes in under 30 s of host wall time.
+   reductions from 8k to 10^6 tasks. The CI gate asserts the 10^5-task
+   tier completes in under 30 s of host wall time.
 
 Rows report ``wall_s`` as *host* seconds (the quantity under test —
 how fast the simulator itself runs), with the simulated makespan in
-``sim_s``. Every event-substrate row is run twice and carries a
-``deterministic`` bit; all event measurements run before any thread
-measurement so thread-run residue (dying OS threads, allocator churn)
-cannot pollute the event timings.
+``sim_s``. The clock-level rows and the small engine tiers are run
+twice and carry a ``deterministic`` bit.
 """
 from __future__ import annotations
 
@@ -37,15 +26,8 @@ from typing import Any
 from benchmarks import common
 from repro.apps import tree_reduction_dag
 from repro.apps.tree_reduction import tree_reduction_expected
-from repro.core import (
-    EngineConfig,
-    WukongEngine,
-    clock_for_scale,
-    drain_worker_cache,
-)
+from repro.core import CostModel, EngineConfig, EventClock, WukongEngine
 
-GATE_LEAVES = 4096        # micro tier the >= 5x speedup gate runs at
-GATE_MIN_SPEEDUP = 5.0
 GATE_SCALE_TASKS = 100_000  # engine tier the wall-budget gate runs at
 GATE_SCALE_BUDGET_S = 30.0
 
@@ -85,10 +67,8 @@ def _tree_actors(clock, leaves: int, compute_ms: float):
     return root()
 
 
-def _micro_once(substrate: str, leaves: int,
-                compute_ms: float) -> dict[str, Any]:
-    drain_worker_cache()
-    clock = clock_for_scale(0.0, substrate)
+def _micro_once(leaves: int, compute_ms: float) -> dict[str, Any]:
+    clock = EventClock()
     t0 = time.perf_counter()
     total = clock.run(_tree_actors(clock, leaves, compute_ms))
     elapsed = time.perf_counter() - t0
@@ -97,12 +77,10 @@ def _micro_once(substrate: str, leaves: int,
             "charged_ms": clock.charged_ms, "result": total}
 
 
-def _engine_once(substrate: str, n: int,
-                 compute_ms: float) -> dict[str, Any]:
-    drain_worker_cache()
+def _engine_once(n: int, compute_ms: float) -> dict[str, Any]:
     dag = tree_reduction_dag(n, compute_ms=compute_ms)
     cfg = EngineConfig(
-        cost=common.cost(0.0, substrate=substrate),
+        cost=CostModel(),
         max_concurrency=max(n, 4096),
         job_timeout_s=1e6,
         # Million-task tiers would hold ~2.5 metric dicts per task;
@@ -119,11 +97,11 @@ def _engine_once(substrate: str, n: int,
             "tasks": rep.tasks}
 
 
-def _row(level: str, substrate: str, tasks: int, first: dict,
+def _row(level: str, tasks: int, first: dict,
          second: "dict | None") -> dict[str, Any]:
     """One scaling-curve row. ``wall_s`` is host seconds (best of the
     runs taken); ``deterministic`` compares the simulated quantities of
-    two event-substrate runs bit-for-bit."""
+    two runs bit-for-bit."""
     deterministic = None
     if second is not None:
         deterministic = all(first[k] == second[k]
@@ -132,9 +110,8 @@ def _row(level: str, substrate: str, tasks: int, first: dict,
             else first["wall_s"])
     sim_s = first["sim_ms"] / 1e3
     row = {
-        "label": f"{level}_{substrate}@{tasks}",
+        "label": f"{level}@{tasks}",
         "level": level,
-        "substrate": substrate,
         "tasks": tasks,
         "wall_s": wall,
         "sim_s": sim_s,
@@ -149,114 +126,52 @@ def _row(level: str, substrate: str, tasks: int, first: dict,
     return row
 
 
-def run(micro_leaves: "tuple[int, ...]" = (1024, GATE_LEAVES),
-        engine_tiers: "tuple[tuple[int, bool], ...]" = (
-            (8192, True), (131072, False)),
+def run(micro_leaves: "tuple[int, ...]" = (1024, 4096),
+        engine_tiers: "tuple[int, ...]" = (8192, 131072),
         compute_ms: float = 1.0) -> list[dict]:
-    """``engine_tiers`` is (dag_n, run_thread_substrate_too); dag_n - 1
-    tasks per tier. All event measurements run before any thread
-    measurement (see module docstring)."""
-    if common.SIM_SCALE > 0:
-        # The curve compares zero-scale substrates; under the real-time
-        # cross-check mode there is nothing meaningful to measure.
-        print("# fig16 skipped (real-time mode)", file=sys.stderr)
-        return []
+    """``engine_tiers`` are tree-reduction sizes: dag_n - 1 tasks per
+    tier."""
     rows: list[dict] = []
-
-    # -- event substrate first: micro tiers, then the engine curve ---------
     for leaves in micro_leaves:
-        first = _micro_once("event", leaves, compute_ms)
-        second = _micro_once("event", leaves, compute_ms)
-        rows.append(_row("substrate", "event", 2 * leaves - 1,
-                         first, second))
-    for n, _both in engine_tiers:
-        first = _engine_once("event", n, compute_ms)
+        first = _micro_once(leaves, compute_ms)
+        second = _micro_once(leaves, compute_ms)
+        rows.append(_row("clock", 2 * leaves - 1, first, second))
+    for n in engine_tiers:
+        first = _engine_once(n, compute_ms)
         # The bit-identity repeat is only affordable at the small tiers;
-        # big tiers get determinism coverage from the micro rows and the
-        # slow-marked scale test.
-        second = (_engine_once("event", n, compute_ms) if n <= 16384
-                  else None)
-        rows.append(_row("engine", "event", n - 1, first, second))
-
-    # -- thread substrate (the cross-check mode) ----------------------------
-    for leaves in micro_leaves:
-        rows.append(_row("substrate", "thread", 2 * leaves - 1,
-                         _micro_once("thread", leaves, compute_ms), None))
-    for n, both in engine_tiers:
-        if both:
-            rows.append(_row("engine", "thread", n - 1,
-                             _engine_once("thread", n, compute_ms), None))
+        # big tiers get determinism coverage from the clock-level rows
+        # and the slow-marked scale test.
+        second = _engine_once(n, compute_ms) if n <= 16384 else None
+        rows.append(_row("engine", n - 1, first, second))
     return rows
 
 
 def scaling_curve(rows: list[dict]) -> list[dict]:
     """The compact tasks-vs-wall-seconds record for BENCH_results.json."""
-    return [{k: r[k] for k in ("level", "substrate", "tasks", "wall_s",
-                               "sim_s", "charged_ms", "deterministic")}
+    return [{k: r[k] for k in ("level", "tasks", "wall_s", "sim_s",
+                               "charged_ms", "deterministic")}
             for r in rows]
 
 
 def check_gates(rows: list[dict]) -> None:
     """The CI scale gates (raise SystemExit on regression):
 
-    - *substrate speedup*: at the 4096-leaf micro tier the event
-      substrate must be >= 5x faster in host wall time than the
-      thread-per-actor substrate;
-    - *bit-identity*: every twice-run event row must reproduce its
-      simulated time and charged ms exactly;
-    - *substrate equivalence*: wherever both substrates ran a tier,
-      their charged_ms (and kv_stats, engine tiers) must be identical;
+    - *bit-identity*: every twice-run row must reproduce its simulated
+      time and charged ms exactly;
     - *scale budget*: the >= 10^5-task engine tier must complete in
       under 30 s of host wall time.
     """
-    if not rows:
-        print("# scale gate skipped (real-time mode)", file=sys.stderr)
-        return
-    by_label = {r["label"]: r for r in rows}
-
-    gate_tasks = 2 * GATE_LEAVES - 1
-    ev = by_label.get(f"substrate_event@{gate_tasks}")
-    th = by_label.get(f"substrate_thread@{gate_tasks}")
-    if ev is None or th is None:
-        raise SystemExit("scale regression: 4096-leaf micro tier missing "
-                         "from the fig16 rows")
-    speedup = th["wall_s"] / ev["wall_s"]
-    if speedup < GATE_MIN_SPEEDUP:
-        raise SystemExit(
-            f"scale regression: event substrate only {speedup:.1f}x faster "
-            f"than thread at {GATE_LEAVES} leaves "
-            f"({ev['wall_s']:.3f}s vs {th['wall_s']:.3f}s; "
-            f">= {GATE_MIN_SPEEDUP:g}x required)")
-
     for r in rows:
         if r["deterministic"] is False:
             raise SystemExit(
                 f"scale regression: {r['label']} not bit-identical across "
                 "two runs")
 
-    for r in rows:
-        if r["substrate"] != "thread":
-            continue
-        ev_r = by_label.get(r["label"].replace("thread", "event"))
-        if ev_r is None:
-            continue
-        if ev_r["charged_ms"] != r["charged_ms"]:
-            raise SystemExit(
-                f"scale regression: {r['label']} charged "
-                f"{r['charged_ms']!r}ms but the event substrate charged "
-                f"{ev_r['charged_ms']!r}ms — substrates diverged")
-        if (r.get("kv_stats") is not None
-                and ev_r.get("kv_stats") != r.get("kv_stats")):
-            raise SystemExit(
-                f"scale regression: {r['label']} kv_stats diverged "
-                "across substrates")
-
     scale = [r for r in rows if r["level"] == "engine"
-             and r["substrate"] == "event"
              and r["tasks"] >= GATE_SCALE_TASKS]
     if not scale:
         raise SystemExit(
-            f"scale regression: no >= {GATE_SCALE_TASKS}-task event tier "
+            f"scale regression: no >= {GATE_SCALE_TASKS}-task engine tier "
             "in the fig16 rows")
     worst = max(scale, key=lambda r: r["wall_s"])
     if worst["wall_s"] >= GATE_SCALE_BUDGET_S:
@@ -265,9 +180,8 @@ def check_gates(rows: list[dict]) -> None:
             f"{worst['wall_s']:.1f}s host wall "
             f"(< {GATE_SCALE_BUDGET_S:g}s required)")
 
-    print(f"# scale gate OK: substrate {speedup:.1f}x at {GATE_LEAVES} "
-          f"leaves; {worst['tasks']} tasks in {worst['wall_s']:.1f}s; "
-          "event rows bit-identical; substrates charge identically",
+    print(f"# scale gate OK: {worst['tasks']} tasks in "
+          f"{worst['wall_s']:.1f}s; twice-run rows bit-identical",
           file=sys.stderr)
 
 
@@ -278,7 +192,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI tiers + the scale gates")
     ap.add_argument("--full", action="store_true",
-                    help="adds the 10^6-task event tier")
+                    help="adds the 10^6-task engine tier")
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="merge the fig16 rows + scaling_curve into this "
                          "BENCH_results.json (read-modify-write; lets the "
@@ -287,9 +201,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.full:
-        kwargs = dict(micro_leaves=(1024, GATE_LEAVES, 16384),
-                      engine_tiers=((8192, True), (131072, False),
-                                    (1 << 20, False)))
+        kwargs = dict(micro_leaves=(1024, 4096, 16384),
+                      engine_tiers=(8192, 131072, 1 << 20))
     else:
         kwargs = dict()  # the smoke/CI tiers are the defaults
     rows = run(**kwargs)

@@ -11,9 +11,9 @@ namespaces.
 Fig. 17 prices that claim. For each crash point ("admit" — after
 journaling ADMITTED, before the runner exists; "dispatch" — after the
 runner actor is spawned; "complete" — after journaling the terminal
-record, before the namespace purge) x crash occurrence x simulation
-substrate, a run is crashed once via ``run_with_recovery`` and compared
-against the uncrashed baseline on:
+record, before the namespace purge) x crash occurrence, a run is
+crashed once via ``run_with_recovery`` and compared against the
+uncrashed baseline on:
 
 - **recovery overhead**: makespan delta vs the baseline (replay +
   re-admission + redone work);
@@ -23,13 +23,13 @@ against the uncrashed baseline on:
   of re-executed;
 - **journal parity**: every journaled-complete job's billed USD and
   latency bit-identical to the baseline record — the no-double-billing
-  acceptance criterion, asserted by the ``--smoke`` gate on BOTH
-  substrates.
+  acceptance criterion, asserted by the ``--smoke`` gate.
 """
 from __future__ import annotations
 
 from benchmarks import common
 from repro.core import (
+    CostModel,
     EngineConfig,
     FaultConfig,
     JobOrchestrator,
@@ -50,19 +50,18 @@ _TENANTS = (
 )
 
 
-def _engine_config(substrate: str) -> EngineConfig:
-    return EngineConfig(cost=common.cost(substrate=substrate,
-                                         cold_start_ms=250.0),
+def _engine_config() -> EngineConfig:
+    return EngineConfig(cost=CostModel(cold_start_ms=250.0),
                         num_initial_invokers=4, num_proxy_invokers=4,
                         max_concurrency=512)
 
 
-def _orch_config(n_jobs: int, rate: float, substrate: str,
+def _orch_config(n_jobs: int, rate: float,
                  crash_point: "str | None" = None, crash_at: int = 1,
                  max_concurrent_jobs: int = 8, seed: int = 0,
                  ) -> OrchestratorConfig:
     return OrchestratorConfig(
-        engine=_engine_config(substrate),
+        engine=_engine_config(),
         workload=WorkloadConfig(n_jobs=n_jobs, arrival_rate_per_s=rate,
                                 tenants=_TENANTS, seed=seed),
         max_concurrent_jobs=max_concurrent_jobs,
@@ -133,29 +132,25 @@ def _row(label: str, rep, base=None, derived: str = "") -> dict:
 
 def run(n_jobs: int = 24, rate: float = 8.0,
         crash_ats: "tuple[int, ...]" = (1, 4),
-        substrates: "tuple[str, ...]" = ("event", "thread"),
         max_concurrent_jobs: int = 8) -> "list[dict]":
     rows: list[dict] = []
-    for substrate in substrates:
-        base = JobOrchestrator(
-            _orch_config(n_jobs, rate, substrate,
-                         max_concurrent_jobs=max_concurrent_jobs)).run()
-        rows.append(_row(f"{substrate}_baseline", base,
-                         derived=f"{n_jobs}jobs@r{rate:g}"))
-        for point in CRASH_POINTS:
-            for crash_at in crash_ats:
-                cfg = _orch_config(n_jobs, rate, substrate,
-                                   crash_point=point, crash_at=crash_at,
-                                   max_concurrent_jobs=max_concurrent_jobs)
-                rep = JobOrchestrator(cfg).run_with_recovery()
-                rows.append(_row(f"{substrate}_{point}_at{crash_at}",
-                                 rep, base=base))
+    base = JobOrchestrator(
+        _orch_config(n_jobs, rate,
+                     max_concurrent_jobs=max_concurrent_jobs)).run()
+    rows.append(_row("baseline", base, derived=f"{n_jobs}jobs@r{rate:g}"))
+    for point in CRASH_POINTS:
+        for crash_at in crash_ats:
+            cfg = _orch_config(n_jobs, rate, crash_point=point,
+                               crash_at=crash_at,
+                               max_concurrent_jobs=max_concurrent_jobs)
+            rep = JobOrchestrator(cfg).run_with_recovery()
+            rows.append(_row(f"{point}_at{crash_at}", rep, base=base))
     return rows
 
 
 def check_gates(rows: "list[dict]") -> None:
-    """CI regression gate (run.py --smoke): every crashed run on every
-    substrate recovered completely with bit-identical journal billing."""
+    """CI regression gate (run.py --smoke): every crashed run recovered
+    completely with bit-identical journal billing."""
     crashed = [r for r in rows if "crashes" in r and r["crashes"] > 0]
     assert crashed, "recovery gate: no crashed runs in fig17 rows"
     for row in crashed:

@@ -30,8 +30,7 @@ container memory get before spills eat the win).
 
 ``check_gates`` is the CI locality gate (run.py --smoke): cached charged
 ms strictly below cacheless on BOTH shapes, tier-0 hit rate > 0, and
-every arm bit-identical across re-runs and across the event/thread
-substrates.
+every arm bit-identical across re-runs.
 """
 from __future__ import annotations
 
@@ -81,24 +80,22 @@ def _row(label: str, rep: Any, derived: str) -> dict:
     }
 
 
-def run(gemm_sizes=((512, 128),), tree_n=256, capacities=(),
-        substrate: "str | None" = None) -> "list[dict]":
+def run(gemm_sizes=((512, 128),), tree_n=256,
+        capacities=()) -> "list[dict]":
     rows = []
     for shape, dag_fn, opt, invokers, derived in _shapes(gemm_sizes,
                                                          tree_n):
         dag = dag_fn()
         for arm, cache in ARMS:
             eng = common.wukong_locality(cache=cache, optimize=opt,
-                                         invokers=invokers,
-                                         substrate=substrate)
+                                         invokers=invokers)
             rows.append(_row(f"{arm}/{shape}", eng.compute(dag), derived))
     # Capacity sweep (full mode): how small can tier 0 get on GEMM
     # before eviction/spill traffic eats the locality win.
     for cap in capacities:
         dag = gemm_dag(*gemm_sizes[0])
         eng = common.wukong_locality(
-            cache=CacheConfig(memory_bytes=cap), optimize=ALL_PASSES,
-            substrate=substrate)
+            cache=CacheConfig(memory_bytes=cap), optimize=ALL_PASSES)
         rows.append(_row(f"cached_cap{cap >> 20}MB/gemm@n={gemm_sizes[0][0]}",
                          eng.compute(dag), f"memory_bytes={cap}"))
     return rows
@@ -111,28 +108,20 @@ def check_gates(rows: "list[dict]", gemm_sizes=((512, 128),),
     - *cache pays*: each cached arm's charged simulated ms is strictly
       below the cacheless baseline on BOTH data-intensive shapes;
     - *tier 0 works*: the cached arms' tier-0 hit rate is > 0;
-    - *determinism*: re-running the smoke sweep — and running it on the
-      thread substrate — reproduces every arm bit-identically
-      (charged ms, wall s, cache_stats, KV counters).
+    - *determinism*: re-running the smoke sweep reproduces every arm
+      bit-identically (charged ms, wall s, cache_stats, KV counters).
     """
-    if common.SIM_SCALE > 0:
-        print("# locality gate skipped (real-time mode)", file=sys.stderr)
-        return
     recorded = {r["label"]: r for r in rows}
-    for substrate in ("event", "thread"):
-        again = run(gemm_sizes=gemm_sizes, tree_n=tree_n,
-                    substrate=substrate)
-        for row in again:
-            first = recorded.get(row["label"])
-            if first is None:
-                continue
-            for field in ("charged_ms", "wall_s", "cache_stats",
-                          "kv_stats"):
-                if first[field] != row[field]:
-                    raise SystemExit(
-                        f"locality regression: {row['label']} not "
-                        f"bit-identical on the {substrate} substrate — "
-                        f"{field} {first[field]!r} != {row[field]!r}")
+    for row in run(gemm_sizes=gemm_sizes, tree_n=tree_n):
+        first = recorded.get(row["label"])
+        if first is None:
+            continue
+        for field in ("charged_ms", "wall_s", "cache_stats", "kv_stats"):
+            if first[field] != row[field]:
+                raise SystemExit(
+                    f"locality regression: {row['label']} not "
+                    f"bit-identical across runs — "
+                    f"{field} {first[field]!r} != {row[field]!r}")
     shapes = {label.split("/", 1)[1] for label in recorded}
     for shape in sorted(shapes):
         base = recorded.get(f"cacheless/{shape}")

@@ -5,22 +5,20 @@ jobs are a first-class, durable, deterministic workload: a Poisson
 event stream lands in the KV store, tumbling windows close and fire
 tree-reduction jobs through the PR 5 orchestrator, timer / job-
 completion / external triggers fire alongside, and the whole run is
-bit-identical across repeats and across the event/thread simulation
-substrates. Dynamic DAGs (runtime graph expansion) claim charged-cost
+bit-identical across repeats. Dynamic DAGs (runtime graph expansion) claim charged-cost
 parity with their static equivalents, and a dispatcher crash mid-
 stream claims exactly-once window fires via the fire journal.
 
 Fig. 19 prices those claims with three arms:
 
 - **streaming**: ``StreamConfig`` arrivals + four trigger rules (one
-  per source type) on both substrates, run twice on the event
-  substrate; gates on >= 64 window-close jobs, >= 1 fire per source,
-  zero failures, and bit-identical steady-state metrics (sustained
-  jobs/s, event-to-result p50/p95/p99, backlog, window fire-key set)
-  across runs AND across substrates;
+  per source type), run twice; gates on >= 64 window-close jobs, >= 1
+  fire per source, zero failures, and bit-identical steady-state
+  metrics (sustained jobs/s, event-to-result p50/p95/p99, backlog,
+  window fire-key set) across the runs;
 - **parity**: ``dynamic_tree_reduction_dag`` vs its pre-expanded
   static equivalent on a ship-free engine — results and charged_ms
-  must match bit for bit on both substrates;
+  must match bit for bit;
 - **crash**: the streaming config crashed at the "dispatch" protocol
   point and recovered via ``run_with_recovery`` — the recovered run
   must complete every job with the same window fire-key set as the
@@ -40,6 +38,7 @@ from repro.apps import (
     static_tree_reduction_equivalent,
 )
 from repro.core import (
+    CostModel,
     EngineConfig,
     FaultConfig,
     JobOrchestrator,
@@ -53,8 +52,8 @@ from repro.core import (
 
 _TENANTS = (TenantSpec("tenant-a"), TenantSpec("tenant-b"))
 
-# Metrics that must be bit-identical across repeated runs and across
-# the event/thread substrates (the determinism gate).
+# Metrics that must be bit-identical across repeated runs (the
+# determinism gate).
 _DETERMINISM_KEYS = (
     "wall_s", "jobs", "completed", "failed", "fires", "windows_closed",
     "window_jobs_completed", "sustained_jobs_per_s",
@@ -64,8 +63,8 @@ _DETERMINISM_KEYS = (
 )
 
 
-def _engine_config(substrate: str, **cost_kw) -> EngineConfig:
-    return EngineConfig(cost=common.cost(substrate=substrate, **cost_kw),
+def _engine_config(**cost_kw) -> EngineConfig:
+    return EngineConfig(cost=CostModel(**cost_kw),
                         num_initial_invokers=4, num_proxy_invokers=4,
                         max_concurrency=512)
 
@@ -98,7 +97,7 @@ def _rules(stream: StreamConfig, window_ms: float) -> "tuple[TriggerRule, ...]":
     )
 
 
-def _orch_config(substrate: str, n_events: int, window_ms: float,
+def _orch_config(n_events: int, window_ms: float,
                  crash_at: "int | None" = None) -> OrchestratorConfig:
     stream = _stream(n_events)
     faults = FaultConfig()
@@ -106,7 +105,7 @@ def _orch_config(substrate: str, n_events: int, window_ms: float,
         faults = FaultConfig(orchestrator_crash_point="dispatch",
                              orchestrator_crash_at=crash_at)
     return OrchestratorConfig(
-        engine=_engine_config(substrate),
+        engine=_engine_config(),
         workload=WorkloadConfig(n_jobs=2, tenants=_TENANTS, seed=1),
         max_concurrent_jobs=8,
         triggers=_rules(stream, window_ms),
@@ -154,7 +153,7 @@ def _row(label: str, rep, bus, n_events: int,
     return row
 
 
-def _parity_rows(substrates: "tuple[str, ...]", n: int) -> "list[dict]":
+def _parity_row(n: int) -> dict:
     """Dynamic-vs-static charged parity on a ship-free engine.
 
     ``schedule_ship_mbps=inf`` removes the static-schedule shipping
@@ -163,62 +162,53 @@ def _parity_rows(substrates: "tuple[str, ...]", n: int) -> "list[dict]":
     cannot share); everything else — invokes, KV traffic, counter
     registration, compute — must then price identically.
     """
-    rows: list[dict] = []
     expected = dynamic_tree_reduction_expected(n)
-    for substrate in substrates:
-        reports = {}
-        for arm, dag_fn in (("dynamic", dynamic_tree_reduction_dag),
-                            ("static", static_tree_reduction_equivalent)):
-            eng = WukongEngine(
-                _engine_config(substrate,
-                               schedule_ship_mbps=float("inf")))
-            reports[arm] = eng.compute(dag_fn(n, compute_ms=5.0))
-        dyn, sta = reports["dynamic"], reports["static"]
-        correct = (np.allclose(dyn.results["reduce"], expected)
-                   and np.allclose(sta.results["reduce"], expected))
-        parity = (dyn.charged_ms == sta.charged_ms
-                  and dyn.tasks == sta.tasks
-                  and np.array_equal(np.asarray(dyn.results["reduce"]),
-                                     np.asarray(sta.results["reduce"])))
-        rows.append({
-            "label": f"{substrate}_parity_n{n}",
-            "wall_s": dyn.wall_s,
-            "charged_ms": dyn.charged_ms,
-            "static_charged_ms": sta.charged_ms,
-            "tasks": dyn.tasks,
-            "kv_stats": dyn.kv_stats,
-            "parity": parity,
-            "correct": correct,
-            "derived": (f"dyn={dyn.charged_ms:.3f}ms "
-                        f"static={sta.charged_ms:.3f}ms "
-                        f"parity={'ok' if parity else 'BROKEN'}"),
-        })
-    return rows
+    reports = {}
+    for arm, dag_fn in (("dynamic", dynamic_tree_reduction_dag),
+                        ("static", static_tree_reduction_equivalent)):
+        eng = WukongEngine(
+            _engine_config(schedule_ship_mbps=float("inf")))
+        reports[arm] = eng.compute(dag_fn(n, compute_ms=5.0))
+    dyn, sta = reports["dynamic"], reports["static"]
+    correct = (np.allclose(dyn.results["reduce"], expected)
+               and np.allclose(sta.results["reduce"], expected))
+    parity = (dyn.charged_ms == sta.charged_ms
+              and dyn.tasks == sta.tasks
+              and np.array_equal(np.asarray(dyn.results["reduce"]),
+                                 np.asarray(sta.results["reduce"])))
+    return {
+        "label": f"parity_n{n}",
+        "wall_s": dyn.wall_s,
+        "charged_ms": dyn.charged_ms,
+        "static_charged_ms": sta.charged_ms,
+        "tasks": dyn.tasks,
+        "kv_stats": dyn.kv_stats,
+        "parity": parity,
+        "correct": correct,
+        "derived": (f"dyn={dyn.charged_ms:.3f}ms "
+                    f"static={sta.charged_ms:.3f}ms "
+                    f"parity={'ok' if parity else 'BROKEN'}"),
+    }
 
 
 def run(n_events: int = 400, window_ms: float = 125.0,
         crash_ats: "tuple[int, ...]" = (12,),
-        substrates: "tuple[str, ...]" = ("event", "thread"),
         parity_n: int = 16) -> "list[dict]":
     rows: list[dict] = []
-    for substrate in substrates:
-        repeats = 2 if substrate == substrates[0] else 1
-        for rep_i in range(repeats):
-            orch = JobOrchestrator(
-                _orch_config(substrate, n_events, window_ms))
-            rep = orch.run()
-            rows.append(_row(f"{substrate}_stream_run{rep_i + 1}", rep,
-                             orch.last_substrate.trigger_bus, n_events,
-                             derived_extra=f"{n_events}ev@40/s"))
+    for rep_i in range(2):
+        orch = JobOrchestrator(_orch_config(n_events, window_ms))
+        rep = orch.run()
+        rows.append(_row(f"stream_run{rep_i + 1}", rep,
+                         orch.last_substrate.trigger_bus, n_events,
+                         derived_extra=f"{n_events}ev@40/s"))
     for crash_at in crash_ats:
         orch = JobOrchestrator(
-            _orch_config(substrates[0], n_events, window_ms,
-                         crash_at=crash_at))
+            _orch_config(n_events, window_ms, crash_at=crash_at))
         rep = orch.run_with_recovery()
-        rows.append(_row(f"{substrates[0]}_crash_at{crash_at}", rep,
+        rows.append(_row(f"crash_at{crash_at}", rep,
                          orch.last_substrate.trigger_bus, n_events,
                          derived_extra=f"crash@dispatch#{crash_at}"))
-    rows.extend(_parity_rows(substrates, parity_n))
+    rows.append(_parity_row(parity_n))
     return rows
 
 
@@ -228,7 +218,7 @@ def check_gates(rows: "list[dict]") -> None:
     a mid-stream dispatcher crash, dynamic/static charged parity."""
     import sys
 
-    stream_rows = [r for r in rows if "_stream_run" in r["label"]]
+    stream_rows = [r for r in rows if r["label"].startswith("stream_run")]
     assert stream_rows, "streaming gate: no streaming rows in fig19"
     for row in stream_rows:
         assert row["completed"] == row["jobs"] and row["failed"] == 0, (
@@ -251,7 +241,7 @@ def check_gates(rows: "list[dict]") -> None:
                 f"determinism regression: {row['label']}.{key} = "
                 f"{row[key]!r} != {base['label']}.{key} = {base[key]!r}")
 
-    crashed = [r for r in rows if "_crash_at" in r["label"]]
+    crashed = [r for r in rows if r["label"].startswith("crash_at")]
     assert crashed, "crash gate: no crashed runs in fig19 rows"
     for row in crashed:
         assert row["crashes"] > 0, (
@@ -267,7 +257,7 @@ def check_gates(rows: "list[dict]") -> None:
             f"crash regression: {row['label']} duplicated a trigger "
             f"job id across recovery")
 
-    parity = [r for r in rows if "_parity_" in r["label"]]
+    parity = [r for r in rows if r["label"].startswith("parity_")]
     assert parity, "parity gate: no parity rows in fig19"
     for row in parity:
         assert row["correct"], (
@@ -276,14 +266,12 @@ def check_gates(rows: "list[dict]") -> None:
         assert row["parity"], (
             f"parity regression: {row['label']} dynamic charged "
             f"{row['charged_ms']} != static {row['static_charged_ms']}")
-    assert len({r["charged_ms"] for r in parity}) == 1, (
-        "parity regression: dynamic charged_ms differs across substrates")
 
     print(f"# streaming gate OK: {len(stream_rows)} runs bit-identical "
           f"({base['windows_closed']} windows, "
           f"{base['sustained_jobs_per_s']:.2f} jobs/s sustained), "
           f"{len(crashed)} crashed sweeps exactly-once, "
-          f"dynamic/static parity on {len(parity)} substrates",
+          "dynamic/static parity",
           file=sys.stderr)
 
 

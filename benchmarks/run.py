@@ -5,17 +5,12 @@ Prints ``name,us_per_call,derived`` CSV rows and writes a
 kv_stats per figure) at the repo root so the perf trajectory is tracked
 across PRs.
 
-Benchmarks run on the deterministic virtual clock by default
-(``SIM_SCALE == 0``): ``wall_s`` is the simulated makespan,
-bit-identical across runs. Setting ``REPRO_SIM_SCALE > 0`` re-enables
-the seed real-time mode (simulated latencies really sleep) for
-cross-checks. Problem-size knobs: ``--quick`` (smaller sizes) and
-``--smoke`` (toy sizes; a CI regression gate that executes every
-figure's engines end-to-end in seconds, plus a data-plane gate, a
-virtual-clock gate asserting determinism and the >=10x wall-time
-speedup over the seed SIM_SCALE=0.1 real-time path, and the fig16
-scale gate asserting the event-driven substrate's >=5x speedup over
-the thread-per-actor cross-check mode and the 10^5-task wall budget).
+Benchmarks run on the deterministic simulation clock: ``wall_s`` is the
+simulated makespan, bit-identical across runs. Problem-size knobs:
+``--quick`` (smaller sizes) and ``--smoke`` (toy sizes; a CI regression
+gate that executes every figure's engines end-to-end in seconds, plus
+the data-plane, platform, multi-tenant, scale, recovery, locality and
+streaming gates).
 """
 from __future__ import annotations
 
@@ -93,68 +88,6 @@ def _time_schedule_generation() -> dict:
     return out
 
 
-def _virtual_mode_trajectory(smoke: bool) -> dict:
-    """The PR 3 acceptance record: fig07's 512-leaf tree reduction under
-    the virtual clock — two seeded runs must produce identical results /
-    charged_ms / simulated makespan, and the virtual run must beat the
-    seed ``SIM_SCALE=0.1`` real-time path by >= 10x wall time. Recorded
-    in BENCH_results.json; asserted under ``--smoke``."""
-    import time as _t
-
-    from repro.apps import tree_reduction_dag
-    from repro.core import CostModel, EngineConfig, WukongEngine
-
-    # 512 leaves, 12 s tasks along a 10-level critical path, 1 MB edge
-    # payloads (the fig07 shape): ~120 s of simulated time. The virtual
-    # run's wall time is flat in task duration (same event count), the
-    # real-time run's scales with it — exactly the decoupling the
-    # virtual clock exists to provide.
-    dag = tree_reduction_dag(1024, compute_ms=12000.0,
-                             payload_bytes=1 << 20)
-
-    def run_once(time_scale: float):
-        eng = WukongEngine(EngineConfig(cost=CostModel(
-            time_scale=time_scale)))
-        t0 = _t.perf_counter()
-        rep = eng.compute(dag)
-        elapsed = _t.perf_counter() - t0
-        (_, root), = rep.results.items()
-        return {"elapsed_s": elapsed, "sim_wall_s": rep.wall_s,
-                "charged_ms": rep.charged_ms, "root": float(root[0])}
-
-    v1 = run_once(0.0)
-    v2 = run_once(0.0)
-    rt = run_once(0.1)  # the seed real-time path (SIM_SCALE=0.1)
-    deterministic = (v1["charged_ms"] == v2["charged_ms"]
-                     and v1["sim_wall_s"] == v2["sim_wall_s"]
-                     and v1["root"] == v2["root"])
-    speedup = rt["elapsed_s"] / min(v1["elapsed_s"], v2["elapsed_s"])
-    out = {
-        "workload": "fig07 512-leaf TR, 12000ms tasks, 1MB payloads",
-        "virtual_wall_s": min(v1["elapsed_s"], v2["elapsed_s"]),
-        "virtual_sim_makespan_s": v1["sim_wall_s"],
-        "virtual_charged_ms": v1["charged_ms"],
-        "realtime_wall_s": rt["elapsed_s"],
-        "speedup_vs_realtime": speedup,
-        "deterministic": deterministic,
-    }
-    print(f"# virtual clock (512-leaf TR): sim makespan "
-          f"{v1['sim_wall_s']:.1f}s in {out['virtual_wall_s']:.2f}s wall; "
-          f"seed real-time path {rt['elapsed_s']:.2f}s wall -> "
-          f"{speedup:.1f}x; deterministic={deterministic}",
-          file=sys.stderr)
-    if smoke:
-        if not deterministic:
-            raise SystemExit(
-                "virtual-clock regression: two identical runs diverged "
-                f"({v1} vs {v2})")
-        if speedup < 10.0:
-            raise SystemExit(
-                f"virtual-clock regression: only {speedup:.1f}x over the "
-                "seed real-time path (>= 10x required)")
-    return out
-
-
 def _check_platform_gate(rows_by_fig: dict, smoke_kwargs: dict) -> None:
     """CI regression gate for the stateful platform model:
 
@@ -164,14 +97,8 @@ def _check_platform_gate(rows_by_fig: dict, smoke_kwargs: dict) -> None:
     - *warm pool pays*: container reuse must strictly lower the charged
       simulated latency relative to the all-cold (keep_alive=0) pool.
     """
-    from benchmarks import common, fig14_platform
+    from benchmarks import fig14_platform
 
-    if common.SIM_SCALE > 0:
-        # Bit-identity is a virtual-clock property; under the real-time
-        # cross-check mode wall_s is real elapsed time and thread timing
-        # perturbs the throttle/pool counters.
-        print("# platform gate skipped (real-time mode)", file=sys.stderr)
-        return
     rows = {r["label"]: r for r in rows_by_fig.get("fig14", [])}
     warm, cold = rows.get("warm_pool"), rows.get("cold_pool")
     if warm is None or cold is None:
@@ -213,11 +140,8 @@ def _check_multitenant_gate(rows_by_fig: dict, smoke_kwargs: dict) -> None:
     - *pooling pays*: the shared warm pool's p50 job latency must be
       strictly below the isolated-per-job baseline's.
     """
-    from benchmarks import common, fig15_multitenant
+    from benchmarks import fig15_multitenant
 
-    if common.SIM_SCALE > 0:
-        print("# multitenant gate skipped (real-time mode)", file=sys.stderr)
-        return
     rows = {r["label"]: r for r in rows_by_fig.get("fig15", [])}
     rate = smoke_kwargs["rates"][0]
     n_tenants = 4
@@ -357,30 +281,26 @@ def main() -> None:
                   dict(n_jobs=64, rates=(2.0, 8.0), tenant_counts=(2, 4),
                        max_concurrent_jobs=32),
                   dict()),
-        # The substrate scaling curve (PR 6). Smoke = the CI gate tiers
-        # (>= 5x substrate speedup at 4096 leaves, 10^5 engine tasks
-        # < 30 s); full adds the 10^6-task event-only tier.
+        # The simulator's scaling curve (PR 6). Smoke = the CI gate tiers
+        # (10^5 engine tasks < 30 s); full adds the 10^6-task tier.
         "fig16": (fig16_scaling.run,
                   dict(),
                   dict(),
                   dict(micro_leaves=(1024, 4096, 16384),
-                       engine_tiers=((8192, True), (131072, False),
-                                     (1 << 20, False)))),
+                       engine_tiers=(8192, 131072, 1 << 20))),
         # Crash-recovery cost curves (durable control plane). The smoke
-        # sweep crashes the dispatcher at all three protocol points on
-        # BOTH simulation substrates and gates on journal billing parity.
+        # sweep crashes the dispatcher at all three protocol points and
+        # gates on journal billing parity.
         "fig17": (fig17_recovery.run,
                   dict(n_jobs=12, rate=8.0, crash_ats=(2,),
-                       substrates=("event", "thread"),
                        max_concurrent_jobs=4),
                   dict(n_jobs=32, rate=8.0, crash_ats=(1, 4),
-                       substrates=("event", "thread"),
                        max_concurrent_jobs=8),
                   dict(n_jobs=64, crash_ats=(1, 4, 16))),
         # Locality series (multi-tier container cache vs cacheless) on
         # the two data-intensive shapes. Smoke = the CI locality gate
         # (cache strictly cheaper, tier-0 hits > 0, bit-identical
-        # across runs and substrates); full adds a capacity sweep.
+        # across runs); full adds a capacity sweep.
         "fig18": (fig18_locality.run,
                   dict(gemm_sizes=((512, 128),), tree_n=256),
                   dict(gemm_sizes=((512, 128),), tree_n=512),
@@ -389,15 +309,13 @@ def main() -> None:
         # Steady-state streaming via the trigger bus (event-fired jobs,
         # windowed aggregation, dynamic-DAG parity, mid-stream crash).
         # Smoke = the CI streaming gate: >= 64 window jobs, all four
-        # trigger sources live, bit-identical metrics across runs and
-        # substrates, exactly-once fires across a dispatcher crash.
+        # trigger sources live, bit-identical metrics across runs,
+        # exactly-once fires across a dispatcher crash.
         "fig19": (fig19_streaming.run,
-                  dict(n_events=400, crash_ats=(12,),
-                       substrates=("event", "thread")),
-                  dict(n_events=400, crash_ats=(12, 40),
-                       substrates=("event", "thread")),
+                  dict(n_events=400, crash_ats=(12,)),
+                  dict(n_events=400, crash_ats=(12, 40)),
                   dict(n_events=1200, crash_ats=(12, 40, 120),
-                       substrates=("event", "thread"), parity_n=64)),
+                       parity_n=64)),
     }
     mode = 0 if args.smoke else (1 if args.quick else 2)
     only = set(args.only.split(",")) if args.only else None
@@ -414,8 +332,6 @@ def main() -> None:
 
     snapshot = {
         "mode": ("smoke" if args.smoke else "quick" if args.quick else "full"),
-        "sim_scale": common.SIM_SCALE,
-        "clock": "virtual" if common.SIM_SCALE == 0 else "realtime",
         "schedule_generation": _time_schedule_generation(),
         "figures": {
             name: {r["label"]: _json_row(r) for r in rows}
@@ -423,15 +339,11 @@ def main() -> None:
         },
     }
     if "fig16" in rows_by_fig:
-        # tasks vs host wall seconds, both substrates where feasible —
-        # the PR 6 acceptance record (fig16's wall_s is HOST seconds,
-        # unlike the simulated wall_s of every other figure).
+        # tasks vs host wall seconds — the PR 6 acceptance record
+        # (fig16's wall_s is HOST seconds, unlike the simulated wall_s
+        # of every other figure).
         snapshot["scaling_curve"] = fig16_scaling.scaling_curve(
             rows_by_fig["fig16"])
-    if only is None:
-        # The trajectory's real-time leg costs ~12 s of genuine sleeping;
-        # skip it when a dev is iterating on a single figure via --only.
-        snapshot["virtual_mode"] = _virtual_mode_trajectory(smoke=args.smoke)
     path = os.path.normpath(RESULTS_JSON)
     with open(path, "w") as f:
         json.dump(snapshot, f, indent=1, sort_keys=True)

@@ -12,7 +12,7 @@ import numpy as np
 
 from repro.apps import randomized_svd_dag
 from repro.apps.svd import randomized_svd_expected
-from repro.core import CostModel, EngineConfig, WukongEngine
+from repro.core import EngineConfig, WukongEngine
 
 
 def main() -> None:
@@ -21,7 +21,7 @@ def main() -> None:
     ap.add_argument("--blocks", type=int, default=8)
     args = ap.parse_args()
 
-    eng = WukongEngine(EngineConfig(cost=CostModel(time_scale=0.05)))
+    eng = WukongEngine(EngineConfig())
 
     for ideal in (False, True):
         dag = randomized_svd_dag(args.n, 5, 5, args.blocks,

@@ -5,9 +5,8 @@ nondeterminism it can see syntactically; this module catches the ones
 it can't, at runtime. In trace mode every effect an actor yields —
 ``("charge", ms)``, ``("get", q, t)``, … — is journaled as a
 :class:`TraceEvent` ``(actor, seq, effect, charge, src)`` tuple, and
-:func:`diff_traces` compares two journals (two runs of the same job, or
-an EventClock run against a VirtualClock cross-check) and reports the
-FIRST divergent event with the actor and source line that produced it
+:func:`diff_traces` compares two journals (two runs of the same job)
+and reports the FIRST divergent event with the actor and source line that produced it
 — turning "charged_ms differs in the 9th decimal" into "frame
 invoker#12, kvstore.py:431, charged 3.07 vs 3.11".
 
@@ -22,13 +21,13 @@ Usage::
     div = diff_traces(trace_a, trace_b)
     assert div is None, div.describe()
 
-The hook is duck-typed: the substrates call ``tracer.record(actor,
+The hook is duck-typed: the clock calls ``tracer.record(actor,
 effect, gen)`` on every freshly generated effect (replayed/deferred
 effects are not re-recorded), so ``repro.core.simclock`` never imports
-this module. Event order is deterministic on both virtual substrates
-(FIFO ready queues, (deadline, seq) timers), so two traced runs of a
-deterministic job produce identical journals; the thread substrate
-additionally interleaves *unrelated* actors' records under the OS
+this module. Event order is deterministic (FIFO ready queues,
+(deadline, seq) timers), so two traced runs of a deterministic job
+produce identical journals. Threads outside the frames that drive
+effects through ``run_effects`` interleave their records under the OS
 scheduler, which is what :func:`diff_traces`'s ``by_actor`` mode is
 for — per-actor effect sequences are deterministic even when the
 global interleaving is not.
@@ -48,7 +47,7 @@ class TraceEvent:
 
     ``seq``    — global position in this trace (append order).
     ``actor``  — the frame/actor label that yielded the effect
-                 (``name#seq`` — substrate-assigned, deterministic).
+                 (``name#seq`` — clock-assigned, deterministic).
     ``effect`` — the effect kind ("charge", "get", "acquire", "wait",
                  "flush", "sleep").
     ``charge`` — the simulated ms for "charge"/"sleep" effects, None
@@ -65,8 +64,8 @@ class TraceEvent:
     src: str
 
     def signature(self) -> tuple[str, "float | None", str]:
-        """The substrate-independent projection compared by
-        :func:`diff_traces` (actor labels differ across substrates)."""
+        """The projection compared by :func:`diff_traces` (actor
+        labels are reported, not compared)."""
         return (self.effect, self.charge, self.src)
 
 
@@ -90,15 +89,16 @@ def _source_of(gen: Any) -> str:
 class Tracer:
     """Collects :class:`TraceEvent` records; attach as ``clock.tracer``.
 
-    Thread-safe: on the thread substrate multiple actor threads record
-    concurrently (the lock keeps ``seq`` consistent with list order)."""
+    Thread-safe: threads driving effects through ``run_effects`` may
+    record concurrently (the lock keeps ``seq`` consistent with list
+    order)."""
 
     def __init__(self) -> None:
         self.events: list[TraceEvent] = []
         self._lock = threading.Lock()
 
     def record(self, actor: str, effect: tuple, gen: Any) -> None:
-        """Substrate hook: journal one freshly generated effect."""
+        """Clock hook: journal one freshly generated effect."""
         kind = effect[0]
         charge = float(effect[1]) if kind in ("charge", "sleep") else None
         src = _source_of(gen)
@@ -166,9 +166,8 @@ def diff_traces(a: "Tracer | Iterable[TraceEvent]",
     """First divergence between two effect traces, or None.
 
     Events compare by ``(effect, charge, src)`` — actor labels are
-    reported, not compared, so an EventClock trace diffs cleanly
-    against a VirtualClock one. Default mode compares the global
-    journal order (exact for the deterministic substrates); ``by_actor``
+    reported, not compared. Default mode compares the global journal
+    order (exact for the frames' deterministic order); ``by_actor``
     compares each actor's own effect sequence instead, pairing the
     k-th distinct actor of one trace with the k-th of the other (spawn
     order is deterministic even where thread interleaving is not) and

@@ -1,8 +1,7 @@
 """Effect-protocol static analysis: the determinism contract as lint rules.
 
 The simulation's core claim — bit-identical ``charged_ms`` / billed USD
-across the EventClock and VirtualClock substrates and across runs —
-rests on discipline that used to be enforced only by review:
+across runs — rests on discipline that used to be enforced only by review:
 
 - **No wall clock in actor code** (``REPRO001``): every duration and
   deadline goes through the engine clock. A ``time.time()`` in a cost
@@ -18,13 +17,14 @@ rests on discipline that used to be enforced only by review:
   is how the codebase marks cross-actor state — frame-confined objects
   like a per-invocation ``TaskExecutor`` mutate freely), a
   threading lock held across a yield (``REPRO011`` — the frame parks
-  while an OS lock stays taken: deadlock on the event substrate),
+  while an OS lock stays taken: deadlock on the clock's one driver
+  thread),
   blocking KV wrappers called inside a generator frame (``REPRO012`` —
   ``kv.get`` is ``run_effects(clock, kv.get_g(...))``, which raises
   ``RuntimeError`` inside a frame; compose with ``yield from`` instead),
   and a ``task_clock`` block not followed by ``yield ("flush",)``
   (``REPRO013`` — compute charged inside the task function is deferred
-  on the event substrate; reading ``now_ms`` before flushing skews the
+  by the clock; reading ``now_ms`` before flushing skews the
   recorded compute/write split).
 - **Key hygiene** (``REPRO020``/``REPRO021``): ``::`` is the KV
   namespace separator — a bare key literal containing it bypasses
@@ -41,7 +41,7 @@ outside the simulation substrate and are exempt. Key-hygiene rules
 apply everywhere.
 
 Suppression: ``ALLOW`` grandfathers whole files that ARE the substrate
-(``core/simclock.py`` implements the clocks out of ``time.*`` — that is
+(``core/simclock.py`` implements the clock out of ``time.*`` — that is
 its job). Individual legitimate sites carry a line pragma instead::
 
     time.sleep(s)  # lint: allow(REPRO001) — real-sleep knob, off by default
@@ -433,8 +433,8 @@ class _ModuleLint(ast.NodeVisitor):
                     for item in stmt.items)
                 if lockish and _contains_yield(stmt):
                     # REPRO011: the frame would suspend holding an OS
-                    # lock; on the event substrate every other frame
-                    # shares this driver thread — deadlock.
+                    # lock; every other frame shares this driver
+                    # thread — deadlock.
                     self.report(
                         "REPRO011", stmt,
                         f"lock held across a yield in {fn.name}; a parked "
@@ -450,9 +450,8 @@ class _ModuleLint(ast.NodeVisitor):
                             "REPRO013", stmt,
                             f"task_clock block in {fn.name} not followed "
                             f"by yield (\"flush\",); compute charged "
-                            f"inside the task is deferred on the event "
-                            f"substrate and must be flushed before "
-                            f"reading the clock")
+                            f"inside the task is deferred by the clock "
+                            f"and must be flushed before reading it")
                 self._walk_statements(
                     stmt.body, fn, state, self_name, effect_gen,
                     lock_depth + (1 if lockish else 0))

@@ -37,21 +37,19 @@ def _add(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def gemm_dag(n: int, block_size: int, seed_a: int = 1, seed_b: int = 2,
-             sleep_per_flop: float = 0.0, ms_per_flop: float = 0.0) -> DAG:
+             ms_per_flop: float = 0.0) -> DAG:
     """DAG computing C = A @ B for n x n matrices in block_size blocks.
 
     Roots are the bxb output blocks ``gemm-C-i-j``. ``ms_per_flop`` adds
     a simulated compute duration per task proportional to its analytic
     FLOPs, charged on the engine clock — the knob that emulates the
     paper's compute-heavy regime on a single-core container (same
-    methodology as TR's delays, paper Fig. 4). ``sleep_per_flop`` is the
-    legacy real-sleep variant (seconds per flop), kept for real-time
-    cross-checks.
+    methodology as TR's delays, paper Fig. 4).
     """
     from repro.apps.costing import flop_costed
 
     def costed(fn, flops):
-        return flop_costed(fn, flops, sleep_per_flop, ms_per_flop)
+        return flop_costed(fn, flops, ms_per_flop)
 
     if n % block_size:
         raise ValueError("n must be divisible by block_size")

@@ -54,7 +54,6 @@ def svc_dag(
     lr: float = 0.1,
     reg: float = 1e-3,
     seed: int = 5,
-    sleep_per_flop: float = 0.0,
     ms_per_flop: float = 0.0,
 ) -> DAG:
     if n_samples % n_blocks:
@@ -65,7 +64,7 @@ def svc_dag(
     def costed(fn):
         from repro.apps.costing import flop_costed
 
-        return flop_costed(fn, grad_flops, sleep_per_flop, ms_per_flop)
+        return flop_costed(fn, grad_flops, ms_per_flop)
 
     g = GraphBuilder()
 

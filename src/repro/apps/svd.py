@@ -50,12 +50,12 @@ def _singular_values(r: jax.Array) -> jax.Array:
     return jnp.linalg.svd(r, compute_uv=False)
 
 
-def _costed(fn, flops, sleep_per_flop, ms_per_flop=0.0):
+def _costed(fn, flops, ms_per_flop=0.0):
     """Per-task compute cost from analytic FLOPs (see
     repro.apps.costing.flop_costed)."""
     from repro.apps.costing import flop_costed
 
-    return flop_costed(fn, flops, sleep_per_flop, ms_per_flop)
+    return flop_costed(fn, flops, ms_per_flop)
 
 
 def tsqr_svd_dag(
@@ -64,14 +64,13 @@ def tsqr_svd_dag(
     n_blocks: int = 8,
     seed: int = 3,
     compute_u: bool = True,
-    sleep_per_flop: float = 0.0,
     ms_per_flop: float = 0.0,
 ) -> DAG:
     """SVD1: tall-and-skinny (rows >> cols) SVD via TSQR.
 
-    ``ms_per_flop`` (simulated, clock-charged) / ``sleep_per_flop``
-    (legacy real sleep) simulate compute duration per task from analytic
-    FLOPs (single-core container; same methodology as TR's delays)."""
+    ``ms_per_flop`` (simulated, clock-charged) simulates compute duration
+    per task from analytic FLOPs (single-core container; same methodology
+    as TR's delays)."""
     if rows % n_blocks:
         raise ValueError("rows must divide evenly into n_blocks")
     block_rows = rows // n_blocks
@@ -86,7 +85,7 @@ def tsqr_svd_dag(
         return make
 
     blocks = [g.add(leaf(i), name=f"svd1-A-{i}") for i in range(n_blocks)]
-    rs = [g.add(_costed(_qr_r, qr_flops, sleep_per_flop, ms_per_flop), blk,
+    rs = [g.add(_costed(_qr_r, qr_flops, ms_per_flop), blk,
                 name=f"svd1-R0-{i}")
           for i, blk in enumerate(blocks)]
     depth = 0
@@ -110,7 +109,7 @@ def tsqr_svd_dag(
 
         for i, blk in enumerate(blocks):
             g.add(_costed(u_block, 2.0 * block_rows * cols ** 2,
-                          sleep_per_flop, ms_per_flop),
+                          ms_per_flop),
                   blk, final_r, name=f"svd1-U-{i}")
     return g.build()
 
@@ -131,7 +130,6 @@ def randomized_svd_dag(
     n_blocks: int = 8,
     seed: int = 4,
     ideal_storage: bool = False,
-    sleep_per_flop: float = 0.0,
     ms_per_flop: float = 0.0,
 ) -> DAG:
     """SVD2: rank-``rank`` randomized SVD of an n x n matrix [Halko et al.].
@@ -149,7 +147,7 @@ def randomized_svd_dag(
     g = GraphBuilder()
 
     def costed(fn, flops=blk_mm_flops):
-        return _costed(fn, flops, sleep_per_flop, ms_per_flop)
+        return _costed(fn, flops, ms_per_flop)
 
     @functools.partial(jax.jit, static_argnums=(0, 1))
     def omega(seed2: int, nn: int) -> jax.Array:

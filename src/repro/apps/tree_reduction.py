@@ -5,13 +5,10 @@ until one remains. An initial array of n numbers yields n/2 leaf tasks at
 the bottom of the DAG (paper Fig. 4 caption). A per-task delay simulates
 a compute task with controllable duration — exactly the paper's
 methodology for sweeping task granularity. ``compute_ms`` declares the
-delay in *simulated* ms charged on the engine clock (free wall-clock
-under the virtual clock, scaled real sleep in real-time mode);
-``sleep_s`` is the seed's real-sleep knob, kept for cross-checks.
+delay in *simulated* ms charged on the engine clock (free in wall-clock
+time).
 """
 from __future__ import annotations
-
-import time
 
 import numpy as np
 
@@ -22,7 +19,6 @@ from repro.core.simclock import simulated_compute
 
 def tree_reduction_dag(
     n: int = 1024,
-    sleep_s: float = 0.0,
     chunk: np.ndarray | None = None,
     payload_bytes: int = 0,
     compute_ms: float = 0.0,
@@ -32,8 +28,6 @@ def tree_reduction_dag(
     ``compute_ms``    — per-task simulated compute duration in ms, charged
                         on the engine clock (the paper's task-granularity
                         knob).
-    ``sleep_s``       — per-task REAL sleep seconds (legacy real-time
-                        knob; prefer ``compute_ms``).
     ``payload_bytes`` — optional ballast carried through every edge so the
                         communication-bound regime (paper: "dominated by
                         the communication overhead of transferring the
@@ -47,8 +41,6 @@ def tree_reduction_dag(
     def charge() -> None:
         if compute_ms > 0:
             simulated_compute(compute_ms)
-        if sleep_s > 0:
-            time.sleep(sleep_s)  # lint: allow(REPRO001) — opt-in real-sleep knob, off by default
 
     def make_add(a: float, b: float):
         def leaf_add() -> np.ndarray:

@@ -60,13 +60,8 @@ from repro.core.orchestrator import (
 from repro.core.schedule import StaticSchedule, generate_static_schedules
 from repro.core.simclock import (
     EventClock,
-    RealtimeClock,
-    VirtualClock,
-    clock_for_scale,
-    drain_worker_cache,
     run_effects,
     simulated_compute,
-    worker_cache_size,
 )
 from repro.core.statemachine import (
     ADMITTED,
@@ -125,8 +120,6 @@ __all__ = [
     "StaticSchedule", "generate_static_schedules",
     "OptimizeConfig", "CompiledDAG", "PassStats", "compile_dag",
     "ALL_PASSES", "NO_PASSES",
-    "EventClock", "VirtualClock", "RealtimeClock", "clock_for_scale",
-    "run_effects", "drain_worker_cache", "worker_cache_size",
-    "simulated_compute",
+    "EventClock", "run_effects", "simulated_compute",
     "PlatformConfig", "FaaSPlatform",
 ]

@@ -25,9 +25,8 @@ gets a fresh cache; keep-alive expiry drops the container's cache with
 the container (``ContainerPool`` notifies the registry).
 
 Every charged operation is an effect-protocol generator (``..._g``), so
-costs land on the engine clock identically under the event and thread
-substrates — cached runs stay bit-identical across substrates and
-repeats, like every other charge in the system.
+costs land on the engine clock like every other charge in the system —
+cached runs stay bit-identical across repeats.
 
 Keys are *store-qualified* (namespace prefix included): a container is
 shared across the jobs of one platform function, so two jobs' bare keys

@@ -21,12 +21,10 @@ Dask because both shared a representation — §V-D; we keep that property
 for every baseline) and the same simulated FaaS cost model.
 
 Time never comes from ``time.*`` here: every wait, deadline, and
-timestamp goes through the engine clock (repro.core.simclock). Under the
-default virtual clock (``CostModel.time_scale == 0``) idle waiting costs
-zero wall time, ``job_timeout_s`` means *simulated* seconds, and
-``JobReport.wall_s`` is the deterministic simulated makespan; with
-``time_scale > 0`` the seed real-time behavior is preserved for
-cross-checks.
+timestamp goes through the engine clock (repro.core.simclock's
+``EventClock``): idle waiting costs zero wall time, ``job_timeout_s``
+means *simulated* seconds, and ``JobReport.wall_s`` is the
+deterministic simulated makespan.
 """
 from __future__ import annotations
 
@@ -65,7 +63,6 @@ from repro.core.simclock import (
     EventClock,
     HostProfile,
     HostRecord,
-    clock_for_scale,
     host_span,
     run_effects,
     task_clock,
@@ -157,12 +154,12 @@ class EngineConfig:
     # max_stripes) since it is a property of the storage substrate.
     batch_kv_round_trips: bool = True
     # Simulated Lambda concurrency (runtime-pool cap). Workers are
-    # created lazily in both clock modes, so the cap can be raised to
-    # AWS-scale (the virtual clock sweeps 8k-64k-task DAGs without the
-    # wall-clock cost that used to bind this to 512).
+    # created lazily, so the cap can be raised to AWS-scale (the clock
+    # sweeps 8k-64k-task DAGs without the wall-clock cost that used to
+    # bind this to 512).
     max_concurrency: int = 4096
-    speculative_poll_s: float = 0.01       # simulated s under VirtualClock
-    job_timeout_s: float = 600.0           # simulated s under VirtualClock
+    speculative_poll_s: float = 0.01       # simulated s
+    job_timeout_s: float = 600.0           # simulated s
     # DAG compiler pipeline run before scheduling (repro.core.optimize);
     # None = run the graph verbatim (the seed behavior). Each pass is
     # independently switchable for §V-B-style factor ablations.
@@ -180,7 +177,7 @@ class EngineConfig:
 @dataclasses.dataclass
 class JobReport:
     results: dict[str, Any]
-    wall_s: float  # simulated makespan (virtual) / real elapsed (realtime)
+    wall_s: float  # simulated makespan
     tasks: int
     executors_invoked: int
     kv_stats: dict[str, int]
@@ -247,8 +244,8 @@ class _ResultWaiter:
 
     Event-driven on the engine clock: the waiter blocks on its
     subscription until a message or the job deadline — no polling, so
-    idle waiting costs zero wall time under the virtual clock and
-    ``timeout_s`` means clock (simulated) seconds."""
+    idle waiting costs zero wall time and ``timeout_s`` means clock
+    (simulated) seconds."""
 
     def __init__(self, kv: ShardedKVStore, roots: tuple[str, ...],
                  dag: "DAG | None" = None):
@@ -324,10 +321,10 @@ def profiler_capturing() -> bool:
     return jax is not None and jax.profiler.TraceAnnotation.is_enabled()
 
 
-def _host_profile(clock: Any) -> HostProfile | None:
+def _host_profile(clock: EventClock) -> HostProfile | None:
     """A HostProfile attached to ``clock`` for the next job, or None
-    unless the clock is an ``EventClock`` and a profiler session captures."""
-    if not isinstance(clock, EventClock) or not profiler_capturing():
+    unless a profiler session captures."""
+    if not profiler_capturing():
         return None
     HOST_LOG.hook_gc(profiler_capturing)
     job = next(_host_jobs)
@@ -351,9 +348,7 @@ class WukongEngine:
         """Run the job to completion on the engine clock.
 
         The job body is an effect generator (``compute_g``); the clock's
-        ``run`` drives it — as the root continuation of the event loop on
-        the event substrate, or inline on the calling (actor) thread on
-        the thread/realtime substrates.
+        ``run`` drives it as the root continuation of the event loop.
 
         While a JAX profiler session captures, a job that runs on a
         store and ``EventClock`` of its own (no ``substrate``, the
@@ -361,14 +356,13 @@ class WukongEngine:
         ``wukong/compile``, ``wukong/walk``, ``wukong/schedule`` and
         ``wukong/task_fn`` on the profiler's trace, and in
         ``JobReport.host_profile`` (``HOST_LAYERS`` above). Nothing is
-        attached on the thread and realtime substrates, nor to an
-        injected or shared substrate (``compute_g``)."""
+        attached to an injected or shared substrate (``compute_g``)."""
         cfg = self.config
         if substrate is not None:
             dag = ensure_compiled(dag, cfg.optimize)
             kv: Any = substrate.kv
             return kv.clock.run(self._compute_g(dag, kv, substrate))
-        clock = clock_for_scale(cfg.cost.time_scale, cfg.cost.substrate)
+        clock = EventClock()
         prof = _host_profile(clock)
         with host_span(prof, "wukong/job"):
             try:
@@ -592,9 +586,7 @@ def _speculative_monitor(ctx, stop, cfg, schedule_set, clock):
     """Re-invoke executors whose current task exceeds the straggler
     threshold (beyond-paper straggler mitigation; safe via idempotence).
 
-    Heartbeat ages come from the engine clock: under the virtual clock
-    they ARE simulated ms; in real-time mode they are real ms scaled back
-    to simulated by ``time_scale`` (the seed behavior)."""
+    Heartbeat ages come from the engine clock, in simulated ms."""
     threshold_ms = cfg.faults.speculative_threshold_ms
     if threshold_ms == float("inf"):
         return
@@ -606,8 +598,7 @@ def _speculative_monitor(ctx, stop, cfg, schedule_set, clock):
         now_ms = clock.now_ms()
         for hb in ctx.heartbeats.inflight():
             age_ms = now_ms - hb.started_at
-            scale = 1.0 if clock.virtual else (cfg.cost.time_scale or 1.0)
-            if age_ms / scale > threshold_ms and hb.executor_id not in respawned:
+            if age_ms > threshold_ms and hb.executor_id not in respawned:
                 respawned.add(hb.executor_id)
                 # Duplicate every member of a coalesced batch, each with
                 # its own covering schedule (a sibling leaf's schedule
@@ -637,7 +628,7 @@ class CentralizedConfig:
     notification: str = "tcp"      # "tcp" (strawman) | "pubsub"
     num_invokers: int = 1          # >1 = parallel-invoker version
     max_concurrency: int = 4096    # lazily-created runtime workers
-    job_timeout_s: float = 600.0   # simulated s under VirtualClock
+    job_timeout_s: float = 600.0   # simulated s
     # DAG compiler pipeline (producer inlining and chain fusion shrink the
     # one-Lambda-per-task graph; the executor-level passes are no-ops
     # here). None = verbatim.
@@ -733,7 +724,7 @@ class _CentralizedEngine:
                     with task_clock(compute_clock):
                         out = task.fn(*args, **kwargs)
                     # Flush compute deferred inside the task function
-                    # (event substrate) before reading the clock delta.
+                    # before reading the clock delta.
                     yield ("flush",)
                     compute_ms = clock.now_ms() - t0
                     t0 = clock.now_ms()
@@ -845,7 +836,7 @@ class ServerfulConfig:
     cost: CostModel = dataclasses.field(default_factory=CostModel)
     n_workers: int = 25            # paper EC2: 5 VMs x 5 worker processes
     worker_bandwidth_mbps: float = 1000.0  # direct worker<->worker TCP
-    job_timeout_s: float = 600.0   # simulated s under VirtualClock
+    job_timeout_s: float = 600.0   # simulated s
     optimize: OptimizeConfig | None = None  # DAG compiler (chain fusion)
     # Fixed-cluster billing (the serverless counterpart bills GB-seconds
     # through repro.platform): the cluster costs VM-hours for the job's
@@ -914,7 +905,7 @@ class ServerfulEngine:
                     with task_clock(clock):
                         out = task.fn(*args, **kwargs)
                     # Flush compute deferred inside the task function
-                    # (event substrate) before reading the clock delta.
+                    # before reading the clock delta.
                     yield ("flush",)
                     compute_ms = clock.now_ms() - t0
                     with owner_lock:

@@ -49,7 +49,7 @@ from repro.core.faults import (
 )
 from repro.core.kvstore import ShardedKVStore, sizeof
 from repro.core.schedule import StaticSchedule, _counter_id
-from repro.core.simclock import BaseClock, task_clock
+from repro.core.simclock import EventClock, task_clock
 
 RESULTS_CHANNEL = "__results__"
 
@@ -57,11 +57,11 @@ RESULTS_CHANNEL = "__results__"
 class TaskMetrics:
     """Per-task timing records for the Fig.13-style CDF breakdown.
 
-    Every record is stamped ``at_ms`` from the engine clock — virtual
-    milliseconds under the virtual clock, so the fig13 CDF is
-    deterministic and independent of host load."""
+    Every record is stamped ``at_ms`` from the engine clock — simulated
+    milliseconds, so the fig13 CDF is deterministic and independent of
+    host load."""
 
-    def __init__(self, clock: BaseClock | None = None,
+    def __init__(self, clock: EventClock | None = None,
                  enabled: bool = True) -> None:
         self._lock = threading.Lock()
         self.clock = clock
@@ -295,8 +295,7 @@ class TaskExecutor:
     def run_g(self):
         """The executor body as an effect-protocol generator (simclock).
 
-        Drive it with ``clock.spawn`` (event substrate runs it as a frame,
-        thread substrates interpret it via ``run_effects``)."""
+        Drive it with ``clock.spawn``, which runs it as a frame."""
         hb = ExecutorHeartbeat(
             executor_id=self.executor_id,
             start_key=self.start_key,
@@ -494,10 +493,9 @@ class TaskExecutor:
                 t0 = clock.now_ms()
                 with task_clock(self.ctx.compute_clock):
                     out = dag.tasks[current].fn(*args, **kwargs)
-                # Event substrate: compute charged inside the task function
-                # is deferred (the function cannot yield); flush it onto the
-                # clock before reading the delta. No-op on the thread
-                # substrates.
+                # Compute charged inside the task function is deferred
+                # (the function cannot yield); flush it onto the clock
+                # before reading the delta.
                 yield ("flush",)
                 compute_ms = clock.now_ms() - t0
                 self.tasks_executed += 1

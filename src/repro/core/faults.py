@@ -183,7 +183,7 @@ class ExecutorHeartbeat:
     executor_id: int
     start_key: str
     current_key: str
-    started_at: float  # engine-clock ms (virtual ms under VirtualClock)
+    started_at: float  # engine-clock ms (simulated)
     parent: str | None = None
     # Full start batch for coalesced executors (speculative duplicates
     # must cover every member, not just the first).

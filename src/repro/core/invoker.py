@@ -32,9 +32,8 @@ Two provider models decide cold starts:
 
 All blocking (work queues, lane threads) goes through the engine clock's
 effect protocol (``simclock``): lanes and the proxy server are generator
-actors, so on the event substrate an idle invoker lane is a parked
-continuation — no OS thread — and on the thread substrates it degrades
-to the familiar blocking loop via ``run_effects``.
+actors, so an idle invoker lane is a parked continuation — no OS
+thread.
 """
 from __future__ import annotations
 
@@ -42,7 +41,7 @@ import threading
 from typing import TYPE_CHECKING, Any, Callable
 
 from repro.core.kvstore import CostModel
-from repro.core.simclock import BaseClock, run_effects
+from repro.core.simclock import EventClock, run_effects
 
 if TYPE_CHECKING:  # import cycle: repro.platform imports repro.core
     from repro.platform import FaaSPlatform
@@ -61,7 +60,7 @@ class InvokerPool:
         self,
         n_invokers: int,
         cost: CostModel,
-        clock: BaseClock,
+        clock: EventClock,
         runtime_pool: Any,
         name: str = "invoker",
         platform: "FaaSPlatform | None" = None,
@@ -203,7 +202,7 @@ class FanoutProxy:
 
     def _serve(self):
         # Event-driven: the proxy parks on its subscription (costing
-        # zero wall time — and, on the event substrate, zero threads)
+        # zero wall time and no thread)
         # until a fan-out message or the ``None`` shutdown sentinel
         # published by ``close``.
         while not self._stop.is_set():

@@ -6,9 +6,8 @@ container has no AWS, the *costs* of the serverless environment are
 simulated and the *algorithms* are real:
 
 - every op pays a base latency plus size/bandwidth transfer time, charged
-  on the engine clock (repro.core.simclock) — the deterministic virtual
-  discrete-event clock by default, the seed real-sleep mode when
-  ``CostModel.time_scale > 0``,
+  on the engine clock, the deterministic discrete-event clock of
+  repro.core.simclock,
 - a shard's transfer lane is held for the duration of a transfer, so
   concurrent large transfers to one shard queue up — this reproduces the
   NIC contention that §V-B measured ("running each KV Store shard on its
@@ -57,7 +56,6 @@ assigned — two identical jobs on one substrate report identically.
 from __future__ import annotations
 
 import dataclasses
-import os
 import pickle
 import random
 import threading
@@ -65,9 +63,8 @@ import zlib
 from typing import Any, Iterable, Mapping
 
 from repro.core.simclock import (
-    BaseClock,
+    EventClock,
     _current_frame,
-    clock_for_scale,
     in_layer,
     run_effects,
 )
@@ -95,17 +92,16 @@ PURGED = _Purged()
 # Per-actor stats sink: while a KVNamespace call is on the stack, the
 # parent store's counter bumps are mirrored into the view's own KVStats
 # (the view can't re-derive byte counts — entry sizes are recorded once
-# at put time and not returned by the ops). On the event substrate the
-# sink rides on the *frame* (the op suspends and resumes inside the
-# scope, and many frames share one driver thread); thread-locals remain
-# the fallback for the thread substrates and external callers.
+# at put time and not returned by the ops). Inside a frame the sink
+# rides on the *frame* (the op suspends and resumes inside the scope,
+# and many frames share one driver thread); thread-locals remain the
+# fallback for external threads.
 _stats_sink = threading.local()
 
 
 class _SinkScope:
     """Installs a view as the current actor's stats sink for one parent
-    call (frame-scoped under the event substrate, thread-scoped
-    otherwise)."""
+    call (frame-scoped inside a frame, thread-scoped otherwise)."""
 
     __slots__ = ("view", "_prev", "_frame")
 
@@ -157,18 +153,9 @@ class CostModel:
     Defaults follow the paper's measurements where it gives them
     (invoke_ms ~50ms via boto3) and plausible AWS numbers elsewhere.
 
-    ``time_scale`` selects the clock mode (repro.core.simclock): 0 — the
-    default — runs on a deterministic virtual discrete-event clock
-    (idle simulated time costs zero wall time, runs are bit-identical);
-    > 0 keeps the seed real-time mode, really sleeping
-    ``ms * time_scale / 1e3`` seconds per charge, for sanity
-    cross-checks against the virtual substrates.
-
-    ``substrate`` picks the virtual scheduler when ``time_scale == 0``:
-    ``"event"`` (the default; override via ``REPRO_SIM_SUBSTRATE``) is
-    the continuation/event-driven engine that scales to million-task
-    DAGs; ``"thread"`` is the PR-3 thread-per-actor engine kept as a
-    cross-check mode. Both produce bit-identical charges.
+    Every charge is simulated time on the engine's ``EventClock``
+    (repro.core.simclock): idle simulated time costs zero wall time,
+    and runs are bit-identical.
 
     Invocation latency is a seeded *distribution*, not a constant, when
     the jitter/cold-start knobs are set: each invocation ``index`` draws
@@ -199,10 +186,6 @@ class CostModel:
     # on distinct shards. <= 0 disables striping entirely.
     stripe_threshold_bytes: int = 1 << 20
     max_stripes: int = 8
-    time_scale: float = 0.0
-    substrate: str = dataclasses.field(
-        default_factory=lambda: os.environ.get("REPRO_SIM_SUBSTRATE",
-                                               "event"))
 
     def transfer_ms(self, nbytes: int) -> float:
         return nbytes / (self.kv_bandwidth_mbps * 1e6) * 1e3
@@ -305,7 +288,7 @@ class _Shard:
         self.lock = threading.Lock()          # metadata atomicity
         # Transfer lane (NIC contention): a clock-aware lock, so an actor
         # holding the lane across a simulated transfer cooperates with
-        # the virtual clock instead of wedging it.
+        # the clock instead of wedging it.
         self.lane = lane
 
 
@@ -318,13 +301,12 @@ class ShardedKVStore:
         cost: CostModel | None = None,
         colocate_shards: bool = False,
         counter_mode: str = "edge_set",
-        clock: BaseClock | None = None,
+        clock: EventClock | None = None,
     ):
         if counter_mode not in ("edge_set", "paper"):
             raise ValueError(counter_mode)
         self.cost = cost or CostModel()
-        self.clock: BaseClock = clock or clock_for_scale(
-            self.cost.time_scale, getattr(self.cost, "substrate", "event"))
+        self.clock = clock or EventClock()
         if colocate_shards:
             # all shards share one VM -> one NIC -> one transfer lane
             shared = self.clock.lock()
@@ -796,9 +778,8 @@ class ShardedKVStore:
 
     # -- pub/sub (paper §III-B) ---------------------------------------------
     def subscribe(self, channel: str) -> Any:
-        """Returns a ``queue.Queue``-compatible subscription (clock-aware
-        in virtual mode, so blocked subscribers never hold back virtual
-        time). Callers MUST :meth:`unsubscribe` the returned queue when
+        """Returns a ``queue.Queue``-compatible subscription (clock-aware,
+        so blocked subscribers never hold back simulated time). Callers MUST :meth:`unsubscribe` the returned queue when
         done — on a substrate that outlives one job, an abandoned
         subscription is a leak: it accumulates in ``_channels`` forever
         and every later ``publish`` still fans out to it."""

@@ -9,7 +9,7 @@ the ROADMAP north star (serve heavy traffic from many users) is this
 axis. ServerMix's tradeoff analysis and Triggerflow's multi-workflow
 orchestration both study it; this module makes it runnable here:
 
-- ``Substrate``        — ONE VirtualClock, ONE ShardedKVStore, and (in
+- ``Substrate``        — ONE EventClock, ONE ShardedKVStore, and (in
                          shared mode) ONE stateful FaaS platform for all
                          jobs. Each job sees the store through a per-job
                          ``KVNamespace`` so names never collide while
@@ -20,7 +20,7 @@ orchestration both study it; this module makes it runnable here:
   size (billing rate AND compute speed).
 - ``generate_workload`` — seeded Poisson arrivals with a heavy-tailed
                           size mix over the paper's four applications,
-                          deterministic under the virtual clock.
+                          deterministic on the simulation clock.
 - ``JobOrchestrator``  — admits jobs against ``max_concurrent_jobs``
                          with per-tenant fair admission (least-loaded
                          tenant first), runs each admitted job as a
@@ -492,7 +492,7 @@ class JobOrchestrator:
     ``max_concurrent_jobs`` with per-tenant fairness, and spawns each
     admitted job as its own clock actor running
     ``WukongEngine.compute(dag, substrate=...)``. Completions come back
-    on a clock queue. Under the virtual clock the whole traffic trace —
+    on a clock queue. On the simulation clock the whole traffic trace —
     arrivals, queueing, contention, billing — is bit-identical across
     runs."""
 
@@ -607,8 +607,7 @@ class JobOrchestrator:
 
     def _run_g(self, jobs: "list[JobRequest]", substrate: Substrate):
         """The dispatcher as an effect generator: the clock drives it as
-        the root continuation (event substrate) or inline on the calling
-        actor thread (thread/realtime substrates)."""
+        the root continuation."""
         machine = JobStateMachine(substrate.control())
         # Submission: journal PENDING (with the reconstructible job
         # spec) for every job before any is admitted — from here on the

@@ -212,9 +212,8 @@ class _LeafSchedules(_MappingABC):
         self._clusters = clusters
         self._delayed = delayed
         self._cache: dict[str, StaticSchedule] = {}
-        # Serializes runtime-expansion table extensions (real concurrency
-        # only exists in the realtime clock mode; the virtual substrates
-        # run one actor at a time).
+        # Serializes runtime-expansion table extensions against callers
+        # on other threads (the clock itself runs one frame at a time).
         self._extend_lock = _threading.Lock()
 
     def extend_for_expansion(self, dag, delta) -> StaticSchedule:

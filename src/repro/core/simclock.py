@@ -1,41 +1,18 @@
-"""Virtual-time simulation core: deterministic discrete-event substrates.
+"""Simulated time: one deterministic discrete-event clock.
 
 Every engine layer (KV store, executors, invoker pools, schedulers, the
-fault monitor) charges FaaS latency on a *clock* instead of calling
-``time.sleep``/``time.monotonic`` directly. Three implementations share
-one interface:
-
-- ``EventClock`` (the default, ``substrate="event"``) is a
-  continuation/event-driven scheduler: actors are *frames* — generators
-  yielding effect tuples — driven from a single ready queue by one
-  driver thread. No OS thread per actor, so a million-task DAG
-  simulates without exhausting threads, and a 4096-leaf tree reduction
-  runs an order of magnitude faster than the thread substrate.
-
-- ``VirtualClock`` (``substrate="thread"``) is the PR-3 cooperative
-  discrete-event scheduler over real threads, kept as a cross-check
-  mode: threads register as *actors*; exactly one actor runs at a time
-  (a run token), and every blocking operation yields the token through
-  the clock. Both virtual substrates replay the same event sequence —
-  FIFO ready queues, timers in (deadline, spawn-seq) order, FIFO
-  waiters — so they produce bit-identical ``charged_ms`` / kv_stats /
-  billing for the same job.
-
-- ``RealtimeClock`` (``time_scale > 0``) is the seed behavior kept for
-  sanity cross-checks: charges really sleep ``ms * time_scale / 1e3``
-  seconds, and the primitives are the plain ``threading``/``queue``
-  ones. ``REPRO_SIM_SCALE`` is only needed for this mode.
-
-All clocks expose the *same* primitive factories (``queue()``,
-``lock()``, ``event()``, ``pool()``, ``spawn()``), so the engines
-contain no mode branches: they are written once against the clock and
-the mode is picked by the cost model.
+fault monitor) charges FaaS latency on the clock instead of calling
+``time.sleep``/``time.monotonic`` directly. ``EventClock`` is a
+continuation/event-driven scheduler: actors are *frames* — generators
+yielding effect tuples — driven from a single ready queue by one driver
+thread. No OS thread per actor, so a million-task DAG simulates without
+exhausting threads, and simulated seconds cost no wall time.
 
 Effect protocol
 ---------------
 
-Actor logic is written once as generator functions yielding effect
-tuples; the substrate decides how each effect blocks:
+Actor logic is written as generator functions yielding effect tuples;
+the clock decides how each effect blocks:
 
 - ``("charge", ms)``    — bill ``ms`` simulated ms and advance time.
 - ``("get", q, t)``     — blocking ``q.get(timeout=t)`` (seconds;
@@ -45,28 +22,26 @@ tuples; the substrate decides how each effect blocks:
 - ``("wait", ev, t)``   — blocking ``ev.wait(timeout=t)`` (seconds);
   the yield evaluates to the flag.
 - ``("flush",)``        — advance time past charges deferred by
-  non-yielding code (``simulated_compute`` inside a task function);
-  no-op on the thread substrates where charges advance immediately.
+  non-yielding code (``simulated_compute`` inside a task function).
 - ``("sleep", ms)``     — advance simulated time without billing.
 
 Non-suspending operations (``q.put``, ``ev.set``, ``lock.release``,
-``pool.submit``, ``clock.spawn``) remain direct calls on every
-substrate. On the thread substrates the shared interpreter
-``run_effects`` maps each effect onto the blocking primitive; on the
-``EventClock`` the generator IS the continuation and effects park the
-frame in the scheduler.
+``pool.submit``, ``clock.spawn``) are direct calls. Inside a frame the
+generator IS the continuation and effects park the frame in the
+scheduler; a thread outside the frames drives a generator through
+``run_effects``, which maps each effect onto the blocking primitive.
 
-Determinism contract (virtual substrates): actors are scheduled FIFO in
-the order they became ready; timers fire in (deadline, registration-seq)
-order; queue/lock waiters are served FIFO. Any randomness (invoke-latency
+Determinism contract: frames are scheduled FIFO in the order they
+became ready; timers fire in (deadline, registration-seq) order;
+queue/lock waiters are served FIFO. Any randomness (invoke-latency
 jitter, cold starts, fault injection) is drawn from counters/keys hashed
 with seeds — never from wall time — so two runs of the same job produce
 identical traces.
 
 Threads that never registered as actors (unit tests driving the KV store
 directly, external callers) degrade gracefully: their charges accumulate
-``charged_ms`` without advancing virtual time, and their blocking waits
-use real condition variables with real timeouts.
+``charged_ms`` without advancing simulated time, and their blocking
+waits use real condition variables with real timeouts.
 """
 from __future__ import annotations
 
@@ -87,22 +62,17 @@ from typing import Any, Callable
 
 __all__ = [
     "HOST_LOG",
-    "BaseClock",
     "EventClock",
+    "EventPool",
     "HostLog",
     "HostProfile",
     "HostRecord",
-    "RealtimeClock",
-    "VirtualClock",
     "charge_meter",
-    "clock_for_scale",
-    "drain_worker_cache",
     "host_span",
     "in_layer",
     "run_effects",
     "simulated_compute",
     "task_clock",
-    "worker_cache_size",
 ]
 
 
@@ -114,7 +84,7 @@ __all__ = [
 # kv stats sink) must follow the *frame* instead: when frame A suspends
 # mid-scope and frame B runs, B must not observe A's context. The
 # driver publishes the currently-stepping frame here; thread-locals
-# remain the fallback for the thread substrates and external callers.
+# remain the fallback for threads outside the frames.
 # ---------------------------------------------------------------------------
 
 _frame_ctx = threading.local()
@@ -141,7 +111,7 @@ _task_clock = threading.local()
 class task_clock:
     """Context manager installing ``clock`` as the current task clock."""
 
-    def __init__(self, clock: "BaseClock | None"):
+    def __init__(self, clock: "EventClock | None"):
         self.clock = clock
 
     def __enter__(self) -> None:
@@ -186,11 +156,11 @@ def simulated_compute(ms: float) -> None:
 # Per-thread charge metering (billing).
 #
 # The platform model bills an invocation the simulated time its body
-# *charges* while running — not a wall-clock delta — because charge
-# amounts are identical across clock modes, which makes billed cost
-# bit-identical. The tap lives here so the platform layer never has to
-# patch clock internals. On the EventClock the accumulator rides on the
-# frame (the body suspends and resumes inside the metered scope).
+# *charges* while running — not a wall-clock delta — so billed cost is
+# as deterministic as the charges. The tap lives here so the platform
+# layer never has to patch clock internals. Inside a frame the
+# accumulator rides on the frame (the body suspends and resumes inside
+# the metered scope); outside one, on the thread.
 # ---------------------------------------------------------------------------
 
 _charge_tap = threading.local()
@@ -421,166 +391,16 @@ def in_layer(layer: str, op: Callable[..., Any]) -> Callable[..., Any]:
 
 
 # ---------------------------------------------------------------------------
-# Worker-thread cache.
-#
-# The thread substrates spawn hundreds of short-lived actor threads per
-# job (invoker lanes, runtime-pool workers, monitors). OS thread
-# creation is ~100s of microseconds — a large fraction of a virtual
-# run's wall time — so finished workers park here and get re-dispatched
-# instead of dying. Recycling is invisible to the simulation: the
-# *actor slot* is created deterministically by ``spawn``; which OS
-# thread services it is not an event the scheduler can observe.
-# ---------------------------------------------------------------------------
-
-_WORKER_CACHE_MAX = 2048
-_worker_cache: "list[_CachedWorker]" = []
-_worker_cache_lock = threading.Lock()
-# Bumped by every drain: a worker whose job was dispatched before the
-# latest drain retires when the job ends instead of parking.
-_worker_cache_epoch = 0
-
-
-class _CachedWorker(threading.Thread):
-    def __init__(self) -> None:
-        super().__init__(daemon=True, name="simclock-worker")
-        self._sem = threading.Semaphore(0)
-        self._job: Callable[[], None] | None = None
-        self._epoch = 0
-        self.start()
-
-    def run(self) -> None:
-        while True:
-            self._sem.acquire()
-            job, self._job = self._job, None
-            if job is None:
-                return
-            job()  # an escaping exception retires this thread (no recycle)
-            with _worker_cache_lock:
-                if (len(_worker_cache) >= _WORKER_CACHE_MAX
-                        or self._epoch != _worker_cache_epoch):
-                    return
-                _worker_cache.append(self)
-
-    def dispatch(self, job: "Callable[[], None] | None") -> None:
-        self._epoch = _worker_cache_epoch
-        self._job = job
-        self._sem.release()
-
-
-def _dispatch_to_worker(job: Callable[[], None]) -> None:
-    with _worker_cache_lock:
-        worker = _worker_cache.pop() if _worker_cache else None
-    (worker or _CachedWorker()).dispatch(job)
-
-
-def drain_worker_cache() -> int:
-    """Retire every cached worker thread and return how many were
-    drained. Call between benchmark iterations (or test runs) so idle
-    threads from a thread-substrate run don't linger into — and skew
-    the wall-time of — event-substrate runs. A worker still finishing a
-    job dispatched before the drain retires instead of parking."""
-    global _worker_cache_epoch
-    with _worker_cache_lock:
-        workers = _worker_cache[:]
-        _worker_cache.clear()
-        _worker_cache_epoch += 1
-    for worker in workers:
-        worker.dispatch(None)  # `run` exits on a None job
-    return len(workers)
-
-
-def worker_cache_size() -> int:
-    """Number of idle cached worker threads (observability for tests)."""
-    with _worker_cache_lock:
-        return len(_worker_cache)
-
-
-# ---------------------------------------------------------------------------
-# Shared interface
+# External threads
 # ---------------------------------------------------------------------------
 
 
-class BaseClock:
-    """Accounting shared by all clock implementations."""
-
-    virtual: bool = False
-
-    def __init__(self) -> None:
-        self._charge_lock = threading.Lock()
-        self.charged_ms = 0.0
-        # Opt-in determinism sanitizer (repro.analysis.divergence.Tracer,
-        # duck-typed so the substrate never imports the analysis
-        # package): when set, every freshly generated effect is
-        # journaled via tracer.record(actor, effect, gen). None is free.
-        self.tracer: Any = None
-        # Opt-in host-time profile of the running job (HostProfile,
-        # EventClock only). None is free.
-        self.host_profile: "HostProfile | None" = None
-
-    def _account(self, ms: float) -> None:
-        with self._charge_lock:
-            self.charged_ms += ms
-        frame = _current_frame()
-        if frame is not None:
-            acc = frame.charge_acc
-        else:
-            acc = getattr(_charge_tap, "acc", None)
-        if acc is not None:
-            acc[0] += ms
-
-    # subclass API ----------------------------------------------------------
-    def charge(self, ms: float) -> None:  # bill + advance simulated time
-        raise NotImplementedError
-
-    def now_ms(self) -> float:  # simulated (virtual) / real elapsed ms
-        raise NotImplementedError
-
-    def queue(self) -> Any:  # queue.Queue-compatible
-        raise NotImplementedError
-
-    def lock(self) -> Any:  # context-manager lock (transfer lanes)
-        raise NotImplementedError
-
-    def event(self) -> Any:  # threading.Event-compatible
-        raise NotImplementedError
-
-    def pool(self, max_workers: int) -> Any:  # .submit(fn) / .shutdown()
-        raise NotImplementedError
-
-    def spawn(self, fn: Callable[[], Any], name: str = "",
-              layer: str | None = None) -> None:
-        """Run ``fn()`` (an effect generator) as a new actor. ``layer``
-        names the layer a HostProfile charges its host time to (event
-        substrate only; None: the profile's default)."""
-        raise NotImplementedError
-
-    def actor(self) -> Any:  # context manager registering current thread
-        raise NotImplementedError
-
-    def run(self, gen: Any) -> Any:
-        """Drive an effect generator to completion on this substrate
-        and return its value. Non-generators pass through unchanged."""
-        return run_effects(self, gen)
-
-
-def _blocking_actor_label(clock: BaseClock) -> str:
-    """Trace label for the thread substrates: ``actor#<seq>`` when the
-    clock tracks the current thread as a registered actor (VirtualClock),
-    else the thread name. Deterministic on the virtual substrate —
-    actors are numbered in registration order."""
-    current = getattr(clock, "_current", None)
-    if current is not None:
-        actor = current()
-        if actor is not None and hasattr(actor, "seq"):
-            return f"actor#{actor.seq}"
-    return threading.current_thread().name
-
-
-def run_effects(clock: BaseClock, gen: Any) -> Any:
-    """Interpret an effect generator on the blocking (thread-based)
-    primitives: the shared cross-check path for ``VirtualClock`` and
-    ``RealtimeClock``, and for external threads driving one-off
-    operations against any clock. Returns the generator's value."""
+def run_effects(clock: "EventClock", gen: Any) -> Any:
+    """Interpret an effect generator on the calling thread, which is not
+    one of ``clock``'s frames (tests, ``_ResultWaiter.wait``,
+    ``FanoutProxy.close``, the blocking KV wrappers): each effect maps
+    onto the primitive's blocking call. Returns the generator's value.
+    Non-generators pass through unchanged."""
     if not isinstance(gen, GeneratorType):
         return gen
     if _current_frame() is not None:
@@ -599,9 +419,9 @@ def run_effects(clock: BaseClock, gen: Any) -> Any:
             value = None
         except StopIteration as stop:
             return stop.value
-        tracer = getattr(clock, "tracer", None)
+        tracer = clock.tracer
         if tracer is not None:
-            tracer.record(_blocking_actor_label(clock), eff, gen)
+            tracer.record(threading.current_thread().name, eff, gen)
         kind = eff[0]
         if kind == "charge":
             clock.charge(eff[1])
@@ -615,122 +435,23 @@ def run_effects(clock: BaseClock, gen: Any) -> Any:
         elif kind == "wait":
             value = eff[1].wait(eff[2])
         elif kind == "flush":
-            pass  # thread substrates advance time at charge time
+            pass  # a thread defers no charges
         elif kind == "sleep":
-            sleep = getattr(clock, "sleep_ms", None)
-            if sleep is not None:
-                sleep(eff[1])
+            clock.sleep_ms(eff[1])
         else:
             raise RuntimeError(f"unknown clock effect {eff!r}")
 
 
 # ---------------------------------------------------------------------------
-# Real-time clock (the seed behavior, kept for cross-checks)
+# The clock: continuation frames on one driver thread
 # ---------------------------------------------------------------------------
-
-
-class _RealtimePool:
-    """Thin ThreadPoolExecutor wrapper pinning the two methods engines
-    use, interpreting effect-generator bodies on the worker thread."""
-
-    def __init__(self, clock: BaseClock, max_workers: int):
-        from concurrent.futures import ThreadPoolExecutor
-
-        self._clock = clock
-        self._tpe = ThreadPoolExecutor(max_workers=max_workers)
-
-    def _run(self, fn: Callable[[], Any]) -> None:
-        run_effects(self._clock, fn())
-
-    def submit(self, fn: Callable[[], Any]) -> None:
-        self._tpe.submit(self._run, fn)
-
-    def shutdown(self, wait: bool = False,
-                 cancel_futures: bool = True) -> None:
-        self._tpe.shutdown(wait=wait, cancel_futures=cancel_futures)
-
-
-class _NullActor:
-    def __enter__(self) -> None:
-        return None
-
-    def __exit__(self, *exc: Any) -> None:
-        return None
-
-
-class RealtimeClock(BaseClock):
-    """Charges simulated latency by really sleeping ``ms * time_scale``."""
-
-    virtual = False
-
-    def __init__(self, time_scale: float):
-        super().__init__()
-        self.time_scale = time_scale
-        self._t0 = time.perf_counter()
-
-    def charge(self, ms: float) -> None:
-        if ms <= 0:
-            return
-        self._account(ms)
-        if self.time_scale > 0:
-            time.sleep(ms * self.time_scale / 1e3)
-
-    def sleep_ms(self, ms: float) -> None:
-        if self.time_scale > 0 and ms > 0:
-            time.sleep(ms * self.time_scale / 1e3)
-
-    def now_ms(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e3
-
-    def queue(self) -> "_queue.Queue[Any]":
-        return _queue.Queue()
-
-    def lock(self) -> threading.Lock:
-        return threading.Lock()
-
-    def event(self) -> threading.Event:
-        return threading.Event()
-
-    def pool(self, max_workers: int) -> _RealtimePool:
-        return _RealtimePool(self, max_workers)
-
-    def spawn(self, fn: Callable[[], Any], name: str = "",
-              layer: str | None = None) -> None:
-        def body() -> None:
-            run_effects(self, fn())
-
-        _dispatch_to_worker(body)
-
-    def actor(self) -> _NullActor:
-        return _NullActor()
-
-
-# ---------------------------------------------------------------------------
-# Thread substrate: cooperative discrete-event scheduling over threads
-# ---------------------------------------------------------------------------
-
-_RUNNING = "running"
-_READY = "ready"
-_BLOCKED = "blocked"
 
 _WAKE_SIGNAL = "signal"
 _WAKE_TIMEOUT = "timeout"
 
 
-class _Actor:
-    __slots__ = ("seq", "cond", "state", "wake_reason", "timer")
-
-    def __init__(self, seq: int, mutex: threading.Lock):
-        self.seq = seq
-        self.cond = threading.Condition(mutex)
-        self.state = _READY
-        self.wake_reason: str | None = None
-        self.timer: "_Timer | None" = None  # pending virtual timeout
-
-
 class _Timer:
-    """Heap entry waking ``owner`` (a thread actor or an event frame —
-    both carry ``seq``) at a virtual deadline."""
+    """Heap entry waking frame ``owner`` at a simulated deadline."""
 
     __slots__ = ("deadline", "owner", "cancelled")
 
@@ -745,9 +466,9 @@ class _Timer:
 
 
 class _ExternalWaiter:
-    """A non-actor thread blocked on a clock primitive (tests, legacy
-    callers). It waits on a real condition with a real timeout and does
-    not hold back virtual-time advancement."""
+    """A thread outside the frames blocked on a clock primitive (tests,
+    ``run_effects`` callers). It waits on a real condition with a real
+    timeout and does not hold back simulated time."""
 
     __slots__ = ("cond", "signalled")
 
@@ -756,379 +477,14 @@ class _ExternalWaiter:
         self.signalled = False
 
 
-class VirtualClock(BaseClock):
-    """Deterministic discrete-event clock over cooperative actor threads.
-
-    Exactly one registered actor holds the run token at any moment; all
-    others are parked on per-actor condition variables sharing one mutex.
-    Blocking operations release the token; wake-ups re-enter a FIFO ready
-    queue. Virtual time jumps to the earliest pending timer only when no
-    actor is ready — i.e. when every actor is provably waiting on
-    simulated time or on an event another actor will produce.
-    """
-
-    virtual = True
-
-    def __init__(self) -> None:
-        super().__init__()
-        self._mutex = threading.Lock()
-        self._now = 0.0
-        self._seq = itertools.count()
-        self._actors: dict[int, _Actor] = {}  # thread ident -> actor
-        self._ready: list[_Actor] = []
-        self._running: _Actor | None = None
-        self._timers: list[_Timer] = []
-        self.switches = 0        # token handoffs (scheduler cost metric)
-        self.actors_spawned = 0  # total actor registrations
-
-    # -- introspection ------------------------------------------------------
-    def now_ms(self) -> float:
-        return self._now
-
-    def _current(self) -> _Actor | None:
-        return self._actors.get(threading.get_ident())
-
-    # -- scheduling core (all called with self._mutex held) -----------------
-    def _schedule_next(self) -> None:
-        """Hand the run token to the next ready actor, advancing virtual
-        time to the earliest timer when nobody is ready."""
-        while True:
-            if self._ready:
-                nxt = self._ready.pop(0)
-                nxt.state = _RUNNING
-                self._running = nxt
-                self.switches += 1
-                nxt.cond.notify()
-                return
-            while self._timers and self._timers[0].cancelled:
-                heapq.heappop(self._timers)
-            if not self._timers:
-                # Fully event-blocked (or no actors at all): idle until an
-                # external stimulus re-kicks the scheduler.
-                self._running = None
-                return
-            timer = heapq.heappop(self._timers)
-            self._now = max(self._now, timer.deadline)
-            actor = timer.owner
-            actor.timer = None
-            actor.wake_reason = _WAKE_TIMEOUT
-            actor.state = _READY
-            self._ready.append(actor)
-
-    def _kick(self) -> None:
-        """Start the scheduler if the simulation is idle (called after an
-        external thread made an actor ready or added a timer)."""
-        if self._running is None:
-            self._schedule_next()
-
-    def _make_ready(self, actor: _Actor) -> None:
-        """Move a blocked actor to the ready queue (waker side)."""
-        if actor.timer is not None:
-            actor.timer.cancelled = True
-            actor.timer = None
-        actor.wake_reason = _WAKE_SIGNAL
-        actor.state = _READY
-        self._ready.append(actor)
-
-    def _block(self, actor: _Actor, timeout_ms: float | None) -> str:
-        """Release the run token and wait to be woken. Returns the wake
-        reason (``signal`` or ``timeout``)."""
-        actor.state = _BLOCKED
-        actor.wake_reason = None
-        if timeout_ms is not None:
-            actor.timer = _Timer(self._now + max(0.0, timeout_ms), actor)
-            heapq.heappush(self._timers, actor.timer)
-        self._schedule_next()
-        while actor.state is not _RUNNING:
-            actor.cond.wait()
-        return actor.wake_reason or _WAKE_SIGNAL
-
-    def _wait_for_token(self, actor: _Actor) -> None:
-        while actor.state is not _RUNNING:
-            actor.cond.wait()
-
-    # -- actor lifecycle ----------------------------------------------------
-    def _register_current(self) -> _Actor:
-        with self._mutex:
-            actor = _Actor(next(self._seq), self._mutex)
-            actor.state = _READY
-            self._actors[threading.get_ident()] = actor
-            self._ready.append(actor)
-            self._kick()
-            self._wait_for_token(actor)
-            return actor
-
-    def _deregister_current(self) -> None:
-        with self._mutex:
-            actor = self._actors.pop(threading.get_ident(), None)
-            if actor is None:
-                return
-            if self._running is actor:
-                self._schedule_next()
-
-    class _ActorContext:
-        def __init__(self, clock: "VirtualClock"):
-            self.clock = clock
-
-        def __enter__(self) -> None:
-            self.clock._register_current()
-
-        def __exit__(self, *exc: Any) -> None:
-            self.clock._deregister_current()
-
-    def actor(self) -> "_ActorContext":
-        return VirtualClock._ActorContext(self)
-
-    def run(self, gen: Any) -> Any:
-        """Drive an effect generator as a registered actor (registering
-        the calling thread for the duration if it isn't one already)."""
-        if not isinstance(gen, GeneratorType):
-            return gen
-        if self._current() is not None:
-            return run_effects(self, gen)
-        with self.actor():
-            return run_effects(self, gen)
-
-    def spawn(self, fn: Callable[[], Any], name: str = "",
-              layer: str | None = None) -> None:
-        # The actor slot enters the ready queue HERE, on the spawning
-        # thread, so scheduling order is a pure function of the event
-        # sequence — not of how quickly the OS starts (or recycles) the
-        # worker thread that will service it.
-        with self._mutex:
-            actor = _Actor(next(self._seq), self._mutex)
-            actor.state = _READY
-            self._ready.append(actor)
-            self.actors_spawned += 1
-            self._kick()
-
-        def body() -> None:
-            with self._mutex:
-                self._actors[threading.get_ident()] = actor
-                self._wait_for_token(actor)
-            try:
-                r = fn()
-                if isinstance(r, GeneratorType):
-                    run_effects(self, r)
-            finally:
-                self._deregister_current()
-
-        _dispatch_to_worker(body)
-
-    # -- time ---------------------------------------------------------------
-    def sleep_ms(self, ms: float) -> None:
-        with self._mutex:
-            actor = self._current()
-            if actor is None or self._running is not actor:
-                return  # non-actor thread: virtual time is not its to spend
-            self._block(actor, ms)
-
-    def charge(self, ms: float) -> None:
-        if ms <= 0:
-            return
-        self._account(ms)
-        self.sleep_ms(ms)
-
-    # -- primitives ---------------------------------------------------------
-    def queue(self) -> "VirtualQueue":
-        return VirtualQueue(self)
-
-    def lock(self) -> "VirtualLock":
-        return VirtualLock(self)
-
-    def event(self) -> "VirtualEvent":
-        return VirtualEvent(self)
-
-    def pool(self, max_workers: int) -> "VirtualPool":
-        return VirtualPool(self, max_workers)
-
-
-class VirtualQueue:
-    """``queue.Queue``-compatible FIFO whose blocking ``get`` cooperates
-    with the virtual clock. ``timeout`` is *simulated seconds* for actor
-    threads and real seconds for non-actor threads."""
-
-    def __init__(self, clock: VirtualClock):
-        self._clock = clock
-        self._items: list[Any] = []
-        self._waiters: list[_Actor | _ExternalWaiter] = []
-
-    def put(self, item: Any) -> None:
-        clock = self._clock
-        with clock._mutex:
-            self._items.append(item)
-            if self._waiters:
-                waiter = self._waiters.pop(0)
-                if isinstance(waiter, _ExternalWaiter):
-                    waiter.signalled = True
-                    waiter.cond.notify()
-                else:
-                    clock._make_ready(waiter)
-                    clock._kick()
-
-    def get(self, timeout: float | None = None) -> Any:
-        clock = self._clock
-        with clock._mutex:
-            actor = clock._current()
-            if actor is not None and clock._running is actor:
-                deadline = (None if timeout is None
-                            else clock._now + timeout * 1e3)
-                while not self._items:
-                    remaining = (None if deadline is None
-                                 else deadline - clock._now)
-                    if remaining is not None and remaining <= 0:
-                        raise _queue.Empty
-                    self._waiters.append(actor)
-                    reason = clock._block(actor, remaining)
-                    if reason == _WAKE_TIMEOUT:
-                        if actor in self._waiters:
-                            self._waiters.remove(actor)
-                        raise _queue.Empty
-                return self._items.pop(0)
-            # Non-actor thread: real wait, real timeout.
-            deadline = (None if timeout is None
-                        else time.monotonic() + timeout)
-            while not self._items:
-                waiter = _ExternalWaiter(clock._mutex)
-                self._waiters.append(waiter)
-                remaining = (None if deadline is None
-                             else deadline - time.monotonic())
-                if remaining is not None and remaining <= 0:
-                    self._waiters.remove(waiter)
-                    raise _queue.Empty
-                if not waiter.cond.wait(remaining):
-                    if waiter in self._waiters:
-                        self._waiters.remove(waiter)
-                    if not waiter.signalled:
-                        raise _queue.Empty
-            return self._items.pop(0)
-
-    def empty(self) -> bool:
-        with self._clock._mutex:
-            return not self._items
-
-    def drain(self) -> "list[Any]":
-        """Atomically remove and return every queued item (pool shutdown
-        with ``cancel_futures``: queued-but-unstarted work is dropped)."""
-        with self._clock._mutex:
-            items, self._items = self._items, []
-            return items
-
-
-class VirtualLock:
-    """Transfer-lane lock held across simulated transfers. FIFO handoff:
-    ``release`` passes ownership directly to the longest-waiting thread,
-    which keeps lane-contention outcomes deterministic."""
-
-    def __init__(self, clock: VirtualClock):
-        self._clock = clock
-        self._owner: Any = None  # _Actor, _ExternalWaiter, or thread ident
-        self._waiters: list[_Actor | _ExternalWaiter] = []
-
-    def acquire(self) -> None:
-        clock = self._clock
-        with clock._mutex:
-            actor = clock._current()
-            if actor is not None and clock._running is actor:
-                if self._owner is None:
-                    self._owner = actor
-                    return
-                self._waiters.append(actor)
-                clock._block(actor, None)  # woken owning the lock
-                return
-            ident = threading.get_ident()
-            if self._owner is None:
-                self._owner = ident
-                return
-            waiter = _ExternalWaiter(clock._mutex)
-            self._waiters.append(waiter)
-            while not waiter.signalled:
-                waiter.cond.wait()
-            self._owner = ident
-
-    def release(self) -> None:
-        clock = self._clock
-        with clock._mutex:
-            if not self._waiters:
-                self._owner = None
-                return
-            waiter = self._waiters.pop(0)
-            if isinstance(waiter, _ExternalWaiter):
-                self._owner = waiter  # placeholder until the thread wakes
-                waiter.signalled = True
-                waiter.cond.notify()
-            else:
-                self._owner = waiter
-                clock._make_ready(waiter)
-                clock._kick()
-
-    def __enter__(self) -> "VirtualLock":
-        self.acquire()
-        return self
-
-    def __exit__(self, *exc: Any) -> None:
-        self.release()
-
-
-class VirtualEvent:
-    """``threading.Event``-compatible; ``wait`` timeout is simulated
-    seconds for actors, real seconds for non-actor threads."""
-
-    def __init__(self, clock: VirtualClock):
-        self._clock = clock
-        self._flag = False
-        self._waiters: list[_Actor | _ExternalWaiter] = []
-
-    def is_set(self) -> bool:
-        return self._flag
-
-    def set(self) -> None:
-        clock = self._clock
-        with clock._mutex:
-            self._flag = True
-            waiters, self._waiters = self._waiters, []
-            kicked = False
-            for waiter in waiters:
-                if isinstance(waiter, _ExternalWaiter):
-                    waiter.signalled = True
-                    waiter.cond.notify()
-                else:
-                    clock._make_ready(waiter)
-                    kicked = True
-            if kicked:
-                clock._kick()
-
-    def wait(self, timeout: float | None = None) -> bool:
-        clock = self._clock
-        with clock._mutex:
-            if self._flag:
-                return True
-            actor = clock._current()
-            if actor is not None and clock._running is actor:
-                self._waiters.append(actor)
-                reason = clock._block(
-                    actor, None if timeout is None else timeout * 1e3)
-                if reason == _WAKE_TIMEOUT and actor in self._waiters:
-                    self._waiters.remove(actor)
-                return self._flag
-            waiter = _ExternalWaiter(clock._mutex)
-            self._waiters.append(waiter)
-            waiter.cond.wait(timeout)
-            if waiter in self._waiters:
-                self._waiters.remove(waiter)
-            return self._flag
-
-
-class VirtualPool:
+class EventPool:
     """Executor-runtime stand-in for ``ThreadPoolExecutor``: worker
-    actors are created lazily up to ``max_workers``, so an 8k-task sweep
+    frames are created lazily up to ``max_workers``, so an 8k-task sweep
     only materializes as many workers as are ever simultaneously busy.
-    Queued bodies do NOT hold back virtual time — a full pool models the
-    provider's concurrency limit. Shared by both virtual substrates:
-    the worker is an effect generator, so on the thread substrate it
-    runs as a cooperative actor and on the event substrate as a frame."""
+    Queued bodies do NOT hold back simulated time — a full pool models
+    the provider's concurrency limit."""
 
-    def __init__(self, clock: BaseClock, max_workers: int):
+    def __init__(self, clock: "EventClock", max_workers: int):
         self._clock = clock
         self._max_workers = max(1, max_workers)
         self._q = clock.queue()
@@ -1171,20 +527,14 @@ class VirtualPool:
             self._closed = True
             n = self._workers
         if cancel_futures:
-            # Drop queued-but-unstarted bodies (matching the
-            # ThreadPoolExecutor contract the realtime pool inherits).
-            # Before this, a torn-down job's queued executors still ran
+            # Drop queued-but-unstarted bodies (the ThreadPoolExecutor
+            # contract). Before this, a torn-down job's queued executors still ran
             # to completion behind the shutdown sentinels — harmless when
-            # the substrate died with the job, a capacity leak once
+            # the clock died with the job, a capacity leak once
             # platform and store outlive it.
             self._q.drain()
         for _ in range(n):
             self._q.put(None)
-
-
-# ---------------------------------------------------------------------------
-# Event substrate: continuation frames on one driver thread
-# ---------------------------------------------------------------------------
 
 
 class _Frame:
@@ -1215,29 +565,33 @@ class _Frame:
         self.exc: BaseException | None = None
 
 
-class EventClock(BaseClock):
-    """Continuation/event-driven discrete-event clock: the default
-    substrate. Actors are *frames* — effect generators — dispatched
-    FIFO from one ready deque by whichever thread called ``run()``; no
-    OS thread per actor. Scheduling replays the VirtualClock event
-    order exactly (FIFO ready, timers in (deadline, seq) order, FIFO
-    waiters, one waiter woken per ``put``), so both virtual substrates
-    produce bit-identical charges for the same job.
+class EventClock:
+    """The discrete-event simulation clock. Actors are *frames* — effect
+    generators — dispatched FIFO from one ready deque by whichever thread
+    called ``run()``; no OS thread per actor. Events are ordered FIFO
+    for ready frames, by (deadline, spawn seq) for timers and FIFO for
+    waiters, with one waiter woken per ``put``.
 
     Charges issued by non-yielding code inside a frame (a task function
     calling ``simulated_compute``) are *deferred*: billed immediately,
-    applied to virtual time at the next suspension or explicit
+    applied to simulated time at the next suspension or explicit
     ``("flush",)`` effect.
 
-    External (non-frame) threads interoperate the same way they do with
-    the VirtualClock: registered via ``actor()``, their charges drive
-    the frame scheduler forward; unregistered, they bill without
-    advancing time and block on real condition variables."""
-
-    virtual = True
+    External (non-frame) threads registered via ``actor()`` drive the
+    frame scheduler forward with their charges; unregistered, they bill
+    without advancing time and block on real condition variables."""
 
     def __init__(self) -> None:
-        super().__init__()
+        self._charge_lock = threading.Lock()
+        self.charged_ms = 0.0
+        # Opt-in determinism sanitizer (repro.analysis.divergence.Tracer,
+        # duck-typed so the clock never imports the analysis package):
+        # when set, every freshly generated effect is journaled via
+        # tracer.record(actor, effect, gen). None is free.
+        self.tracer: Any = None
+        # Opt-in host-time profile of the running job (HostProfile).
+        # None is free.
+        self.host_profile: "HostProfile | None" = None
         # RLock: frame code runs under the driver's mutex and re-enters
         # it through every primitive call (put/set/release/spawn).
         self._mutex = threading.RLock()
@@ -1255,8 +609,18 @@ class EventClock(BaseClock):
     def now_ms(self) -> float:
         return self._now
 
-    def _current(self) -> "_Frame | None":
-        return _current_frame()
+    def _account(self, ms: float) -> None:
+        """Bill ``ms``: to the clock, and to the innermost
+        ``charge_meter`` of the running frame or thread."""
+        with self._charge_lock:
+            self.charged_ms += ms
+        frame = _current_frame()
+        if frame is not None:
+            acc = frame.charge_acc
+        else:
+            acc = getattr(_charge_tap, "acc", None)
+        if acc is not None:
+            acc[0] += ms
 
     # -- driver -------------------------------------------------------------
     def run(self, gen: Any) -> Any:
@@ -1275,6 +639,8 @@ class EventClock(BaseClock):
                 raise RuntimeError("EventClock is already being driven")
             root = _Frame(next(self._seq), None, "root", root=True)
             root.gen = gen
+            # A thread inside a charge_meter bills what it drives there.
+            root.charge_acc = getattr(_charge_tap, "acc", None)
             self._ready.append(root)
             self._driving = True
             try:
@@ -1331,7 +697,7 @@ class EventClock(BaseClock):
                 self._step(frame, q._items.pop(0), None, None)
                 return
             # Signalled but the item was taken: wait out the remainder
-            # (mirrors the VirtualQueue re-check loop).
+            # (EventQueue.get re-checks the same way).
             remaining = None if deadline is None else deadline - self._now
             if remaining is not None and remaining <= 0:
                 self._step(frame, None, _queue.Empty(), None)
@@ -1375,9 +741,9 @@ class EventClock(BaseClock):
 
     def _defer_flush(self, frame: _Frame, eff: "tuple[Any, ...]") -> None:
         """A suspending effect arrived with compute charges still
-        deferred: advance time past them first, then replay the effect
-        (keeps the time trajectory identical to the thread substrate,
-        where those charges advanced time when issued)."""
+        deferred: advance time past them first, then replay the effect,
+        so the time trajectory is the one those charges would give had
+        they advanced time when issued."""
         self._park(frame, ("replay", eff), frame.deferred_ms)
         frame.deferred_ms = 0.0
 
@@ -1495,8 +861,8 @@ class EventClock(BaseClock):
     def _retire(self, frame: _Frame, result: Any) -> None:
         frame.result = result
         if frame.deferred_ms > 0.0:
-            # Auto-flush trailing compute charges so the frame's time
-            # footprint matches the thread substrate's.
+            # Auto-flush trailing compute charges: the frame's time
+            # footprint includes them.
             self._park(frame, ("retire",), frame.deferred_ms)
             frame.deferred_ms = 0.0
             return
@@ -1514,9 +880,8 @@ class EventClock(BaseClock):
         if frame.root:
             frame.exc = exc
             return
-        # Mirror the thread substrate: an exception escaping a spawned
-        # actor body is reported (threading excepthook), not raised
-        # into the scheduler.
+        # An exception escaping a spawned actor body is reported (as the
+        # threading excepthook would), not raised into the scheduler.
         print(f"Exception in frame {frame.name!r}:", file=sys.stderr)
         traceback.print_exception(type(exc), exc, exc.__traceback__)
 
@@ -1552,9 +917,8 @@ class EventClock(BaseClock):
 
     def actor(self) -> "_ExternalActorContext":
         """Register the calling (external) thread as an actor: its
-        charges drive the frame scheduler — advancing virtual time and
-        firing parked frames' timers — exactly like a thread-substrate
-        actor's charges let other actors run."""
+        charges drive the frame scheduler, advancing simulated time and
+        firing parked frames' timers."""
         return EventClock._ExternalActorContext(self)
 
     # -- time ---------------------------------------------------------------
@@ -1597,15 +961,14 @@ class EventClock(BaseClock):
     def event(self) -> "EventEvent":
         return EventEvent(self)
 
-    def pool(self, max_workers: int) -> VirtualPool:
-        return VirtualPool(self, max_workers)
+    def pool(self, max_workers: int) -> EventPool:
+        return EventPool(self, max_workers)
 
 
 class EventQueue:
-    """``queue.Queue``-compatible FIFO for the event substrate: frames
-    suspend via ``("get", q, timeout)`` effects (simulated-seconds
-    timeout); external threads block on real condvars with real
-    timeouts, exactly like the VirtualQueue non-actor path."""
+    """``queue.Queue``-compatible FIFO: frames suspend via
+    ``("get", q, timeout)`` effects (simulated-seconds timeout);
+    external threads block on real condvars with real timeouts."""
 
     def __init__(self, clock: EventClock):
         self._clock = clock
@@ -1659,7 +1022,7 @@ class EventQueue:
 
 
 class EventLock:
-    """Transfer-lane lock for the event substrate. Frames acquire via
+    """Transfer-lane lock. Frames acquire via
     ``("acquire", lock)`` effects; ``release`` is a direct call with
     FIFO ownership handoff (deterministic lane contention)."""
 
@@ -1708,8 +1071,7 @@ class EventLock:
 
 
 class EventEvent:
-    """``threading.Event``-compatible flag for the event substrate.
-    Frames wait via ``("wait", ev, timeout)`` effects; ``set`` wakes
+    """``threading.Event``-compatible flag. Frames wait via ``("wait", ev, timeout)`` effects; ``set`` wakes
     every waiter in FIFO order."""
 
     def __init__(self, clock: EventClock):
@@ -1747,24 +1109,3 @@ class EventEvent:
             if waiter in self._waiters:
                 self._waiters.remove(waiter)
             return self._flag
-
-
-# ---------------------------------------------------------------------------
-# Mode selection
-# ---------------------------------------------------------------------------
-
-
-def clock_for_scale(time_scale: float,
-                    substrate: str = "event") -> BaseClock:
-    """``time_scale > 0`` keeps the seed real-time mode for
-    cross-checks; otherwise ``substrate`` picks the virtual engine:
-    ``"event"`` (default) is the continuation scheduler, ``"thread"``
-    the PR-3 thread-per-actor cross-check mode."""
-    if time_scale > 0:
-        return RealtimeClock(time_scale)
-    if substrate == "thread":
-        return VirtualClock()
-    if substrate == "event":
-        return EventClock()
-    raise ValueError(f"unknown simulation substrate {substrate!r} "
-                     "(expected 'event' or 'thread')")

@@ -44,9 +44,9 @@ module adds that control-plane layer on top of the PR 5 orchestrator:
   sustained window-jobs/s, p50/p95/p99 event-to-result latency,
   backlog depth.
 
-Determinism: everything runs on the shared virtual clock; a fresh run
-of the same config is bit-identical (fig19 gates this across runs AND
-across the event/thread substrates).
+Determinism: everything runs on the shared simulation clock; a fresh
+run of the same config is bit-identical (fig19 gates this across
+runs).
 """
 from __future__ import annotations
 

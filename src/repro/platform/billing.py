@@ -2,11 +2,10 @@
 
 An invocation's billed duration is the simulated time its container
 spends executing the function body — measured as the sum of simulated-
-latency charges made by the invocation's thread (``charge_meter`` in
-repro.core.simclock), NOT as a wall-clock delta. Charge sums are
-identical in virtual and real-time clock modes (both modes charge the
-same simulated amounts), so a job's billed cost is *bit-identical
-across clock modes* — the cross-check tests rely on this. Like AWS,
+latency charges made by the invocation (``charge_meter`` in
+repro.core.simclock), NOT as a wall-clock delta, so a job's billed cost
+is as deterministic as its charges — the pinned-result tests rely on
+this. Like AWS,
 the cold-start provisioning delay and the invoke API latency are not
 billed as duration.
 
@@ -21,8 +20,7 @@ attribution the durable control plane journals at job completion and
 the crash-recovery tests audit against the uncrashed baseline.
 
 Snapshots sum per-invocation GB-seconds in sorted record order so the
-total is independent of the (thread-racy, in real-time mode) order in
-which invocations complete.
+total is independent of the order in which invocations complete.
 """
 from __future__ import annotations
 

@@ -33,7 +33,7 @@ from typing import Any, Callable
 
 from repro.core.cache import CacheRegistry
 from repro.core.kvstore import CostModel
-from repro.core.simclock import BaseClock, charge_meter
+from repro.core.simclock import EventClock, charge_meter
 from repro.platform.billing import BillingMeter
 from repro.platform.config import PlatformConfig
 from repro.platform.pool import ContainerPool
@@ -49,7 +49,7 @@ class ComputeScaledClock:
     runs slower on smaller containers; engine-side latencies (KV,
     invoke) are unaffected."""
 
-    def __init__(self, clock: BaseClock, scale: float):
+    def __init__(self, clock: EventClock, scale: float):
         self._clock = clock
         self._scale = scale
 
@@ -66,7 +66,7 @@ class FaaSPlatform:
     account-wide and the container pool is function-wide."""
 
     def __init__(self, config: PlatformConfig, cost: CostModel,
-                 clock: BaseClock):
+                 clock: EventClock):
         self.config = config
         self.cost = cost
         self.clock = clock
@@ -172,8 +172,7 @@ class FaaSPlatform:
                job: str | None = None) -> Callable[[], Any]:
         """Effect-protocol sibling of ``wrap``: the returned zero-arg
         callable is a generator function, so it composes with bodies
-        that are themselves effect generators (the event substrate's
-        executor bodies). Metering and release semantics are identical
+        that are themselves effect generators (the executor bodies). Metering and release semantics are identical
         to ``wrap``."""
 
         memory_mb = self.memory_mb(function)
@@ -205,7 +204,7 @@ class FaaSPlatform:
         self.throttle.release()
 
     # -- compute scaling ----------------------------------------------------
-    def compute_clock(self, clock: BaseClock,
+    def compute_clock(self, clock: EventClock,
                       function: str = DEFAULT_FUNCTION) -> Any:
         """Task clock for ``function``: CPU share proportional to ITS
         memory size (per-tenant under the orchestrator)."""

@@ -16,14 +16,14 @@ from __future__ import annotations
 import threading
 from typing import Callable
 
-from repro.core.simclock import BaseClock
+from repro.core.simclock import EventClock
 from repro.platform.config import PlatformConfig
 
 
 class ContainerPool:
     """Per-function idle-container stacks keyed on the engine clock."""
 
-    def __init__(self, config: PlatformConfig, clock: BaseClock):
+    def __init__(self, config: PlatformConfig, clock: EventClock):
         self.config = config
         self.clock = clock
         self._lock = threading.Lock()

@@ -19,14 +19,14 @@ from __future__ import annotations
 import threading
 
 from repro.core.faults import exponential_backoff_ms
-from repro.core.simclock import BaseClock
+from repro.core.simclock import EventClock
 from repro.platform.config import PlatformConfig
 
 
 class ConcurrencyThrottle:
     """Tracks in-flight invocations against the time-ramped limit."""
 
-    def __init__(self, config: PlatformConfig, clock: BaseClock):
+    def __init__(self, config: PlatformConfig, clock: EventClock):
         self.config = config
         self.clock = clock
         self._lock = threading.Lock()

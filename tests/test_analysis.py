@@ -32,7 +32,7 @@ from repro.analysis.effects import lint_source, lint_tree
 from repro.core.dag import DAG, DynamicDAG, Expansion, Task, TaskRef
 from repro.core.optimize import compile_dag
 from repro.core.schedule import generate_static_schedules
-from repro.core.simclock import EventClock, VirtualClock
+from repro.core.simclock import EventClock
 
 FIXTURES = Path(__file__).parent / "fixtures" / "analysis"
 REPO = Path(__file__).parent.parent
@@ -313,11 +313,6 @@ def _traced_run(clock_cls, seed: int) -> Tracer:
 def test_identical_runs_produce_identical_traces():
     assert diff_traces(_traced_run(EventClock, 7),
                        _traced_run(EventClock, 7)) is None
-
-
-def test_cross_substrate_traces_match():
-    assert diff_traces(_traced_run(EventClock, 7),
-                       _traced_run(VirtualClock, 7)) is None
 
 
 def test_diff_pinpoints_first_divergent_event_and_actor():

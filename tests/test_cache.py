@@ -12,8 +12,9 @@ The tentpole properties:
 - *warm retention*: a warm container keeps its cache across reuses
   (tier-0 hits > 0 on shared-input DAGs); cold start and keep-alive
   expiry clear it.
-- *substrate parity*: cached runs stay bit-identical between the event
-  and thread substrates, like every other charge in the system.
+
+The pinned cached runs (tests/test_determinism.py) hold their charges
+bit-identical across changes, like every other charge in the system.
 """
 import pytest
 
@@ -218,11 +219,11 @@ class TestCacheRegistry:
 # ---------------------------------------------------------------------------
 
 
-def _cfg(cache, substrate="event", **kw):
+def _cfg(cache, **kw):
     kw.setdefault("num_initial_invokers", 4)
     kw.setdefault("num_proxy_invokers", 4)
     return EngineConfig(
-        cost=CostModel(cold_start_ms=250.0, substrate=substrate),
+        cost=CostModel(cold_start_ms=250.0),
         platform=PlatformConfig(keep_alive_s=600.0, cache=cache),
         **kw)
 
@@ -292,15 +293,13 @@ class TestEvictionCorrectness:
         assert rep.cache_stats["mem_hits"] == 0
         assert rep.cache_stats["disk_hits"] == 0
 
-    @pytest.mark.parametrize("substrate", ["event", "thread"])
-    def test_retries_with_tiny_cache_stay_correct_and_identical(
-            self, substrate):
+    def test_retries_with_tiny_cache_stay_correct_and_identical(self):
         # Injected failures + Lambda retries against a spilling cache:
         # the retry re-walks from its start key; host-side mutation is
         # atomic under the cache lock, so it never observes a
         # half-spilled entry — results and charges stay deterministic.
         cfg = EngineConfig(
-            cost=CostModel(cold_start_ms=250.0, substrate=substrate),
+            cost=CostModel(cold_start_ms=250.0),
             platform=PlatformConfig(
                 keep_alive_s=600.0,
                 cache=CacheConfig(memory_bytes=1 << 12,
@@ -360,20 +359,3 @@ class TestWarmRetention:
         rep = WukongEngine(_cfg(None)).compute(dag)
         assert "cache" not in rep.platform_stats
         assert rep.cache_stats == {}
-
-
-class TestSubstrateParity:
-    def test_cached_run_bit_identical_event_vs_thread(self):
-        def run(substrate):
-            dag = tree_reduction_dag(64, payload_bytes=1 << 16,
-                                     compute_ms=5.0)
-            return WukongEngine(_cfg(
-                CacheConfig(memory_bytes=1 << 14, disk_bytes=512 << 20),
-                substrate=substrate)).compute(dag)
-
-        a, b = run("event"), run("thread")
-        assert a.charged_ms == b.charged_ms
-        assert a.wall_s == b.wall_s
-        assert a.kv_stats == b.kv_stats
-        assert a.cache_stats == b.cache_stats
-        assert a.platform_stats["cache"] == b.platform_stats["cache"]

@@ -3,7 +3,7 @@
 The tentpole property: a DAG that grows at runtime (a task returns an
 ``Expansion`` instead of a value) charges EXACTLY what the statically
 pre-expanded equivalent graph charges — same results, same charged_ms,
-same KV traffic, on both simulation substrates. Plus the expansion
+same KV traffic. Plus the expansion
 validation surface, iterate-until-converged chaining with the depth
 cap, and the idempotent-replay path that makes duplicate execution of
 an expanding task (crash resume) safe.
@@ -34,16 +34,12 @@ from repro.core import (
     expansion_base_key,
 )
 
-SUBSTRATES = ("event", "thread")
-
-
-def _engine(substrate: str) -> WukongEngine:
+def _engine() -> WukongEngine:
     # schedule_ship_mbps=inf: expansion schedules are built after
     # dispatch, so static-schedule shipping is the one cost the dynamic
     # arm structurally cannot share with the pre-expanded equivalent.
     return WukongEngine(EngineConfig(
-        cost=CostModel(substrate=substrate,
-                       schedule_ship_mbps=float("inf")),
+        cost=CostModel(schedule_ship_mbps=float("inf")),
         num_initial_invokers=4, num_proxy_invokers=4,
         max_concurrency=512))
 
@@ -225,9 +221,8 @@ def _countdown_dag(rounds: int, depth_cap: int = 16) -> DynamicDAG:
 
 
 class TestEngineIntegration:
-    @pytest.mark.parametrize("substrate", SUBSTRATES)
-    def test_iterate_until_converged(self, substrate):
-        rep = _engine(substrate).compute(_countdown_dag(4))
+    def test_iterate_until_converged(self):
+        rep = _engine().compute(_countdown_dag(4))
         (_, v), = rep.results.items()
         assert v[0] == 0.0
         # 1 seed + the initial iter + 3 re-expanded finals
@@ -236,29 +231,21 @@ class TestEngineIntegration:
     def test_depth_cap_surfaces_as_job_error(self):
         from repro.core import JobError
         with pytest.raises(JobError, match="depth"):
-            _engine("event").compute(_countdown_dag(6, depth_cap=2))
+            _engine().compute(_countdown_dag(6, depth_cap=2))
 
     @settings(max_examples=5, deadline=None)
     @given(n=st.sampled_from([4, 8, 16, 32]),
            compute_ms=st.sampled_from([0.0, 3.0]))
     def test_dynamic_matches_static_equivalent(self, n, compute_ms):
         """The PR's core parity property: data-dependent fan-out priced
-        bit-identically to the pre-expanded graph, both substrates."""
-        per_substrate = []
-        for substrate in SUBSTRATES:
-            dyn = _engine(substrate).compute(
-                dynamic_tree_reduction_dag(n, compute_ms=compute_ms))
-            sta = _engine(substrate).compute(
-                static_tree_reduction_equivalent(
-                    n, compute_ms=compute_ms))
-            assert np.array_equal(np.asarray(dyn.results["reduce"]),
-                                  np.asarray(sta.results["reduce"]))
-            assert dyn.results["reduce"][0] \
-                == dynamic_tree_reduction_expected(n)
-            assert dyn.charged_ms == sta.charged_ms
-            assert dyn.tasks == sta.tasks
-            assert dyn.kv_stats == sta.kv_stats
-            per_substrate.append((dyn.charged_ms, dyn.tasks,
-                                  float(dyn.results["reduce"][0])))
-        # and the whole parity tuple is substrate-invariant
-        assert per_substrate[0] == per_substrate[1]
+        bit-identically to the pre-expanded graph."""
+        dyn = _engine().compute(
+            dynamic_tree_reduction_dag(n, compute_ms=compute_ms))
+        sta = _engine().compute(
+            static_tree_reduction_equivalent(n, compute_ms=compute_ms))
+        assert np.array_equal(np.asarray(dyn.results["reduce"]),
+                              np.asarray(sta.results["reduce"]))
+        assert dyn.results["reduce"][0] == dynamic_tree_reduction_expected(n)
+        assert dyn.charged_ms == sta.charged_ms
+        assert dyn.tasks == sta.tasks
+        assert dyn.kv_stats == sta.kv_stats

@@ -14,7 +14,6 @@ except ImportError:  # property tests skip without the dev extra
     from _hypothesis_fallback import given, settings, st
 
 from repro.core import (
-    CostModel,
     EngineConfig,
     FaultConfig,
     GraphBuilder,
@@ -175,7 +174,6 @@ class TestFaultTolerance:
     def test_speculative_straggler_duplicates_are_safe(self):
         dag = tree_dag(16)
         cfg = EngineConfig(
-            cost=CostModel(time_scale=0.01),
             faults=FaultConfig(straggler_prob=0.2,
                                straggler_slowdown_ms=2000,
                                speculative_threshold_ms=200, seed=5),

@@ -18,7 +18,7 @@ from jax.profiler import ProfileData
 import repro.core.engine as engine_mod
 import repro.core.simclock as simclock
 from repro.apps import gemm_dag
-from repro.core import CostModel, EngineConfig, OptimizeConfig, WukongEngine
+from repro.core import EngineConfig, OptimizeConfig, WukongEngine
 from repro.core.engine import HOST_LAYERS, profiler_capturing
 from repro.core.kvstore import HostTimedKVStore, ShardedKVStore
 from repro.core.simclock import HOST_LOG
@@ -57,13 +57,13 @@ def runs(tmp_path_factory):
     engine = WukongEngine(EngineConfig(optimize=OptimizeConfig()))
     engine.compute(_job())  # block programs compiled outside the session
     clocks = []
-    real = engine_mod.clock_for_scale
+    real = engine_mod.EventClock
 
-    def recording_clock(*args):
-        clocks.append(real(*args))
+    def recording_clock():
+        clocks.append(real())
         return clocks[-1]
 
-    engine_mod.clock_for_scale = recording_clock
+    engine_mod.EventClock = recording_clock
     try:
         off = engine.compute(_job())
         trace_dir = tmp_path_factory.mktemp("trace")
@@ -76,7 +76,7 @@ def runs(tmp_path_factory):
         finally:
             jax.profiler.stop_trace()
     finally:
-        engine_mod.clock_for_scale = real
+        engine_mod.EventClock = real
     return {"off": off, "on": on, "clock": clocks[-1],
             "spans": _host_spans(trace_dir)}
 
@@ -165,29 +165,14 @@ class _Refused:
         raise AssertionError("TraceAnnotation built with no session")
 
 
-@pytest.mark.parametrize("substrate", ["event", "thread"])
-def test_without_a_session_nothing_is_recorded_or_built(substrate, monkeypatch):
+def test_without_a_session_nothing_is_recorded_or_built(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Refused)
     jobs = list(HOST_LOG.jobs)
-    engine = WukongEngine(EngineConfig(cost=CostModel(substrate=substrate),
-                                       optimize=OptimizeConfig()))
+    engine = WukongEngine(EngineConfig(optimize=OptimizeConfig()))
     rep = engine.compute(gemm_dag(256, 128, seed_a=1, seed_b=2))
     assert not profiler_capturing()
     assert rep.host_profile is None
     assert list(HOST_LOG.jobs) == jobs
-
-
-def test_thread_substrate_is_not_profiled_during_a_session(tmp_path):
-    engine = WukongEngine(EngineConfig(cost=CostModel(substrate="thread"),
-                                       optimize=OptimizeConfig()))
-    jobs = len(HOST_LOG.jobs)
-    _session(tmp_path)
-    try:
-        rep = engine.compute(gemm_dag(256, 128, seed_a=1, seed_b=2))
-    finally:
-        jax.profiler.stop_trace()
-    assert rep.host_profile is None
-    assert len(HOST_LOG.jobs) == jobs
 
 
 def _ticking_profile(monkeypatch, clock):

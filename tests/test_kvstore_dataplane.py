@@ -1,8 +1,8 @@
 """Data-plane tests: striped large objects, batched round trips, and the
 O(V+E) schedule generation (PR 2).
 
-The KV-level tests drive the protocol directly (time_scale=0: we assert
-*charged* simulated ms, not wall time); the engine-level tests assert the
+The KV-level tests drive the protocol directly (we assert *charged*
+simulated ms, not wall time); the engine-level tests assert the
 end-to-end properties the ISSUE acceptance criteria name — bit-identical
 results with striping on/off and a >=15% charged-ms reduction on the
 fig08-style GEMM smoke workload.

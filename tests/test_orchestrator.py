@@ -237,18 +237,6 @@ class TestSubstrateReuse:
         assert substrate.kv.subscriber_count() == 0
         assert substrate.kv._channels == {}
 
-    def test_worker_cache_stays_bounded(self):
-        import repro.core.simclock as sc
-
-        cfg = _engine_cfg()
-        substrate = Substrate(cfg, None)
-        dag = tree_reduction_dag(16, compute_ms=5.0)
-        with substrate.clock.actor():
-            for i in range(5):
-                sub = substrate.job_substrate(f"job{i}", "tenant-x")
-                WukongEngine(cfg).compute(dag, substrate=sub)
-        assert len(sc._worker_cache) <= sc._WORKER_CACHE_MAX
-
     def test_shared_platform_carries_warmth_across_jobs(self):
         cfg = _engine_cfg(cost=CostModel(cold_start_ms=250.0))
         substrate = Substrate(cfg, PlatformConfig(keep_alive_s=600.0),
@@ -428,11 +416,11 @@ class TestPlatformStatsAliasing:
             assert r2.platform_stats[k] == v
 
     def test_snapshot_returns_fresh_structures(self):
-        from repro.core.simclock import VirtualClock
+        from repro.core.simclock import EventClock
         from repro.platform import FaaSPlatform
 
         platform = FaaSPlatform(PlatformConfig(), CostModel(),
-                                VirtualClock())
+                                EventClock())
         platform.configure_function("tenant-x", 896)
         platform.meter.add_invocation(10.0, memory_mb=896, key="tenant-x")
         s1, s2 = platform.snapshot(), platform.snapshot()

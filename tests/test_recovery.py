@@ -7,8 +7,8 @@ The acceptance properties (ISSUE 7):
   replaying the journal rebuilds the exact state;
 - journal primitives are charged KV operations and are reclaimed with
   their namespace;
-- for every injected orchestrator crash point, on BOTH simulation
-  substrates, a recovered run completes all jobs, journaled-complete
+- for every injected orchestrator crash point, a recovered run
+  completes all jobs, journaled-complete
   jobs are returned from the journal (never re-executed), and their
   billed USD is bit-identical to the uncrashed baseline.
 """
@@ -19,7 +19,6 @@ import pytest
 from repro.core import (
     ADMITTED,
     COMPLETED,
-    CostModel,
     EngineConfig,
     FAILED,
     FaultConfig,
@@ -35,19 +34,13 @@ from repro.core import (
 )
 from repro.core.statemachine import InvalidTransition
 
-SUBSTRATES = ("event", "thread")
 CRASH_POINTS = ("admit", "dispatch", "complete")
 
 
-def _cost(substrate):
-    return CostModel(substrate=substrate)
-
-
-def _engine_cfg(substrate="event", **kw):
+def _engine_cfg(**kw):
     kw.setdefault("num_initial_invokers", 2)
     kw.setdefault("num_proxy_invokers", 2)
     kw.setdefault("max_concurrency", 64)
-    kw.setdefault("cost", _cost(substrate))
     return EngineConfig(**kw)
 
 
@@ -60,10 +53,10 @@ def _workload(n_jobs=6, seed=3):
         app_mix=(("tree_reduction", 1.0),), compute_ms=5.0)
 
 
-def _orch_cfg(substrate="event", crash_point=None, crash_at=2, **kw):
+def _orch_cfg(crash_point=None, crash_at=2, **kw):
     faults = FaultConfig(orchestrator_crash_point=crash_point,
                          orchestrator_crash_at=crash_at)
-    kw.setdefault("engine", _engine_cfg(substrate))
+    kw.setdefault("engine", _engine_cfg())
     kw.setdefault("workload", _workload())
     kw.setdefault("max_concurrent_jobs", 3)
     return OrchestratorConfig(faults=faults, **kw)
@@ -182,21 +175,19 @@ class TestJournalPrimitives:
 # ---------------------------------------------------------------------------
 
 
-def _baseline(substrate):
-    rep = JobOrchestrator(_orch_cfg(substrate)).run()
+def _baseline():
+    rep = JobOrchestrator(_orch_cfg()).run()
     assert rep.completed == rep.jobs
     return rep
 
 
 class TestCrashRecovery:
-    @pytest.mark.parametrize("substrate", SUBSTRATES)
     @pytest.mark.parametrize("point", CRASH_POINTS)
-    def test_recovered_run_completes_with_billing_parity(
-            self, substrate, point):
-        base = _baseline(substrate)
+    def test_recovered_run_completes_with_billing_parity(self, point):
+        base = _baseline()
         base_by_id = {r["job_id"]: r for r in base.job_records}
 
-        orch = JobOrchestrator(_orch_cfg(substrate, crash_point=point))
+        orch = JobOrchestrator(_orch_cfg(crash_point=point))
         rep = orch.run_with_recovery()
 
         assert rep.crashes == 1
@@ -221,14 +212,11 @@ class TestCrashRecovery:
             # crash hits while jobs are in flight: recovery re-admits
             assert rep.recovered_jobs > 0
 
-    @pytest.mark.parametrize("substrate", SUBSTRATES)
-    def test_complete_crash_leaves_journaled_jobs_untouched(
-            self, substrate):
+    def test_complete_crash_leaves_journaled_jobs_untouched(self):
         """Crash between COMPLETED journal and purge: the job's bill in
         the shared meter must not grow during recovery (its work is
         never re-executed)."""
-        orch = JobOrchestrator(
-            _orch_cfg(substrate, crash_point="complete", crash_at=2))
+        orch = JobOrchestrator(_orch_cfg(crash_point="complete", crash_at=2))
         rep = orch.run_with_recovery()
         from_journal = [r for r in rep.job_records if r.get("from_journal")]
         assert from_journal  # the 2nd completion was journaled pre-crash

@@ -1,20 +1,22 @@
-"""Virtual-time simulation core (PR 3): determinism, scale, primitives.
+"""The simulation clock (PR 3, PR 6): primitives, determinism, scale.
 
 The tentpole properties:
 
-- *determinism*: two identical virtual-mode runs produce bit-identical
-  ``wall_s`` / ``charged_ms`` / metrics / results — even with latency
-  jitter, cold starts, and fault injection enabled (all draws are
-  seeded, and the cooperative scheduler serializes actors in a
-  reproducible order).
+- *the effect protocol*: frames suspend on ``charge``/``get``/
+  ``acquire``/``wait``/``sleep`` effects, wake in FIFO and
+  (deadline, seq) order, and threads outside the frames bridge in
+  through ``run_effects`` and ``actor()``;
+- *determinism*: two identical runs produce bit-identical ``wall_s`` /
+  ``charged_ms`` / metrics / results — even with latency jitter, cold
+  starts, and fault injection enabled (all draws are seeded, and the
+  clock serializes actors in a reproducible order);
 - *scale decoupling*: simulated seconds cost zero wall time, so a
   4096-leaf tree reduction (~8k tasks, minutes of simulated time)
   completes in seconds of wall time, and ``job_timeout_s`` means
   *simulated* seconds (a timeout fires instantly in wall time).
-- *cross-check*: the virtual clock charges exactly what the seed
-  real-time mode charges for the same job.
 """
 import queue
+import threading
 import time
 
 import pytest
@@ -28,128 +30,175 @@ from repro.core import (
     JobError,
     WukongEngine,
 )
-from repro.core.simclock import (
-    EventClock,
-    RealtimeClock,
-    VirtualClock,
-    clock_for_scale,
-)
+from repro.core.simclock import EventClock, run_effects
 
 
 # ---------------------------------------------------------------------------
-# Clock primitives
+# Clock primitives (the effect protocol)
 # ---------------------------------------------------------------------------
 
 
-class TestVirtualClockPrimitives:
-    def test_mode_selection(self):
-        # Event-driven is the default zero-scale substrate; the
-        # thread-per-actor VirtualClock stays as the cross-check mode.
-        assert isinstance(clock_for_scale(0.0), EventClock)
-        assert isinstance(clock_for_scale(0.0, "thread"), VirtualClock)
-        assert isinstance(clock_for_scale(0.0, "event"), EventClock)
-        assert isinstance(clock_for_scale(0.1), RealtimeClock)
-        with pytest.raises(ValueError):
-            clock_for_scale(0.0, "bogus")
+class TestEventClockPrimitives:
+    def test_charge_advances_and_run_returns(self):
+        clock = EventClock()
 
-    def test_charge_outside_actor_accumulates_without_advancing(self):
-        clock = VirtualClock()
+        def main():
+            yield ("charge", 250.0)
+            yield ("charge", 125.0)
+            return clock.now_ms()
+
+        assert clock.run(main()) == 375.0
+        assert clock.charged_ms == 375.0
+
+    def test_sleepers_wake_in_deadline_order(self):
+        clock = EventClock()
+        wakes = []
+
+        def sleeper(ms):
+            def body():
+                yield ("sleep", ms)
+                wakes.append((ms, clock.now_ms()))
+            return body
+
+        for ms in (300.0, 100.0, 200.0):
+            clock.spawn(sleeper(ms), name=f"s{ms}")
+
+        def main():
+            yield ("sleep", 400.0)
+
+        clock.run(main())
+        assert wakes == [(100.0, 100.0), (200.0, 200.0), (300.0, 300.0)]
+
+    def test_queue_get_timeout_is_simulated(self):
+        clock = EventClock()
+        q = clock.queue()
+
+        def main():
+            try:
+                yield ("get", q, 3600.0)  # one simulated hour
+            except queue.Empty:
+                return clock.now_ms()
+            raise AssertionError("get should have timed out")
+
+        assert clock.run(main()) == pytest.approx(3600e3)
+
+    def test_queue_put_wakes_blocked_actor(self):
+        clock = EventClock()
+        q = clock.queue()
+        got = []
+
+        def consumer():
+            got.append((yield ("get", q, 60.0)))
+
+        clock.spawn(consumer, name="consumer")
+
+        def main():
+            yield ("charge", 5.0)  # let the consumer park first
+            q.put("payload")
+            yield ("charge", 1.0)
+
+        clock.run(main())
+        assert got == ["payload"]
+        assert clock.now_ms() < 60e3  # woken by the put, not the timeout
+
+    def test_lock_contention_charges_waiters_for_the_hold(self):
+        clock = EventClock()
+        lane = clock.lock()
+        spans = []
+
+        def transfer():
+            yield ("acquire", lane)
+            t0 = clock.now_ms()
+            yield ("charge", 100.0)
+            spans.append((t0, clock.now_ms()))
+            lane.release()
+
+        for _ in range(3):
+            clock.spawn(transfer, name="t")
+
+        def main():
+            yield ("sleep", 1000.0)
+
+        clock.run(main())
+        assert spans == [(0.0, 100.0), (100.0, 200.0), (200.0, 300.0)]
+
+    def test_event_wait_timeout_and_set(self):
+        clock = EventClock()
+        ev = clock.event()
+
+        def main():
+            flag = yield ("wait", ev, 0.5)  # simulated 500 ms
+            assert flag is False
+            assert clock.now_ms() == pytest.approx(500.0)
+            ev.set()
+            flag = yield ("wait", ev, 0.5)
+            assert flag is True
+            return clock.now_ms()
+
+        assert clock.run(main()) == pytest.approx(500.0)  # no extra wait
+
+    def test_flush_applies_deferred_direct_charges(self):
+        # Non-yieldable code (simulated_compute inside task fns) calls
+        # clock.charge() directly: billed immediately, time advance
+        # deferred until the frame's next ("flush",).
+        clock = EventClock()
+
+        def main():
+            clock.charge(42.0)
+            assert clock.charged_ms == 42.0
+            assert clock.now_ms() == 0.0  # not yet advanced
+            yield ("flush",)
+            return clock.now_ms()
+
+        assert clock.run(main()) == 42.0
+
+    def test_external_thread_drives_effects_blockingly(self):
+        # run_effects is the bridge for code running on a real OS thread
+        # (the same generator protocol, mapped onto blocking waits).
+        clock = EventClock()
+        q = clock.queue()
+        out = []
+
+        def external():
+            def gen():
+                out.append((yield ("get", q, 5.0)))
+            run_effects(clock, gen())
+
+        t = threading.Thread(target=external)
+        t.start()
+
+        def main():
+            yield ("charge", 1.0)
+            q.put(42)
+
+        clock.run(main())
+        t.join(timeout=5.0)
+        assert out == [42]
+
+    def test_charge_outside_any_frame_bills_without_advancing(self):
+        clock = EventClock()
         clock.charge(123.0)
         assert clock.charged_ms == 123.0
         assert clock.now_ms() == 0.0
 
-    def test_actor_charge_advances_virtual_time(self):
-        clock = VirtualClock()
+    def test_charge_inside_actor_advances_time(self):
+        clock = EventClock()
         with clock.actor():
             clock.charge(250.0)
             clock.charge(125.0)
             assert clock.now_ms() == 375.0
         assert clock.charged_ms == 375.0
 
-    def test_sleepers_wake_in_deadline_order(self):
-        clock = VirtualClock()
-        wakes = []
-
-        def sleeper(ms):
-            def body():
-                clock.sleep_ms(ms)
-                wakes.append((ms, clock.now_ms()))
-            return body
-
-        for ms in (300.0, 100.0, 200.0):
-            clock.spawn(sleeper(ms), name=f"s{ms}")
-        deadline = time.monotonic() + 5.0
-        while len(wakes) < 3 and time.monotonic() < deadline:
-            time.sleep(0.001)
-        assert wakes == [(100.0, 100.0), (200.0, 200.0), (300.0, 300.0)]
-
-    def test_queue_get_timeout_is_simulated(self):
-        clock = VirtualClock()
+    def test_unregistered_thread_blocks_on_a_real_timeout(self):
+        # A thread that never registered waits in real seconds, and
+        # simulated time stays where it was.
+        clock = EventClock()
         q = clock.queue()
-        with clock.actor():
-            t0 = time.perf_counter()
-            with pytest.raises(queue.Empty):
-                q.get(timeout=3600.0)  # one simulated hour...
-            real = time.perf_counter() - t0
-            assert clock.now_ms() == pytest.approx(3600e3)
-        assert real < 5.0  # ...costs (essentially) zero wall time
-
-    def test_queue_put_wakes_blocked_actor(self):
-        clock = VirtualClock()
-        q = clock.queue()
-        got = []
-
-        def consumer():
-            got.append(q.get(timeout=60.0))
-
-        clock.spawn(consumer, name="consumer")
-        with clock.actor():
-            clock.charge(5.0)  # let the consumer block first
-            q.put("payload")
-        deadline = time.monotonic() + 5.0
-        while not got and time.monotonic() < deadline:
-            time.sleep(0.001)
-        assert got == ["payload"]
-        assert clock.now_ms() < 60e3  # woken by the put, not the timeout
-
-    def test_lock_contention_charges_waiters_for_the_hold(self):
-        clock = VirtualClock()
-        lane = clock.lock()
-        spans = []
-
-        def transfer(ms):
-            def body():
-                with lane:
-                    t0 = clock.now_ms()
-                    clock.charge(ms)
-                    spans.append((t0, clock.now_ms()))
-            return body
-
-        for _ in range(3):
-            clock.spawn(transfer(100.0), name="t")
-        deadline = time.monotonic() + 5.0
-        while len(spans) < 3 and time.monotonic() < deadline:
-            time.sleep(0.001)
-        # serialized: each holder's span starts when the previous ends
-        assert spans == [(0.0, 100.0), (100.0, 200.0), (200.0, 300.0)]
-
-    def test_event_wait_timeout_and_set(self):
-        clock = VirtualClock()
-        ev = clock.event()
-        with clock.actor():
-            assert ev.wait(timeout=0.5) is False  # simulated 500 ms
-            assert clock.now_ms() == pytest.approx(500.0)
-            ev.set()
-            assert ev.wait(timeout=0.5) is True
-            assert clock.now_ms() == pytest.approx(500.0)  # no extra wait
-
-    def test_nonactor_threads_still_block_for_real(self):
-        # Unit-test usage: no actors anywhere, plain threads must not
-        # deadlock on the clock-aware primitives.
-        import threading
-
-        clock = VirtualClock()
-        q = clock.queue()
+        t0 = time.perf_counter()
+        with pytest.raises(queue.Empty):
+            q.get(timeout=0.05)
+        assert time.perf_counter() - t0 >= 0.05
+        assert clock.now_ms() == 0.0
         out = []
         t = threading.Thread(target=lambda: out.append(q.get(timeout=5.0)))
         t.start()
@@ -221,25 +270,6 @@ class TestDeterminism:
         assert run(0.0).charged_ms > run(1.0).charged_ms
 
 
-class TestCrossCheck:
-    def test_virtual_matches_realtime_charges(self):
-        """The virtual substrate must charge exactly what the seed
-        real-time mode charges for the same job (protocol equivalence;
-        only the passage of wall time differs)."""
-        def run(scale):
-            cfg = EngineConfig(cost=CostModel(time_scale=scale))
-            return WukongEngine(cfg).compute(
-                tree_reduction_dag(16, compute_ms=20.0))
-
-        virt = run(0.0)
-        real = run(0.001)
-        assert virt.charged_ms == pytest.approx(real.charged_ms)
-        assert virt.kv_stats == real.kv_stats
-        (_, v), = virt.results.items()
-        (_, r), = real.results.items()
-        assert v[0] == r[0]
-
-
 # ---------------------------------------------------------------------------
 # Scale: simulated seconds are free
 # ---------------------------------------------------------------------------
@@ -263,8 +293,8 @@ class TestScale:
 
     def test_4096_leaf_tree_reduction_under_wall_budget(self):
         """Acceptance: a 4096-leaf TR (8191 tasks, ~7 simulated minutes)
-        completes correctly within a wall-time budget in virtual mode —
-        the DAG scale the 512-thread real-time cap could never reach."""
+        completes correctly within a wall-time budget — the DAG scale
+        the seed's 512-thread real-time cap could never reach."""
         n = 8192  # 4096 leaf tasks
         dag = tree_reduction_dag(n, compute_ms=500.0)
         cfg = EngineConfig(max_concurrency=8192, job_timeout_s=3600.0)
@@ -276,6 +306,21 @@ class TestScale:
         assert rep.tasks == n - 1
         assert rep.wall_s > 10.0       # minutes of simulated time...
         assert wall < 120.0            # ...in seconds of wall time
+
+    @pytest.mark.slow
+    def test_100k_task_tree_reduction_under_wall_budget(self):
+        n = 131072  # 131071 tasks
+        dag = tree_reduction_dag(n, compute_ms=1.0)
+        cfg = EngineConfig(max_concurrency=n, job_timeout_s=1e6,
+                           record_metrics=False)
+        t0 = time.perf_counter()
+        rep = WukongEngine(cfg).compute(dag)
+        wall = time.perf_counter() - t0
+        (_, v), = rep.results.items()
+        assert v[0] == tree_reduction_expected(n)
+        assert rep.tasks == n - 1
+        assert rep.metrics == []  # record_metrics=False
+        assert wall < 30.0
 
 
 class TestRetryBackoff:

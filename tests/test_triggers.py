@@ -24,7 +24,7 @@ from repro.core import (
     WorkloadConfig,
     stream_arrivals,
 )
-from repro.core.kvstore import NAMESPACE_SEP, CostModel
+from repro.core.kvstore import NAMESPACE_SEP
 from repro.core.simclock import EventClock
 
 _ACTION = {"app": "tree_reduction", "size": 8, "tenant": "tenant-a"}
@@ -261,18 +261,15 @@ class TestPubSubTeardown:
 _TENANTS = (TenantSpec("tenant-a"), TenantSpec("tenant-b"))
 
 
-def _orch_config(substrate: "str | None" = None,
-                 crash_at: "int | None" = None) -> OrchestratorConfig:
+def _orch_config(crash_at: "int | None" = None) -> OrchestratorConfig:
     stream = StreamConfig(n_events=40, rate_per_s=40.0, seed=3,
                           flush_event="eos")
-    cost_kw = {} if substrate is None else {"substrate": substrate}
     faults = FaultConfig()
     if crash_at is not None:
         faults = FaultConfig(orchestrator_crash_point="dispatch",
                              orchestrator_crash_at=crash_at)
     return OrchestratorConfig(
-        engine=EngineConfig(cost=CostModel(**cost_kw),
-                            num_initial_invokers=4, num_proxy_invokers=4,
+        engine=EngineConfig(num_initial_invokers=4, num_proxy_invokers=4,
                             max_concurrency=512),
         workload=WorkloadConfig(n_jobs=2, tenants=_TENANTS, seed=1),
         max_concurrent_jobs=8,
@@ -325,14 +322,6 @@ class TestOrchestratorStreaming:
         sa = a.last_substrate.trigger_bus.report(n_events=40)
         sb = b.last_substrate.trigger_bus.report(n_events=40)
         assert sa == sb
-
-    def test_substrates_bit_identical(self):
-        a = JobOrchestrator(_orch_config(substrate="event"))
-        b = JobOrchestrator(_orch_config(substrate="thread"))
-        ra, rb = a.run(), b.run()
-        assert ra.makespan_s == rb.makespan_s
-        assert ra.billed_usd_total == rb.billed_usd_total
-        assert _fire_summary(a) == _fire_summary(b)
 
     def test_crash_mid_stream_recovers_exactly_once(self):
         base = JobOrchestrator(_orch_config())
